@@ -24,9 +24,10 @@ Two configurations run back to back on the same model and load:
   per-engine locks + the ragged mixed-dispatch plane (the defaults;
   ``PADDLE_SERVING_RAGGED=0`` drops the last one).
 
-``vs_baseline`` is the pipelined/baseline aggregate tokens/s ratio. The
-acceptance bar (ISSUE 6): >= 1.5x tokens/s and >= 2x interactive TTFT p50
-under prefill on the CPU proxy. ISSUE 20 adds
+``vs_baseline`` is the pipelined/baseline aggregate tokens/s ratio (the
+ISSUE 6 bar of >= 1.5x tokens/s and >= 2x interactive TTFT p50 under
+prefill was set and met on a CPU run; it is not measured on the chip yet).
+ISSUE 20 adds
 ``extra.compile.serving_programs`` — the count of distinct serve.*
 programs each mode compiled across warmup + run (the bucket-ladder
 collapse shows up as the pipelined count dropping >= 50% below
@@ -35,8 +36,9 @@ appends its headline + per-program devprof rows to
 BENCH_trajectory.jsonl and flags >10% same-config regressions in the
 contract line.
 
-Usage: python bench_serving.py [--quick]   (--quick: tiny smoke load for
-tests; numbers are not meaningful at that scale)
+Usage: python bench_serving.py [--quick]. Without --quick it needs a TPU
+and fails without one; --quick is the tiny smoke load the tests run on the
+CPU, and its numbers are not measurements of anything.
 """
 import json
 import os
@@ -55,25 +57,24 @@ def _percentile(xs, q):
     return xs[i]
 
 
-def _build_model():
-    import jax
-
+def _build_model(quick):
+    """The tiny model only under --quick (the tests' smoke entry); the
+    measurement itself is never resized by what backend JAX found."""
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM, llama_tiny
 
-    on_tpu = jax.default_backend() == "tpu"
     paddle.seed(0)
-    if on_tpu:
+    if quick:
+        model = LlamaForCausalLM(llama_tiny(max_position_embeddings=1024))
+    else:
         cfg = LlamaConfig(
             vocab_size=32000, hidden_size=2048, intermediate_size=5504,
             num_hidden_layers=12, num_attention_heads=16,
             max_position_embeddings=2048, dtype="bfloat16")
         model = LlamaForCausalLM(cfg)
         model.bfloat16()
-    else:
-        model = LlamaForCausalLM(llama_tiny(max_position_embeddings=1024))
     model.eval()
-    return model, on_tpu
+    return model
 
 
 def _make_engines(model, mode, n_replicas, knobs):
@@ -518,23 +519,22 @@ def run_bench(quick=False, seed=0):
 
     from paddle_tpu.utils.envs import env_bool
 
-    model, on_tpu = _build_model()
+    if not quick and jax.devices()[0].platform != "tpu":
+        raise RuntimeError(
+            f"bench_serving.py measures the chip and JAX found none "
+            f"({jax.devices()}); --quick is the CPU smoke, not a measurement")
+    model = _build_model(quick)
     vocab = model.config.vocab_size
-    if on_tpu:
-        knobs = dict(max_seqs=4, page_size=64, max_len=2048, decode_block=32,
-                     prefill_chunk=512, n_replicas=2, n_batch=4,
-                     n_interactive=12, n_probe=6, long_lo=1024, long_hi=1536,
-                     batch_new=64, inter_new=32, repeats=3)
-    elif quick:
+    if quick:
         knobs = dict(max_seqs=2, page_size=16, max_len=192, decode_block=4,
                      prefill_chunk=32, n_replicas=1, n_batch=1,
                      n_interactive=2, n_probe=2, long_lo=96, long_hi=128,
                      batch_new=4, inter_new=3, repeats=1)
     else:
-        knobs = dict(max_seqs=8, page_size=16, max_len=1024, decode_block=8,
-                     prefill_chunk=256, n_replicas=2, n_batch=4,
-                     n_interactive=24, n_probe=6, long_lo=512, long_hi=768,
-                     batch_new=64, inter_new=32, repeats=4)
+        knobs = dict(max_seqs=4, page_size=64, max_len=2048, decode_block=32,
+                     prefill_chunk=512, n_replicas=2, n_batch=4,
+                     n_interactive=12, n_probe=6, long_lo=1024, long_hi=1536,
+                     batch_new=64, inter_new=32, repeats=3)
     base = _run_mode(model, "baseline", knobs, seed, vocab)
     pipe = _run_mode(model, "pipelined", knobs, seed, vocab)
     telemetry = _telemetry_snapshot(model, knobs, seed, vocab)
@@ -601,13 +601,10 @@ def run_bench(quick=False, seed=0):
 
 
 def main():
-    quick = "--quick" in sys.argv
-    try:
-        res = run_bench(quick=quick)
-    except Exception as e:  # noqa: BLE001 — the driver needs a JSON line, always
-        res = {"metric": "serving_tokens_per_sec_per_chip", "value": 0.0,
-               "unit": "tokens/s/chip", "vs_baseline": 0.0,
-               "error": f"{type(e).__name__}: {str(e)[:300]}"}
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    res = run_bench(quick="--quick" in sys.argv)
     _trajectory_guard(res)
     print(json.dumps(res), flush=True)
 

@@ -23,9 +23,6 @@ def role_main():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import numpy as np
 
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import paddle_tpu as paddle
     from paddle_tpu import nn, optimizer
     from paddle_tpu.distributed import ps

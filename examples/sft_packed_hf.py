@@ -13,11 +13,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import paddle_tpu as paddle
 from paddle_tpu import optimizer
 from paddle_tpu.models.llama import LlamaPretrainingCriterion

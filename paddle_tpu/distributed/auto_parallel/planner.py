@@ -41,7 +41,7 @@ DCN_LATENCY_S = 5e-5
 
 # Mutable cost-model constants, refittable from measured bench rungs
 # (reference: auto_parallel/static/cluster.py reads measured cluster specs;
-# here `calibrate_from_bench` fits them from BENCH_rungs.jsonl instead).
+# here `calibrate_from_bench` fits them from bench.py result lines instead).
 # compute_efficiency is the measured MFU of the best real-TPU training rung:
 # the planner's compute term uses achievable FLOP/s, not datasheet peak, so
 # the compute/communication tradeoff reflects this chip as measured.
@@ -54,8 +54,8 @@ CALIBRATION = {
 
 
 def calibrate(records):
-    """Fit CALIBRATION from bench result dicts (rows of BENCH_rungs.jsonl
-    and/or a BENCH_r*.json top-level dict). Uses the best real-TPU training
+    """Fit CALIBRATION from bench result dicts (bench.py rung results or
+    its final contract line). Uses the best real-TPU training
     rung's measured MFU as the achievable-compute efficiency. Returns the
     updated CALIBRATION, or None if no TPU evidence exists (constants kept)."""
     best = None
@@ -75,8 +75,8 @@ def calibrate(records):
 
 
 def calibrate_from_bench(path, save_path=None):
-    """Load a bench artifact (JSONL of rungs, or a single-JSON BENCH_r*.json
-    — possibly pretty-printed) and refit the cost-model constants. With
+    """Load a bench artifact (JSONL of rung results, or one JSON document —
+    possibly pretty-printed) and refit the cost-model constants. With
     `save_path`, persist the fitted constants as JSON so other processes can
     pick them up via `load_calibration` (or the PADDLE_TPU_CALIBRATION env
     var at import). Returns the updated CALIBRATION or None."""
@@ -89,7 +89,7 @@ def calibrate_from_bench(path, save_path=None):
         text = f.read().strip()
     records = []
     try:
-        # whole-file parse first: BENCH_r*.json artifacts are pretty-printed
+        # whole-file parse first: a single-document artifact may be pretty-printed
         whole = json.loads(text)
         records = whole if isinstance(whole, list) else [whole]
     except json.JSONDecodeError:

@@ -54,6 +54,11 @@ def init_distributed(timeout_s=900):
     global _initialized
     if _initialized:
         return
+    # the launched worker's bootstrap is an entry point: place the
+    # persistent compile cache (every restart/regrow recompiles otherwise)
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     world = get_world_size()
     if world > 1 and not env_bool("PADDLE_TPU_SKIP_JAX_DIST"):
         coordinator = get_master_endpoint()

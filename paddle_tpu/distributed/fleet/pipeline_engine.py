@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ...framework.core import Parameter, Tensor, apply
-from ...framework.jax_compat import shard_map as _shard_map
 from ...nn.layer.layers import Layer
 
 
@@ -185,14 +184,11 @@ class PipelineStack(Layer):
                 streams_l = args[n_leaf:]
                 sid = jax.lax.axis_index("pp")
                 mb_shape = x_stream.shape[1:]
-                if hasattr(jax.lax, "pcast"):
-                    _pvary = lambda v, ax: jax.lax.pcast(v, ax, to="varying")
-                elif hasattr(jax.lax, "pvary"):
-                    _pvary = jax.lax.pvary
-                else:  # pre-varying-types jax (<= 0.4.x): no cast needed
-                    _pvary = lambda v, ax: v
-                state = _pvary(jnp.zeros(mb_shape, x_stream.dtype), ("pp",))
-                outputs = _pvary(jnp.zeros((M,) + mb_shape, x_stream.dtype), ("pp",))
+                def _pvary(v):
+                    return jax.lax.pcast(v, ("pp",), to="varying")
+
+                state = _pvary(jnp.zeros(mb_shape, x_stream.dtype))
+                outputs = _pvary(jnp.zeros((M,) + mb_shape, x_stream.dtype))
 
                 def apply_stage(h, *ex_mb):
                     ex = rebuild_extra(ex_mb)
@@ -230,7 +226,7 @@ class PipelineStack(Layer):
                 mask = (sid == pp - 1).astype(outputs.dtype)
                 return jax.lax.psum(outputs * mask, "pp")
 
-            shmapped = _shard_map(
+            shmapped = jax.shard_map(
                 shard_body,
                 mesh=mesh,
                 in_specs=(P(), *[P("pp") for _ in leaf_stacks], *[P() for _ in stream_datas]),

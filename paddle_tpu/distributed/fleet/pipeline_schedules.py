@@ -364,11 +364,7 @@ def build_schedule(num_micro, pp, num_chunks=1, style="1f1b"):
 def _pvary(v, axes):
     import jax
 
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(v, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(v, axes)
-    return v  # pre-varying-types jax (<= 0.4.x): no cast needed
+    return jax.lax.pcast(v, axes, to="varying")
 
 
 def _store(buf, slot, val):
@@ -463,14 +459,7 @@ def make_pipeline_train_fn(sched, mesh, first_fn, mid_fn, last_fn):
                 (each accumulator on its weight's own TP spec) needs
                 per-leaf specs threaded into the engine and must be
                 re-validated against the deadlock class on a >=16-device
-                mesh before switching — measure on real hardware first.
-
-                On pre-`jax.shard_map` releases (<= 0.4.x) the partial-auto
-                path this pin guards doesn't exist (jax_compat falls back
-                to experimental shard_map) and with_sharding_constraint
-                cannot run inside the manual body — skip the pin there."""
-                if not hasattr(jax, "shard_map"):
-                    return x
+                mesh before switching — measure on real hardware first."""
                 return jax.lax.with_sharding_constraint(x, P(*([None] * x.ndim)))
             tokens, labels, seed_ct = pv(tokens), pv(labels), pv(seed_ct)
             stk_local = tuple(l[:, 0] for l in flat[:ns])  # [V, Lc, ...]
@@ -621,9 +610,7 @@ def make_pipeline_train_fn(sched, mesh, first_fn, mid_fn, last_fn):
             tuple(rep for _ in embed_ws),
             tuple(rep for _ in tail_ws),
         )
-        from ...framework.jax_compat import shard_map as _shard_map
-
-        shmapped = _shard_map(
+        shmapped = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(rep, rep, rep) + stk_specs + tuple(rep for _ in embed_ws + tail_ws + extras),

@@ -221,8 +221,7 @@ def fused_linear_cross_entropy(hidden, weight, labels, ignore_index=-100,
     barriers are in the StableHLO but absent from the optimized module, and
     unconstrained unrolled chunks overlap to 2.5× the loop's temp at the
     8192×32000 probe shape), so the memory bound is only enforceable on
-    TPU, where opt-barrier is honored. scripts/perf_exp.py variants 11/12
-    measure it on the headline shape.
+    TPU, where opt-barrier is honored. Not measured on the chip yet.
     """
     import os
 

@@ -16,7 +16,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..framework import dtype as dtypes
 from ..framework.core import Tensor
-from ..framework.jax_compat import shard_map as _shard_map
 from ..incubate.nn.functional import fused_rotary_position_embedding, swiglu
 from ..nn import functional as F
 from ..nn import initializer as I
@@ -393,7 +392,7 @@ class LlamaAttention(Layer):
             # context is exactly what CP exists to avoid
             from ..ops.flash_attention import flash_attention_fwd
 
-            island = _shard_map(
+            island = jax.shard_map(
                 functools.partial(
                     ulysses_attention, axis_name="sep", causal=True,
                     attn_impl=lambda qq, kk, vv: flash_attention_fwd(
@@ -412,7 +411,7 @@ class LlamaAttention(Layer):
                 return island(qd, kd, vd)
         else:
             spec = P(bspec if batch else None, hspec, "sep", None)
-            island = _shard_map(
+            island = jax.shard_map(
                 functools.partial(ring_attention, axis_name="sep", causal=True),
                 mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
             )

@@ -1,11 +1,10 @@
 """Compile & HBM observability (ISSUE 8): the XLA compile ledger, the
 recompile-churn detector, the HBM memory ledger, and OOM forensics.
 
-Two failure classes cost real sessions (PROFILE.md): **compile churn**
-(cold paged-serve programs were a 7.3x throughput cliff until warmup();
-one big compile killed two rounds) and **HBM fit** (a silent bf16->f32
-Adam upcast ate ~3 GB). This module measures both instead of
-rediscovering them post-mortem:
+Two failure classes cost real sessions: **compile churn** (cold
+paged-serve programs were a 7.3x throughput cliff until warmup()) and
+**HBM fit** (a silent bf16->f32 Adam upcast ate ~3 GB). This module
+measures both instead of rediscovering them post-mortem:
 
 - :func:`ledgered_jit` — the blessed ``jax.jit`` wrapper every compile
   site in ``paddle_tpu/`` goes through (lint-enforced by scripts/ci.sh,
@@ -679,6 +678,24 @@ class MemoryLedger:
             while len(self._programs) > self._max_programs:
                 self._programs.popitem(last=False)
 
+    @staticmethod
+    def _compile_captured(jitted, abstract):
+        a, kw = abstract
+        with _compile_lock(), ledger.suppressed():
+            return jitted.lower(*a, **kw).compile()  # compile-ledger-ok (the ledger's own suppressed analysis)
+
+    def compiled(self, key):
+        """The compiled executable of one captured program, re-lowered
+        from its abstract signature (suppressed in the ledger) — for
+        callers that read ``as_text()``, e.g. to see the collectives of a
+        sharded step. KeyError for a program never captured."""
+        with self._lock:
+            v = self._programs[str(key)]
+        jitted = v["jitted"]()
+        if jitted is None:
+            raise KeyError(f"{key}: program garbage-collected")
+        return self._compile_captured(jitted, v["abstract"])
+
     def analyze(self, keys=None, force=False):
         """Harvest ``memory_analysis()`` for captured programs (all, or
         the given keys). Each un-analyzed program pays one suppressed
@@ -698,12 +715,10 @@ class MemoryLedger:
                     v["error"] = err
                 out[k] = {"error": err}
                 continue
-            a, kw = v["abstract"]
             try:
-                with _compile_lock(), ledger.suppressed():
-                    compiled = jitted.lower(*a, **kw).compile()  # compile-ledger-ok (the ledger's own suppressed analysis)
-                    analysis = _analysis_dict(compiled.memory_analysis())
-                    cost = _cost_dict(compiled)
+                compiled = self._compile_captured(jitted, v["abstract"])
+                analysis = _analysis_dict(compiled.memory_analysis())
+                cost = _cost_dict(compiled)
                 with self._lock:
                     v["analysis"] = analysis
                     v["cost"] = cost

@@ -59,7 +59,8 @@ from ..utils.envs import env_bool, env_float, env_int
 from .metrics import registry as _registry
 
 __all__ = ["DevProfPlane", "arm_from_env", "enable", "disable", "enabled",
-           "plane", "report", "serving_block", "fleet_block", "ENABLE_ENV",
+           "plane", "report", "serving_block", "fleet_block", "device_peaks",
+           "DEVICE_PEAKS", "ENABLE_ENV",
            "EVERY_ENV", "PEAK_FLOPS_ENV", "PEAK_BW_ENV"]
 
 #: master switch — unset/false = every hot path is one None check
@@ -72,20 +73,21 @@ PEAK_FLOPS_ENV = "PADDLE_DEVPROF_PEAK_FLOPS"
 #: hardware peak HBM bytes/s override for the roofline knee
 PEAK_BW_ENV = "PADDLE_DEVPROF_PEAK_BW"
 
-#: bf16 peak FLOP/s and HBM bytes/s per chip by device-kind substring,
-#: first match wins (same table shape as bench.peak_flops_per_chip)
-_PEAKS = (
-    ("v5 lite", 197e12, 819e9),
-    ("v5e", 197e12, 819e9),
-    ("lite", 197e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v5", 459e12, 2765e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-)
-#: nominal knees for CPU smoke runs — the roofline still needs a finite
-#: denominator so MFU/verdicts are well-defined (and obviously nominal)
-_CPU_PEAKS = (1e12, 100e9)
+#: THE peak table (bench.py reads it too): bf16 peak FLOP/s and HBM
+#: bytes/s of one chip, keyed by jax's ``device_kind``. Source: Google
+#: Cloud TPU documentation, the system-architecture page of each version
+#: ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM). A kind that is not here
+#: is an error, not a default — add its row with its source.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),   # v5e
+    "TPU v5": (459e12, 2765e9),       # v5p
+    "TPU v4": (275e12, 1228e9),
+    "TPU v3": (123e12, 900e9),
+    # NOT a device metric: nominal knees so the CPU test suite gets finite
+    # roofline denominators. Nothing computed from this row is a
+    # measurement of any chip.
+    "cpu": (1e12, 100e9),
+}
 
 #: measured device time past this multiple of the roofline-predicted
 #: time means the chip is idle most of the window: host-bound
@@ -97,23 +99,18 @@ _PLANE = None
 _plane_lock = threading.Lock()
 
 
-def _device_peaks(peak_flops=None, peak_bw=None):
-    """(kind, peak FLOP/s, peak bytes/s): env overrides first, else the
-    device-kind table, else CPU nominals. Never raises — a plane must
-    arm even when jax/devices are unavailable."""
-    kind = "unknown"
-    try:
-        import jax
+def device_peaks(peak_flops=None, peak_bw=None):
+    """(kind, peak FLOP/s, peak bytes/s) of ``jax.devices()[0]`` from
+    DEVICE_PEAKS; explicit arguments, then the env overrides, replace the
+    table's numbers. An unknown device kind raises."""
+    import jax
 
-        d = jax.devices()[0]
-        kind = (getattr(d, "device_kind", "") or d.platform or "cpu").lower()
-    except Exception:
-        pass
-    flops, bw = _CPU_PEAKS
-    for sub, f, b in _PEAKS:
-        if sub in kind:
-            flops, bw = f, b
-            break
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s / bytes/s known for device kind {kind!r}: add "
+            f"a row (with its source) to devprof.DEVICE_PEAKS")
+    flops, bw = DEVICE_PEAKS[kind]
     flops = float(peak_flops if peak_flops is not None
                   else env_float(PEAK_FLOPS_ENV, flops))
     bw = float(peak_bw if peak_bw is not None
@@ -129,7 +126,7 @@ class DevProfPlane:
     def __init__(self, sample_every=None, peak_flops=None, peak_bw=None):
         self.sample_every = max(1, int(sample_every) if sample_every
                                 is not None else env_int(EVERY_ENV, 16))
-        self.device_kind, self.peak_flops, self.peak_bw = _device_peaks(
+        self.device_kind, self.peak_flops, self.peak_bw = device_peaks(
             peak_flops, peak_bw)
         self._lock = threading.Lock()
         #: dispatches since the last timed sample, per call-site context
@@ -307,7 +304,7 @@ class DevProfPlane:
     @staticmethod
     def _serving_split(rows):
         """The decode device-time budget: device-seconds per emitted
-        token, overall and per decode program signature — BENCH_r05's
+        token, overall and per decode program signature — the
         paged-vs-dense gap, attributed program by program."""
         decode = {k: r for k, r in rows.items()
                   if k.startswith("serve.decode") and r.get("tokens")}

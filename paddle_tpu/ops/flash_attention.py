@@ -1,22 +1,22 @@
 """Flash attention for TPU (reference: paddle/phi/kernels/gpu/flash_attn_kernel.cu
 + external flash-attn v2 — here a Pallas kernel tiled for MXU/VMEM).
 
-Strategy: use jax's built-in Pallas TPU flash attention when importable
-(jax.experimental.pallas.ops.tpu.flash_attention) — it implements the
-blockwise online-softmax algorithm with proper VMEM tiling and a custom VJP.
-Fall back to a hand-rolled Pallas kernel, then to fused-XLA math attention.
+Strategy: on a TPU, jax's Pallas flash attention
+(jax.experimental.pallas.ops.tpu.flash_attention: blockwise online softmax,
+VMEM tiling, custom VJP) for MHA and the splash kernel for GQA/packed/varlen.
+The tier is chosen from what the code can observe — platform, alignment,
+head dim — never by catching: a kernel the chip's compiler refuses raises.
+Off-TPU (and for shapes the predicates exclude) the fused-XLA math path runs.
 
 Layout contract here: [batch, seq, heads, head_dim] (paddle convention);
 jax's kernel wants [batch, heads, seq, head_dim], so we transpose around it —
 XLA fuses the transposes into the surrounding ops.
 """
-import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
-
-_PALLAS_IMPL = None
 
 # Which attention impl was selected at last trace ("splash" | "pallas" | "xla").
 # Selection happens at trace time (shapes are static under jit), so this is an
@@ -42,7 +42,7 @@ def configure(block_q=_UNSET, block_k=_UNSET):
 
     Tiles must divide the (128-aligned) sequence length; larger tiles
     raise arithmetic intensity per VMEM fill, smaller tiles cut VMEM
-    pressure for long head dims. perf_exp.py sweeps these."""
+    pressure for long head dims."""
     import os
 
     if block_q is _UNSET and block_k is _UNSET:
@@ -78,38 +78,29 @@ def _block_sizes(seq_q, seq_k):
     return max(bq, 128), max(bk, 128)
 
 
-def _get_pallas_impl():
-    global _PALLAS_IMPL
-    if _PALLAS_IMPL is not None:
-        return _PALLAS_IMPL
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes,
-            flash_attention as _fa,
-        )
+def _pallas_flash(q, k, v, causal, scale):
+    """jax's Pallas TPU flash attention (fwd + custom-VJP bwd); q/k/v:
+    [B, H, S, D]."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes,
+        flash_attention as _fa,
+    )
 
-        def impl(q, k, v, causal, scale):
-            # q/k/v: [B, H, S, D]
-            bq, bk = _block_sizes(q.shape[2], k.shape[2])
-            sizes = BlockSizes(
-                block_q=bq,
-                block_k_major=bk,
-                block_k=bk,
-                block_b=1,
-                block_q_major_dkv=bq,
-                block_k_major_dkv=bk,
-                block_k_dkv=bk,
-                block_q_dkv=bq,
-                block_k_major_dq=bk,
-                block_k_dq=bk,
-                block_q_dq=bq,
-            )
-            return _fa(q, k, v, causal=causal, sm_scale=scale, block_sizes=sizes)
-
-        _PALLAS_IMPL = impl
-    except Exception:
-        _PALLAS_IMPL = False
-    return _PALLAS_IMPL
+    bq, bk = _block_sizes(q.shape[2], k.shape[2])
+    sizes = BlockSizes(
+        block_q=bq,
+        block_k_major=bk,
+        block_k=bk,
+        block_b=1,
+        block_q_major_dkv=bq,
+        block_k_major_dkv=bk,
+        block_k_dkv=bk,
+        block_q_dkv=bq,
+        block_k_major_dq=bk,
+        block_k_dq=bk,
+        block_q_dq=bq,
+    )
+    return _fa(q, k, v, causal=causal, sm_scale=scale, block_sizes=sizes)
 
 
 _SPLASH_CACHE = {}
@@ -163,10 +154,42 @@ def _splash_impl(qt, kt, vt, causal, scale):
 
 
 def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
+
+
+def _per_shard(fn, q, k, v):
+    """Run the [B, H, S, D] attention kernel `fn` once per shard of the
+    ambient mesh. GSPMD cannot partition a Mosaic kernel ("wrap the call in
+    a shard_map"), so under a multi-device mesh the kernel becomes a
+    shard_map island: batch over the data axes and heads over mp where
+    those divide, replicated otherwise. Axes an enclosing shard_map
+    already made manual (the pipeline engine's pp, the ring/Ulysses
+    island) are left alone; with none left the kernel is called as is."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..distributed.mesh import get_mesh, has_mesh
+
+    if not has_mesh():
+        return fn(q, k, v)
+    mesh = get_mesh()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = {a for a in mesh.axis_names if a not in manual}
+    if mesh.size == 1 or not free:
+        return fn(q, k, v)
+
+    def live(axis):
+        return axis in free and mesh.shape[axis] > 1
+
+    batch = tuple(a for a in ("dcn_dp", "dp", "sharding") if live(a))
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch):
+        batch = ()
+    mp = mesh.shape["mp"] if live("mp") else 1
+    heads = "mp" if mp > 1 and not (q.shape[1] % mp or k.shape[1] % mp) \
+        else None
+    spec = P(batch or None, heads, None, None)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+        axis_names=frozenset(free), check_vma=False)(q, k, v)
 
 
 def varlen_segment_ids(cu_seqlens, total):
@@ -188,8 +211,9 @@ def flash_attention_varlen_fwd(q, k, v, cu_q, cu_k, causal=True, scale=None,
     sequences are contiguous, so a static global CausalMask ∧ same-segment
     equals within-sequence causal. O(total·block) memory, never the dense
     [total, total] score matrix. Pads totals to the 128 lattice with a
-    self-attending padding segment, sliced off on return. Falls back to
-    the dense segment-masked math path off-TPU / on kernel rejection."""
+    self-attending padding segment, sliced off on return. Off-TPU (or for
+    head dims / offsets the kernel does not cover) the dense
+    segment-masked math path runs."""
     global LAST_IMPL
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     head_dim = q.shape[-1]
@@ -203,12 +227,9 @@ def flash_attention_varlen_fwd(q, k, v, cu_q, cu_k, causal=True, scale=None,
         same_offsets = _same_offsets(cu_q, cu_k)
     offsets_ok = not causal or same_offsets
     if _on_tpu() and dim_ok and offsets_ok and not _FORCE_XLA and not force_math:
-        try:
-            out = _splash_varlen(q, k, v, cu_q, cu_k, causal, scale)
-            LAST_IMPL = "splash-varlen"
-            return out
-        except Exception:
-            pass
+        out = _splash_varlen(q, k, v, cu_q, cu_k, causal, scale)
+        LAST_IMPL = "splash-varlen"
+        return out
     LAST_IMPL = "xla-varlen"
     return _dense_varlen(q, k, v, cu_q, cu_k, causal, scale)
 
@@ -216,12 +237,11 @@ def flash_attention_varlen_fwd(q, k, v, cu_q, cu_k, causal=True, scale=None,
 def _same_offsets(a, b):
     if a is b:
         return True
-    try:
-        import numpy as np
-
-        return bool(np.array_equal(np.asarray(a), np.asarray(b)))
-    except Exception:
+    if isinstance(a, jax.core.Tracer) or isinstance(b, jax.core.Tracer):
         return False  # traced offsets: unknown → take the safe dense path
+    import numpy as np
+
+    return bool(np.array_equal(np.asarray(a), np.asarray(b)))
 
 
 def _splash_varlen(q, k, v, cu_q, cu_k, causal, scale):
@@ -289,24 +309,21 @@ def flash_attention_packed(q, k, v, segment_ids, causal=True, scale=None):
     dim_ok = head_dim % 128 == 0 or head_dim in (64, 96, 128, 256)
     aligned = qt.shape[2] % 128 == 0
     if _on_tpu() and dim_ok and aligned and not _FORCE_XLA:
-        try:
-            from jax.experimental.pallas.ops.tpu.splash_attention import (
-                splash_attention_kernel as sk,
-            )
+        from jax.experimental.pallas.ops.tpu.splash_attention import (
+            splash_attention_kernel as sk,
+        )
 
-            S = qt.shape[2]
-            kernel = _splash_kernel(hq, S, S, causal, cache_tag="packed")
-            # splash is GQA-native: kv heads stay unexpanded in kb/vb
-            def one(qb, kb, vb, sb):
-                return kernel((qb * scale).astype(vb.dtype), kb, vb,
-                              segment_ids=sk.SegmentIds(q=sb, kv=sb))
+        S = qt.shape[2]
+        kernel = _splash_kernel(hq, S, S, causal, cache_tag="packed")
+        # splash is GQA-native: kv heads stay unexpanded in kb/vb
+        def one(qb, kb, vb, sb):
+            return kernel((qb * scale).astype(vb.dtype), kb, vb,
+                          segment_ids=sk.SegmentIds(q=sb, kv=sb))
 
-            out = jax.vmap(one)(qt, kt, vt, seg)
-            LAST_IMPL = "splash-packed"
-            return jnp.swapaxes(out, 1, 2)
-        except Exception:
-            pass
-    # dense fallback: same-segment ∧ causal, per batch row
+        out = jax.vmap(one)(qt, kt, vt, seg)
+        LAST_IMPL = "splash-packed"
+        return jnp.swapaxes(out, 1, 2)
+    # dense path: same-segment ∧ causal, per batch row
     if hq != hk:
         kt = jnp.repeat(kt, hq // hk, axis=1)
         vt = jnp.repeat(vt, hq // hk, axis=1)
@@ -345,30 +362,21 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None):
 
     aligned = qt.shape[2] % 128 == 0 and kt.shape[2] % 128 == 0
     head_dim = qt.shape[-1]
-    # the Pallas kernels want MXU-friendly head dims; anything else takes
-    # the fused-XLA math path rather than risking a Mosaic tiling error
+    # the Pallas kernels want MXU-friendly head dims and 128-aligned
+    # sequences; anything else takes the fused-XLA math path. Inside the
+    # predicate a kernel failure is fatal — it never becomes the math path.
     dim_ok = head_dim % 128 == 0 or head_dim in (64, 96, 128, 256)
     use_kernels = _on_tpu() and aligned and dim_ok and not _FORCE_XLA
-    if use_kernels and hq != hk:
-        try:
-            out = _splash_impl(qt, kt, vt, causal, scale)
-            LAST_IMPL = "splash"
-            return jnp.swapaxes(out, 1, 2)
-        except Exception:
-            pass  # fall through to expand + flash/XLA
+    if use_kernels:
+        kernel = _splash_impl if hq != hk else _pallas_flash
+        out = _per_shard(
+            lambda a, b, c: kernel(a, b, c, causal, scale), qt, kt, vt)
+        LAST_IMPL = "splash" if hq != hk else "pallas"
+        return jnp.swapaxes(out, 1, 2)
 
-    if hq != hk:  # GQA fallback: expand kv heads
+    if hq != hk:  # GQA on the math path: expand kv heads
         kt = jnp.repeat(kt, hq // hk, axis=1)
         vt = jnp.repeat(vt, hq // hk, axis=1)
-
-    impl = _get_pallas_impl()
-    if use_kernels and impl:
-        try:
-            out = impl(qt, kt, vt, causal, scale)
-            LAST_IMPL = "pallas"
-            return jnp.swapaxes(out, 1, 2)
-        except Exception:
-            pass  # Mosaic rejection → fused-XLA math
     out = _xla_attention(qt, kt, vt, causal, scale)
     LAST_IMPL = "xla"
     return jnp.swapaxes(out, 1, 2)

@@ -164,21 +164,18 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     D = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     if _on_tpu() and not _FORCE_XLA:
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _kernel,
-            )
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention as _kernel,
+        )
 
-            blk = pages_per_compute_block or min(8, page_indices.shape[1])
-            while page_indices.shape[1] % blk:
-                blk -= 1
-            qdt = jnp.bfloat16 if is_quantized(k_pages) else k_pages.dtype
-            out = _kernel((q * scale).astype(qdt), k_pages, v_pages,
-                          lengths, page_indices,
-                          pages_per_compute_block=max(blk, 1))
-            LAST_IMPL = "paged-kernel"
-            return out.astype(q.dtype)
-        except Exception:
-            pass
+        blk = pages_per_compute_block or min(8, page_indices.shape[1])
+        while page_indices.shape[1] % blk:
+            blk -= 1
+        qdt = jnp.bfloat16 if is_quantized(k_pages) else k_pages.dtype
+        out = _kernel((q * scale).astype(qdt), k_pages, v_pages,
+                      lengths, page_indices,
+                      pages_per_compute_block=max(blk, 1))
+        LAST_IMPL = "paged-kernel"
+        return out.astype(q.dtype)
     LAST_IMPL = "paged-math"
     return _paged_math(q, k_pages, v_pages, lengths, page_indices, scale)

@@ -19,12 +19,19 @@ step's writes (the query attends to itself), mirroring the `lengths + 1`
 convention of `paged_decode_attention`.
 
 Two tiers, same contract as the decode kernel:
-- `_ragged_pallas`: Pallas grid over (batch_row, kv_page); per-row scalar
-  prefetch (`cu_q_lens` / `kv_lens` / page table) drives the masked block
-  walk and the page-indirect BlockSpec index_map. `interpret=True` off-TPU
-  so CPU tier-1 exercises the real kernel math.
+- `_ragged_pallas`: Pallas grid over (head block, q block, batch row, kv
+  block of several pages); per-row scalar prefetch (`cu_q_lens` /
+  `kv_lens` / page table) drives the masked block walk and the
+  page-indirect BlockSpec index_maps. A step holds one [heads, q block, D]
+  tile and its online-softmax scratch, so the resident set does not grow
+  with T — blocked to what the chip's compiler accepts at 7B widths, not
+  yet tuned. On TPU a failure of this tier raises; `interpret=True`
+  off-TPU so CPU tier-1 exercises the real kernel body.
 - `_ragged_math`: lax.scan over page columns with a vectorized per-token
-  page gather and online-softmax accumulation — the XLA oracle/default.
+  page gather and online-softmax accumulation — the XLA reference and the
+  off-TPU default. The engine's bit-exactness contract is held on this
+  tier; kernel-vs-math comparisons use a tolerance (another blocking sums
+  in another order).
 
 Both handle the f32 pool and the int8 QuantizedTensor pool (weight
 [Hkv, P, bs, D] int8 + per-row absmax scales).
@@ -187,26 +194,39 @@ def _ragged_math(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
     return out.reshape(T, Hq, D).astype(q.dtype)
 
 
-def _ragged_kernel(S, npages, bs, group, quantized,
+# Kernel blocking. A grid step holds one q block of _Q_BLOCK tokens for
+# _HEADS_PER_STEP query heads against one kv block of ~_KV_BLOCK positions
+# (several pages), so the resident set is a few MB whatever T, Hq and the
+# page-table width are — the chip's compiler refuses (or never finishes)
+# a kernel that keeps the whole packed [T, Hq, D] stream resident.
+_Q_BLOCK = 128
+_KV_BLOCK = 128
+_HEADS_PER_STEP = 8
+_LANES = 128  # m/l scratch keep a lane-aligned last dim
+
+
+def _ragged_kernel(bs, group, ppb, quantized,
                    # scalar prefetch (order fixed by PrefetchScalarGridSpec)
                    cu_ref, kvl_ref, pt_ref,
                    # blocked operands
                    *refs):
-    """Grid (batch_row b, kv_page j). The whole packed q block stays
-    resident; each step streams ONE page of row b's KV (page-indirect
-    index_map off the prefetched page table) and folds it into the
-    online-softmax scratch of every query token — tokens outside row b or
-    past their causal limit are masked. Accumulators normalize into the
-    output on the final step."""
+    """Grid (head block h, q block i, batch row b, kv block j); b and j
+    are the reduction axes. A step folds `ppb` pages of row b's KV into
+    the online-softmax scratch of the q block's tokens — tokens outside
+    row b or past their causal limit are masked, and steps whose row
+    misses the q block (or whose pages lie past the row's KV extent) are
+    skipped. Heads lead every operand so both contractions are 3-D
+    batched dots; the accumulators normalize into the output block on the
+    q block's final reduction step."""
     import jax.experimental.pallas as pl
 
-    if quantized:
-        q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc, m, l = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc, m, l = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    T = q_ref.shape[0]
+    q_ref = refs[0]
+    n_in = 1 + ppb * (4 if quantized else 2)
+    pages = refs[1:n_in]
+    o_ref, acc, m, l = refs[n_in:]
+    i, b, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    n, tq, _ = q_ref.shape
+    kv_blk = ppb * bs
 
     @pl.when((b == 0) & (j == 0))
     def _init():
@@ -218,41 +238,62 @@ def _ragged_kernel(S, npages, bs, group, quantized,
     cu1 = cu_ref[b + 1]
     kvl = kvl_ref[b]
     q_len = cu1 - cu0
-    n_pages = (kvl + bs - 1) // bs
+    q_lo = i * tq
+    # the block's last in-row token bounds what any of its tokens may see
+    lim_max = kvl - q_len + (jnp.minimum(cu1, q_lo + tq) - cu0)
 
-    @pl.when((q_len > 0) & (j < n_pages))
+    @pl.when((q_len > 0) & (cu0 < q_lo + tq) & (cu1 > q_lo)
+             & (j * kv_blk < lim_max))
     def _accumulate():
-        k_blk = k_ref[:, 0].astype(jnp.float32)          # [Hkv, bs, D]
-        v_blk = v_ref[:, 0].astype(jnp.float32)
-        if quantized:
-            # from_int8: w * scales / 127.5 (per-row absmax)
-            k_blk = k_blk * ks_ref[:, 0].astype(jnp.float32) / 127.5
-            v_blk = v_blk * vs_ref[:, 0].astype(jnp.float32) / 127.5
-        Hkv = k_blk.shape[0]
-        qs = q_ref[...].astype(jnp.float32).reshape(T, Hkv, group, -1)
-        s = jnp.einsum("thgd,hkd->thgk", qs, k_blk,
-                       preferred_element_type=jnp.float32)  # [T,Hkv,g,bs]
-        t_ids = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-        in_row = (t_ids >= cu0) & (t_ids < cu1)          # [T, 1]
-        lim = kvl - q_len + (t_ids - cu0) + 1            # [T, 1]
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        mask = in_row & (kv_pos < lim)                   # [T, bs]
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        m_prev = m[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask[:, None, None, :], p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        m[...] = m_new
-        l[...] = l[...] * corr + p.sum(axis=-1)
-        acc[...] = acc[...] * corr[..., None] + jnp.einsum(
-            "thgk,hkd->thgd", p, v_blk,
-            preferred_element_type=jnp.float32)
+        def load(idx):
+            # ppb pages [hb, bs, D] -> one [n, kv_blk, D] kv block, each kv
+            # head repeated over its query-head group
+            per = 2 if quantized else 1
+            blks = []
+            for pg in range(ppb):
+                w = pages[(idx * ppb + pg) * per][:, 0]
+                if quantized:
+                    # from_int8: w * scales / 127.5 (per-row absmax)
+                    sc = pages[(idx * ppb + pg) * per + 1][:, 0]
+                    w = (w.astype(jnp.float32)
+                         * (sc.astype(jnp.float32) / 127.5))
+                blks.append(w)
+            blk = blks[0] if ppb == 1 else jnp.concatenate(blks, axis=1)
+            if group > 1:
+                hb = blk.shape[0]
+                blk = jnp.broadcast_to(
+                    blk[:, None], (hb, group) + blk.shape[1:]
+                ).reshape((n,) + blk.shape[1:])
+            return blk
 
-    @pl.when((b == S - 1) & (j == npages - 1))
+        k_blk, v_blk = load(0), load(1)
+        qs = q_ref[...].astype(k_blk.dtype)
+        s = jax.lax.dot_general(
+            qs, k_blk, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)            # [n, tq, kv_blk]
+        t_ids = q_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        in_row = (t_ids >= cu0) & (t_ids < cu1)            # [tq, 1]
+        lim = kvl - q_len + (t_ids - cu0) + 1              # [tq, 1]
+        kv_pos = j * kv_blk + jax.lax.broadcasted_iota(
+            jnp.int32, (1, kv_blk), 1)
+        mask = (in_row & (kv_pos < lim))[None]             # [1, tq, kv_blk]
+        s = jnp.where(mask, s, -1e30)
+        m_prev = m[:, :, :1]                               # [n, tq, 1]
+        l_prev = l[:, :, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m[...] = jnp.broadcast_to(m_new, m.shape)
+        l[...] = jnp.broadcast_to(
+            l_prev * corr + p.sum(axis=-1, keepdims=True), l.shape)
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)            # [n, tq, D]
+
+    @pl.when((b == pl.num_programs(2) - 1) & (j == pl.num_programs(3) - 1))
     def _finalize():
-        out = acc[...] / jnp.maximum(l[...], 1e-30)[..., None]
-        o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+        o_ref[...] = (acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
@@ -267,44 +308,71 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
     S, npages = page_indices.shape
     group = Hq // Hkv
 
-    def page_map(b, j, cu, kvl, pt):
-        return (0, pt[b, j], 0, 0)
+    tq = min(_Q_BLOCK, -(-T // 8) * 8)
+    t_pad = -(-T // tq) * tq
+    hb = max(1, min(Hkv, _HEADS_PER_STEP // group))  # kv heads per step
+    while Hkv % hb:
+        hb -= 1
+    n = hb * group
+    ppb = max(1, min(npages, _KV_BLOCK // bs))       # pages per kv block
+    nkv = -(-npages // ppb)
 
-    def whole(b, j, cu, kvl, pt):
-        return (0, 0, 0)
+    def page_map(pg):
+        def index(h, i, b, j, cu, kvl, pt):
+            # a step that will be skipped maps to scratch page 0: a block
+            # index that repeats between steps is not fetched again
+            page = j * ppb + pg
+            live = ((cu[b + 1] > cu[b]) & (cu[b] < (i + 1) * tq)
+                    & (cu[b + 1] > i * tq) & (page * bs < kvl[b]))
+            return (h, jnp.where(
+                live, pt[b, jnp.minimum(page, npages - 1)], 0), 0, 0)
+        return index
 
-    page_spec = pl.BlockSpec((Hkv, 1, bs, D), page_map)
-    scale_spec = pl.BlockSpec((Hkv, 1, bs, 1), page_map)
-    q_spec = pl.BlockSpec((T, Hq, D), whole)
+    def q_map(h, i, b, j, cu, kvl, pt):
+        return (h, i, 0)
 
-    if kq:
-        in_specs = [q_spec, page_spec, scale_spec, page_spec, scale_spec]
-        operands = (q * scale, k_pages.weight, k_pages.scales,
-                    v_pages.weight, v_pages.scales)
-    else:
-        in_specs = [q_spec, page_spec, page_spec]
-        operands = (q * scale, k_pages, v_pages)
+    q_spec = pl.BlockSpec((n, tq, D), q_map)
+    # heads lead: [Hq, t_pad, D], in the pool's dtype (f32 for int8 pools,
+    # whose pages dequantize to f32 like the math tier's)
+    qs = jnp.swapaxes(q * scale, 0, 1).astype(
+        jnp.float32 if kq else kw.dtype)
+    qs = jnp.pad(qs, ((0, 0), (0, t_pad - T), (0, 0)))
+
+    in_specs, operands = [q_spec], [qs]
+    for pages in (k_pages, v_pages):
+        for pg in range(ppb):
+            in_specs.append(pl.BlockSpec((hb, 1, bs, D), page_map(pg)))
+            if kq:
+                in_specs.append(pl.BlockSpec((hb, 1, bs, 1), page_map(pg)))
+                operands += [pages.weight, pages.scales]
+            else:
+                operands.append(pages)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, npages),
+        grid=(Hkv // hb, t_pad // tq, S, nkv),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((T, Hkv, group, D), jnp.float32),  # acc
-            pltpu.VMEM((T, Hkv, group), jnp.float32),     # running max
-            pltpu.VMEM((T, Hkv, group), jnp.float32),     # running sum
+            pltpu.VMEM((n, tq, D), jnp.float32),       # acc
+            pltpu.VMEM((n, tq, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((n, tq, _LANES), jnp.float32),  # running sum
         ],
     )
-    kernel = functools.partial(_ragged_kernel, S, npages, bs, group, kq)
+    kernel = functools.partial(_ragged_kernel, bs, group, ppb, kq)
     fn = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hq, t_pad, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel",
+                                 "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ragged_paged_attention",
     )
-    return fn(cu_q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
-              page_indices.astype(jnp.int32), *operands)
+    out = fn(cu_q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
+             page_indices.astype(jnp.int32), *operands)
+    return jnp.swapaxes(out, 0, 1)[:T]
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,
@@ -314,7 +382,8 @@ def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,
     q: [T, Hq, D] packed token stream; returns [T, Hq, D]. kv_lens must
     already include this step's tokens (post-write totals). Pad tokens
     (beyond cu_q_lens[-1]) return zeros-ish garbage — callers discard
-    them. impl: None/"auto" (kernel on TPU, math elsewhere), "math",
+    them. impl: None/"auto" (kernel on TPU — a kernel failure there
+    raises, it never becomes the math tier — and math elsewhere), "math",
     "pallas" (interpret-mode off TPU — the CPU tier-1 path through the
     real kernel body)."""
     global LAST_IMPL
@@ -325,15 +394,10 @@ def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,
     impl = impl or _env_str("PADDLE_RAGGED_IMPL", "auto")
     on_tpu = _on_tpu() and not _FORCE_XLA
     if impl == "pallas" or (impl == "auto" and on_tpu):
-        try:
-            out = _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices,
-                                 cu_q_lens, scale, interpret=not on_tpu)
-            LAST_IMPL = ("ragged-kernel" if on_tpu
-                         else "ragged-kernel-interpret")
-            return out
-        except Exception:
-            if impl == "pallas":
-                raise
+        out = _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices,
+                             cu_q_lens, scale, interpret=not on_tpu)
+        LAST_IMPL = "ragged-kernel" if on_tpu else "ragged-kernel-interpret"
+        return out
     LAST_IMPL = "ragged-math"
     return _ragged_math(q, k_pages, v_pages, kv_lens, page_indices,
                         cu_q_lens, scale)

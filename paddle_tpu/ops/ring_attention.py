@@ -222,13 +222,9 @@ def ring_attention(q, k, v, axis_name="sep", causal=False, scale=None,
     dim_ok = D % 128 == 0 or D in (64, 96, 128, 256)
     auto_kernel = _on_tpu() and S % 128 == 0 and dim_ok and not _FORCE_XLA
     if impl == "kernel" or (impl is None and auto_kernel):
-        try:
-            out = _ring_kernel(q, k, v, axis_name, causal, scale, block_k)
-            LAST_IMPL = "ring-splash"
-            return out
-        except Exception:
-            if impl == "kernel":
-                raise
+        out = _ring_kernel(q, k, v, axis_name, causal, scale, block_k)
+        LAST_IMPL = "ring-splash"
+        return out
     LAST_IMPL = "ring-block"
     return _ring_block_impl(q, k, v, axis_name, causal, scale, block_k)
 

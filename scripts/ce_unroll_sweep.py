@@ -3,8 +3,8 @@ barrier-chained unroll (FLAGS_fused_ce_unroll). Motivates why the unroll is
 OPT-IN: on CPU the opt-barrier chain is stripped during XLA optimization, so
 the unrolled chunks overlap and temp grows well past the loop's bound (and
 past the full-logits buffer fused-CE exists to avoid). On TPU opt-barrier is
-honored, so the chain should hold the one-chunk bound — measured on chip by
-scripts/perf_exp.py variants 11/12, not here.
+honored, so the chain should hold the one-chunk bound — that needs a chip
+run, not this script.
 
 Recorded result (8192×256×32000, chunk 2048 → 4 chunks, bf16 inputs):
   loop (unroll=0):      568 MB temp, 1 pre-opt barrier (remat's own)

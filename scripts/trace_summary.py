@@ -1,4 +1,4 @@
-"""Summarize a captured xprof trace (scripts/capture_trace.py artifact):
+"""Summarize a captured xprof trace (a `jax.profiler.trace` directory):
 per-category XLA-op busy time on the device track. Usage:
 
     python scripts/trace_summary.py xprof_traces/tpu/<ts>

@@ -1,0 +1,157 @@
+"""The main path's Pallas kernels compile for the chip, at real widths.
+
+This sandbox has no accelerator, but the TPU's compiler is installed and
+compiles for a chip that is *described* (a v5e 2x2 topology), not attached:
+what Mosaic refuses on the chip it refuses here — a slice off the tiling, a
+resident set past VMEM, an API the installed jax no longer has — at no chip
+time. Interpret-mode tests cannot see any of that. Each case lowers one
+kernel exactly as the ops module calls it, at LLaMA-7B widths (h4096 = 32
+heads x 128, page 16, T = prefill_chunk + max_seqs), and asserts the
+compiled program holds a Mosaic kernel (`tpu_custom_call`).
+
+This is the ONLY test file that describes a topology, and it does so inside
+a module-scoped fixture: only one process may load the TPU library, so the
+call must not run while any module is imported (pytest-xdist workers all
+import every test file) nor in a child process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, HKV_GQA, D, SEQ = 32, 8, 128, 2048
+PAGE, MAX_LEN, MAX_SEQS, PREFILL_CHUNK = 16, 2048, 4, 512
+T = PREFILL_CHUNK + MAX_SEQS       # the engine's packed token-stream width
+NPAGES = MAX_LEN // PAGE           # page-table width per sequence
+POOL = 1 + MAX_SEQS * NPAGES       # the engine's default pool (+ scratch)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    env = pytest.MonkeyPatch()
+    if "TPU_LOG_DIR" not in os.environ:
+        env.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile can be written to the persistent cache but not read
+    # back without a chip (the next one warns and compiles again): keep
+    # the cache off around these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    env.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _ragged(quantized):
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        quantization_utils as qu,
+    )
+
+    from paddle_tpu.ops.ragged_paged_attention import _ragged_pallas
+
+    def fn(q, k, v, kv_lens, page_indices, cu):
+        return _ragged_pallas(q, k, v, kv_lens, page_indices, cu,
+                              D ** -0.5, interpret=False)
+
+    def args(sds):
+        if quantized:
+            pool = qu.QuantizedTensor(
+                weight=sds((H, POOL, PAGE, D), jnp.int8),
+                scales=sds((H, POOL, PAGE, 1), jnp.float32))
+        else:
+            pool = sds((H, POOL, PAGE, D), jnp.bfloat16)
+        return (sds((T, H, D), jnp.bfloat16), pool, pool,
+                sds((MAX_SEQS,), jnp.int32),
+                sds((MAX_SEQS, NPAGES), jnp.int32),
+                sds((MAX_SEQS + 1,), jnp.int32))
+
+    return fn, args
+
+
+def _flash(grad):
+    from paddle_tpu.ops.flash_attention import _pallas_flash
+
+    def fwd(q, k, v):
+        return _pallas_flash(q, k, v, True, D ** -0.5)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def args(sds):
+        return (sds((2, H, SEQ, D), jnp.bfloat16),) * 3
+
+    return (bwd if grad else fwd), args
+
+
+def _splash_gqa():
+    from paddle_tpu.ops.flash_attention import _splash_impl
+
+    def fn(q, k, v):
+        return _splash_impl(q, k, v, True, D ** -0.5)
+
+    def args(sds):
+        kv = sds((2, HKV_GQA, SEQ, D), jnp.bfloat16)
+        return (sds((2, H, SEQ, D), jnp.bfloat16), kv, kv)
+
+    return fn, args
+
+
+def _paged_decode():
+    """jax's paged-attention kernel through ops/paged_attention.py's own
+    call (the test steers its platform predicate to the described chip)."""
+    from paddle_tpu.ops.paged_attention import paged_decode_attention
+
+    def fn(q, k, v, lengths, page_indices):
+        return paged_decode_attention(q, k, v, lengths, page_indices)
+
+    def args(sds):
+        pool = sds((H, POOL, PAGE, D), jnp.bfloat16)
+        return (sds((MAX_SEQS, H, D), jnp.bfloat16), pool, pool,
+                sds((MAX_SEQS,), jnp.int32),
+                sds((MAX_SEQS, NPAGES), jnp.int32))
+
+    return fn, args
+
+
+CASES = {
+    "ragged-bf16-pool": lambda: _ragged(quantized=False),
+    "ragged-int8-pool": lambda: _ragged(quantized=True),
+    "flash-fwd-s2048": lambda: _flash(grad=False),
+    "flash-bwd-s2048": lambda: _flash(grad=True),
+    "splash-gqa-kv8": _splash_gqa,
+    "paged-decode": _paged_decode,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, monkeypatch):
+    from paddle_tpu.ops import flash_attention
+
+    # jax.devices() still says CPU here: the tier choice follows the
+    # described chip in this test only, not through an option of the program
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    fn, args = CASES[case]()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(fn).lower(*args(sds)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,5 +1,5 @@
 """Compiled-program memory evidence for the perf-critical paths
-(BASELINE.md/PROFILE.md claims, verifiable without TPU hardware via XLA's
+(BASELINE.md claims, verifiable without TPU hardware via XLA's
 CompiledMemoryStats on the CPU backend — absolute numbers differ on TPU,
 but the asymptotics asserted here are backend-independent properties of
 the HLO).

@@ -4,9 +4,8 @@ used for TP, DP, and sharding alike)."""
 import jax
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from paddle_tpu.framework.jax_compat import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist

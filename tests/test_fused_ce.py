@@ -70,7 +70,7 @@ class TestChunkLoopUnroll:
     billed 8.2% of device time to while-loop control for a 3-iteration CE
     loop), and the barrier chain that sequences chunks on TPU present in
     the lowered program. The memory bound itself is TPU-only (XLA CPU
-    strips opt-barrier) — measured by scripts/perf_exp.py variants 11/12."""
+    strips opt-barrier) — not measured on the chip yet."""
 
     def _grad_fn(self, n=1024, h=64, v=8000, chunk=256):
         import jax

@@ -102,14 +102,53 @@ class TestRaggedKernel:
                                    rtol=1e-6, atol=1e-6)
 
     def test_int8_pool_pallas_matches_math(self):
-        """Both tiers dequantize with the same from_int8 math — the int8
-        pool path must agree bit-for-bit between them."""
+        """Both tiers dequantize with the same from_int8 math; they are two
+        implementations that sum in two orders (the kernel folds several
+        pages per step, the math tier one), so they agree to f32 rounding
+        of O(1) values, not bit for bit."""
         args, raw = _mixed_case(seed=5, quantized=True)
         ref = rpa.ragged_paged_attention(*args, impl="math")
         out = rpa.ragged_paged_attention(*args, impl="pallas")
         cu = raw[5]
-        np.testing.assert_array_equal(np.asarray(out)[:cu[-1]],
-                                      np.asarray(ref)[:cu[-1]])
+        np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
+                                   np.asarray(ref)[:cu[-1]],
+                                   rtol=0, atol=1e-6)
+
+    @pytest.mark.parametrize("T,S,npages,bs", [
+        (300, 3, 20, 16),   # three q blocks; kv blocks of 8 + 8 + 4 pages
+        (40, 2, 9, 8),      # one q block; kv block of 9 pages (odd width)
+    ])
+    def test_blocked_kernel_matches_math_across_blocks(self, T, S, npages,
+                                                       bs):
+        """The kernel's q blocks (128 tokens), head blocks and multi-page
+        kv blocks must tile a batch larger than any one of them: rows that
+        straddle q blocks, GQA groups, a page table that is not a multiple
+        of the kv block. Tolerance: two summation orders in f32."""
+        rng = np.random.RandomState(T)
+        Hq, Hkv, D = 16, 4, 32   # group 4 -> two kv heads per head block
+        P = 1 + S * npages
+        kp = jnp.asarray(rng.randn(Hkv, P, bs, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(Hkv, P, bs, D).astype(np.float32))
+        page_indices = jnp.asarray(
+            rng.permutation(np.arange(1, P)).reshape(S, npages)
+            .astype(np.int32))
+        # a decode row over history, a long chunk over a short history
+        # that straddles every q block, (an empty row); 7 pad tokens
+        q_lens = np.array([1, T - 1 - 7, 0][:S], np.int32)
+        kv_lens = np.where(q_lens > 0,
+                           q_lens + np.array([50, 9, 0][:S]), 0)
+        kv_lens = kv_lens.astype(np.int32)
+        assert kv_lens.max() <= npages * bs
+        cu = np.zeros(S + 1, np.int32)
+        cu[1:] = np.cumsum(q_lens)
+        q = jnp.asarray(rng.randn(T, Hq, D).astype(np.float32))
+        args = (q, kp, vp, jnp.asarray(kv_lens), page_indices,
+                jnp.asarray(cu))
+        ref = rpa.ragged_paged_attention(*args, impl="math")
+        out = rpa.ragged_paged_attention(*args, impl="pallas")
+        np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
+                                   np.asarray(ref)[:cu[-1]],
+                                   rtol=1e-5, atol=1e-5)
 
     def test_write_ragged_kv_places_tokens_and_scratches_pads(self):
         rng = np.random.RandomState(1)
@@ -138,6 +177,17 @@ class TestRaggedKernel:
             for off in range(bs):
                 if (pid, off) not in written:
                     assert not np.any(out[:, pid, off])
+
+
+def _aligned_like(a):
+    """A copy of `a` in a 64-byte-aligned buffer — the case in which the
+    CPU backend's jnp.asarray aliases numpy memory instead of copying."""
+    buf = np.zeros(a.size + 64, a.dtype)
+    off = (-buf.ctypes.data % 64) // a.itemsize
+    out = buf[off:off + a.size].reshape(a.shape)
+    out[...] = a
+    assert out.ctypes.data % 64 == 0
+    return out
 
 
 def _prompts(rng, lens, vocab=100):
@@ -174,6 +224,49 @@ class TestRaggedEngine:
                                 seed=3)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
+
+    @pytest.mark.parametrize("ragged", [True, False])
+    def test_dispatch_operands_do_not_alias_engine_arrays(self, model,
+                                                          ragged):
+        """The race behind the token mismatches that only some processes
+        showed (alignment decides, load times it), without a clock: the
+        engine mutates `lengths`/`page_table` in place right after an
+        ASYNC dispatch, so no program operand may share their memory — on
+        the CPU backend jnp.asarray of a 64-byte-aligned numpy buffer does
+        — and, read back after all the bookkeeping, each must still hold
+        what the host held at dispatch."""
+        eng = ContinuousBatchingEngine(model, max_seqs=4, page_size=16,
+                                       max_len=160, ragged=ragged)
+        eng.lengths = _aligned_like(eng.lengths)
+        eng.page_table = _aligned_like(eng.page_table)
+        calls = []  # (operands that mirror engine arrays, host values then)
+
+        def spy(builder, mirrors):
+            def build(*key):
+                fn = builder(*key)
+
+                def call(*args):
+                    calls.append(([(args[i], getattr(eng, name).copy())
+                                   for i, name in mirrors], args))
+                    return fn(*args)
+                return call
+            return build
+
+        # positional layouts of the two programs' operands
+        eng._decode_block_fn = spy(eng._decode_block_fn,
+                                   [(3, "page_table"), (4, "lengths")])
+        eng._ragged_fn = spy(eng._ragged_fn, [(9, "page_table")])
+        rng = np.random.RandomState(17)
+        eng.serve(_prompts(rng, (5, 21)), max_new_tokens=2 * eng.decode_block)
+        assert calls
+        for mirrored, args in calls:
+            for a in args:
+                if hasattr(a, "shape"):
+                    host = np.asarray(a)
+                    assert not np.shares_memory(host, eng.lengths)
+                    assert not np.shares_memory(host, eng.page_table)
+            for operand, then in mirrored:
+                np.testing.assert_array_equal(np.asarray(operand), then)
 
     def test_eos_mid_block_truncates_identically(self, model):
         rng = np.random.RandomState(13)

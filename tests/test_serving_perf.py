@@ -282,17 +282,25 @@ class TestPipelineMechanics:
         e0, e1 = pipe_pair
         assert e0.dispatch_lock is not e1.dispatch_lock
         outs = {}
+        # both serves start together, whatever the scheduler does: the
+        # test is about two engines stepping CONCURRENTLY
+        go = threading.Barrier(2, timeout=120)
 
         def drive(tag, eng):
             rng = np.random.RandomState(14)
             p = rng.randint(1, model.config.vocab_size, (9,)) \
                 .astype(np.int32)
-            outs[tag] = eng.serve([p], max_new_tokens=16)[0]
+            go.wait()
+            try:
+                outs[tag] = eng.serve([p], max_new_tokens=16)[0]
+            except Exception as e:  # noqa: BLE001 — surfaced by the assert
+                outs[tag] = e
 
         t = threading.Thread(target=drive, args=("bg", e1))
         t.start()
         drive("fg", e0)
         t.join(timeout=120)
+        assert not t.is_alive()
         np.testing.assert_array_equal(outs["fg"], outs["bg"])
         assert e0.idle() and e1.idle()
         # the bench baseline's shared-lock injection really is shared
