@@ -15,6 +15,9 @@ from ..engine import Finding, rule
 #: name: metrics registry, thread spans, request-trace, frontend families
 REG_ATTRS = {"counter", "gauge", "histogram", "bump",
              "span",
+             # the step log's spans (ISSUE 25): profiler annotations while
+             # the record is built, fanned out as spans when tracing is on
+             "annotation", "_phase",
              "child", "event", "begin", "span_at",
              "_class_hist"}
 

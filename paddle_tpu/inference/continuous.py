@@ -51,6 +51,7 @@ device compute instead of ping-ponging between them —
   in-process replicas therefore genuinely run concurrently once warm.
 """
 import hashlib
+import itertools
 import math
 import threading
 import time
@@ -78,10 +79,11 @@ from ..utils.metrics_bus import counters
 from ..utils.retry import RetryPolicy
 
 # serving telemetry (the Gemma-on-TPU serving comparison's vocabulary,
-# PAPERS.md): TTFT = serve-entry → first token per request; TPOT = decode
-# dispatch wall / tokens in the block. Gauges carry high-water marks so a
-# post-hoc snapshot still shows peak pressure. Always-on: per-request /
-# per-dispatch observes are noise against a jitted model call.
+# PAPERS.md): TTFT = serve-entry → first token per request; TPOT = one
+# block's own interval / tokens in the block (see _process_block). Gauges
+# carry high-water marks so a post-hoc snapshot still shows peak pressure.
+# Always-on: per-request / per-dispatch observes are noise against a jitted
+# model call.
 _M_TTFT = _registry.histogram("serve.ttft_s")
 _M_TPOT = _registry.histogram(
     "serve.tpot_s",
@@ -93,14 +95,10 @@ _M_TOKENS = _registry.counter("serve.tokens_out")
 _M_REQUESTS = _registry.counter("serve.requests")
 _M_PREFIX_HIT = _registry.counter("serve.prefix.hit_pages")
 _M_PREFIX_LOOKUP = _registry.counter("serve.prefix.lookup_pages")
-# data-plane pipeline metrics (ISSUE 6): host time hidden under an
-# in-flight decode dispatch, prefill chunks landed between decode blocks,
-# and warmup()'s AOT compile wall (the spike the per-replica warmup keeps
-# out of first requests)
-_M_OVERLAP = _registry.histogram(
-    "serve.dispatch_overlap_s",
-    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
-             0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
+# data-plane pipeline metrics (ISSUE 6): prefill chunks landed between
+# decode blocks, and warmup()'s AOT compile wall (the spike the per-replica
+# warmup keeps out of first requests). What the pipeline hides of the host
+# is read off the step log (observability/tracing.py).
 _M_CHUNKS = _registry.counter("serve.prefill_chunks")
 _M_WARMUP = _registry.histogram("serve.compile_warmup_s")
 # page-pool fragmentation gauges (ISSUE 8): where the pool's pages are —
@@ -238,6 +236,15 @@ class _StampedRLock:
 #: process-wide ``_DISPATCH_LOCK`` that serialized every jitted call of
 #: every replica behind one lock.
 _COMPILE_LOCK = _StampedRLock(name="inference.compile_lock")
+_ENGINE_SEQ = itertools.count()  # `engine` of a step record
+#: the spans of one dispatch as (span, first stamp, last stamp) over its
+#: step record (docs/OBSERVABILITY.md "The step log"): the dispatch's life,
+#: then its phases on the dispatcher thread
+STEP_PHASES = (("serve.step", "t_step0", "t_emit1"),
+               ("serve.pack", "t_pack0", "t_disp0"),
+               ("serve.decode", "t_disp0", "t_disp1"),
+               ("serve.decode.sync", "t_sync0", "t_ready"),
+               ("serve.emit", "t_ready", "t_emit1"))
 
 #: canonical greedy sampling tuple — every greedy request shares ONE
 #: compiled prefill/decode program regardless of the knob values passed
@@ -381,17 +388,18 @@ class _PrefillState:
 
 class _InflightBlock:
     """One dispatched-but-not-read-back decode block: the device token
-    array, the slot→request mapping frozen at dispatch time, and the
-    device-resident last-step row the NEXT block's feed chains from."""
+    array, the slot→request mapping frozen at dispatch time, the
+    device-resident last-step row the NEXT block's feed chains from, and
+    the dispatch's step record (completed at readback)."""
 
-    __slots__ = ("blk", "last", "k", "rows", "t0", "host", "cold")
+    __slots__ = ("blk", "last", "k", "rows", "step", "host", "cold")
 
-    def __init__(self, blk, last, k, rows, t0, host=None, cold=False):
+    def __init__(self, blk, last, k, rows, step, host=None, cold=False):
         self.blk = blk      # device [k, max_seqs] token block
         self.last = last    # device [max_seqs, 1] last-step tokens
         self.k = k
         self.rows = rows    # [(slot, req)] active at dispatch
-        self.t0 = t0
+        self.step = step    # the step-log record under construction
         self.host = host    # sync mode: tokens already read back in-lock
         self.cold = cold    # dispatched under a first-trace (compile) hold
 
@@ -495,6 +503,17 @@ class ContinuousBatchingEngine:
         # _settle_inflight) processed the in-flight block: step() returns
         # them on its next call so the frontend still finishes every handle
         self._pending_retired = []
+        # step log (observability/tracing.py): this engine's id in it, the
+        # entry stamp of the running step() call, admissions since the last
+        # record (bounded: a replica whose requests leave before a dispatch
+        # of these planes — the prefill role — never drains them), the
+        # previous block's readback stamp (the start of the next block's own
+        # interval) and the dispatcher thread's open phase annotation
+        self._engine_seq = next(_ENGINE_SEQ)
+        self._t_step0 = 0
+        self._admits = deque(maxlen=4 * self.max_seqs)
+        self._t_prev_ready = 0
+        self._phase_ann = None
         self.enable_prefix_cache = bool(enable_prefix_cache)
         if self.enable_prefix_cache and kv_cache_dtype == "int8":
             # a shared prefix would be re-read through the lossy int8
@@ -2107,6 +2126,7 @@ class ContinuousBatchingEngine:
         req.pages = pages
         req.slot = slot
         req.t_admit = time.monotonic()
+        self._admits.append((req.rid, req.t_enqueue, req.t_admit, true_len))
         sampling = req.sampling
         if self._ragged:
             # ---- ragged admission (ISSUE 20): reserve pages and install
@@ -2451,8 +2471,39 @@ class ContinuousBatchingEngine:
         the new tenant's prefill/decode before it is ever read). The sync
         path (``async_decode=False``) dispatches and reads back in one
         call — the pre-pipeline behavior, kept as the bench baseline."""
-        if self._ragged:
-            return self._step_ragged()
+        self._t_step0 = time.monotonic_ns()
+        with _trace.annotation("serve.step"):
+            try:
+                return (self._step_ragged() if self._ragged
+                        else self._step_ladder())
+            finally:
+                self._phase(None)  # a raise left a phase open
+
+    def _phase(self, name, step=None, stamp=None):
+        """Move the dispatcher thread's phase annotation on — the open one
+        closes and ``name`` (a span of STEP_PHASES, or None) opens
+        — stamping ``step[stamp]`` on the step log's clock between them.
+        For ``serve.pack`` → ``serve.decode``, which hand over inside a
+        dispatch function where no ``with`` block can span them."""
+        ann, self._phase_ann = self._phase_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if step is not None:
+            step[stamp] = time.monotonic_ns()
+        if name is not None:
+            ann = self._phase_ann = _trace.annotation(name)
+            ann.__enter__()
+
+    def _begin_step(self, kind, k, chain):
+        """The step record of the dispatch about to be packed."""
+        step = _trace.new_step(
+            engine=self._engine_seq, kind=kind, k=k,
+            chained=chain is not None, t_step0=self._t_step0)
+        self._phase("serve.pack", step, "t_pack0")
+        return step
+
+    def _step_ladder(self):
+        """step() for the bucket-ladder plane (``ragged=False``)."""
         # requests that retired under an out-of-band _settle_inflight
         # readback surface here, so the frontend's step-driven finish path
         # sees every terminal request exactly once
@@ -2567,6 +2618,7 @@ class ContinuousBatchingEngine:
         lora_rank = self._active_lora_rank
         state = self._captured_state()
         k = self.decode_block
+        step = self._begin_step("mixed", k, chain)
         S = self.max_seqs
         T = self._ragged_tokens
         budget = self._ragged_chunk
@@ -2679,7 +2731,8 @@ class ContinuousBatchingEngine:
         host = None
         t0 = time.monotonic()
         try:
-            with self._locked_dispatch(*progs), _trace.span("serve.decode"):
+            with self._locked_dispatch(*progs):
+                self._phase("serve.decode", step, "t_disp0")
                 if sampling[0]:
                     idx_mat = (idxs[None, :]
                                + np.arange(k, dtype=np.int32)[:, None])
@@ -2689,9 +2742,11 @@ class ContinuousBatchingEngine:
                     keys = jnp.zeros((k, S, 2), jnp.uint32)
                 blk, pools = self.retry_policy.run(dispatch,
                                                    name="serve.decode")
+                self._phase(None, step, "t_disp1")
                 if not self.async_decode:
-                    host = np.asarray(blk)  # serve-readback-ok
+                    host = self._read_back_in_lock(step, blk)
         except Exception as e:
+            self._phase(None)
             if not _compilemem.is_oom(e):
                 raise  # the decode seam's contract: an outage ends serve()
             # an OOM is the prompts' — the chunk tokens are what size this
@@ -2743,7 +2798,15 @@ class ContinuousBatchingEngine:
             # and each scan write lands one BEHIND its dispatch count (the
             # boundary token fed at position true_len, not true_len+1)
             self.lengths[slot] -= 1
-        return _InflightBlock(blk, last, k, part, t0, host=host, cold=cold)
+        # the kernel's own extents: q_len tokens fed, kv_len = the row's
+        # tokens AFTER this dispatch's first write (kv_lens = lengths + q)
+        self._dispatched(step, cold, [
+            *((r.rid, "d", 1, int(lengths_op[slot]) + 1)
+              for slot, r in part if slot not in chunk_rows),
+            *((st.req.rid, "g" if final else "c", take,
+               int(lengths_op[slot]) + take)
+              for slot, st, take, final in sched)])
+        return _InflightBlock(blk, last, k, part, step, host=host, cold=cold)
 
     def _dispatch_decode(self, chain=None):
         """Dispatch ONE decode block over the current active set WITHOUT
@@ -2782,6 +2845,7 @@ class ContinuousBatchingEngine:
         else:
             k = min(self.decode_block, remaining)
             k = 1 << (k.bit_length() - 1)
+        step = self._begin_step("decode", k, chain)
         rows = list(self._active.items())
         # a chained slot must still belong to the SAME request — a slot
         # retired and re-admitted while the block was in flight feeds its
@@ -2871,8 +2935,9 @@ class ContinuousBatchingEngine:
         if sampling[0]:
             progs.append(("keys", k))
         host = None
-        t0 = time.monotonic()  # dispatch epoch: TPOT = readback - t0 per k
-        with self._locked_dispatch(*progs), _trace.span("serve.decode"):
+        t0 = time.monotonic()  # the compile note's and devprof's epoch
+        with self._locked_dispatch(*progs):
+            self._phase("serve.decode", step, "t_disp0")
             if sampling[0]:
                 idx_mat = idxs[None, :] + np.arange(k, dtype=np.int32)[:, None]
                 keys = _KEYS_FROM_BASE(jnp.asarray(bases),
@@ -2881,13 +2946,9 @@ class ContinuousBatchingEngine:
                 # greedy ignores the keys entirely — skip the device work
                 keys = jnp.zeros((k, self.max_seqs, 2), jnp.uint32)
             blk, pools = self.retry_policy.run(dispatch, name="serve.decode")
+            self._phase(None, step, "t_disp1")
             if not self.async_decode:
-                # legacy sync semantics: the readback happens INSIDE the
-                # lock, exactly like the pre-pipeline engine — the lock
-                # covers the whole device round trip, which is what made
-                # replicas sharing a lock serialize their compute. The
-                # async path's readback is lock-free in _process_block.
-                host = np.asarray(blk)  # serve-readback-ok
+                host = self._read_back_in_lock(step, blk)
         self.pools = list(pools)  # lint: shared-mutation-without-lock-ok (engine fields are dispatcher-owned — single-threaded by contract)
         cold = self._last_dispatch_cold
         if _trace.enabled() and cold:
@@ -2920,45 +2981,77 @@ class ContinuousBatchingEngine:
         # what per-token emit accounting would produce (+k per block); a
         # slot that turns out to have finished mid-block is zeroed at
         # retire, so the overshoot never leaks
+        self._dispatched(step, cold, [
+            (r.rid, "d", 1, min(int(self.lengths[slot]), int(caps[slot])) + 1)
+            for slot, r in rows])
         for slot, r in rows:
             r.n_dispatched += k
             self.lengths[slot] += k
-        return _InflightBlock(blk, last, k, rows, t0, host=host, cold=cold)
+        return _InflightBlock(blk, last, k, rows, step, host=host, cold=cold)
+
+    def _read_back_in_lock(self, step, blk):
+        """Sync mode (``async_decode=False``): the readback happens INSIDE
+        the dispatch lock, exactly like the pre-pipeline engine — the lock
+        covers the whole device round trip, which is what made replicas
+        sharing a lock serialize their compute. The async path's readback
+        is lock-free in _process_block."""
+        step["t_sync0"] = time.monotonic_ns()
+        with _trace.annotation("serve.decode.sync"):
+            host = np.asarray(blk)  # serve-readback-ok
+        step["t_ready"] = time.monotonic_ns()
+        return host
+
+    def _dispatched(self, step, cold, rows):
+        """The dispatch went out: its rows ``(rid, role, q_len, kv_len)``
+        and the admissions since the previous record go on its record."""
+        step["cold"] = cold
+        step["rows"] = rows
+        step["admits"] = list(self._admits)
+        self._admits.clear()
 
     def _process_block(self, rec):
         """The decode pipeline's designated readback point: block tokens
-        come to the host, per-request emit/retire runs, TPOT lands."""
-        if self.async_decode:
-            # host time that ran while the device executed this block —
-            # the latency the double-buffering hides per block
-            _M_OVERLAP.observe(time.monotonic() - rec.t0)
-        with _trace.span("serve.decode.sync"):
-            try:
-                block = (rec.host if rec.host is not None
-                         else np.asarray(rec.blk))  # serve-readback-ok
-            except Exception as e:
-                # async-path OOM surfaces at readback, outside the
-                # dispatch lock — same forensics seam as _locked_dispatch
-                _compilemem.maybe_oom_report(e, program="serve.decode_block")
-                raise
-        # wall from dispatch to readback, normalized per token: the TPOT
-        # the serving comparison papers report
-        block_wall = time.monotonic() - rec.t0
+        come to the host, per-request emit/retire runs, TPOT lands, and the
+        dispatch's step record is completed and committed."""
+        step = rec.step
+        if rec.host is not None:
+            block = rec.host  # sync mode stamped its readback in the lock
+        else:
+            step["t_sync0"] = time.monotonic_ns()
+            with _trace.annotation("serve.decode.sync"):
+                try:
+                    block = np.asarray(rec.blk)  # serve-readback-ok
+                except Exception as e:
+                    # async-path OOM surfaces at readback, outside the
+                    # dispatch lock — same forensics seam as
+                    # _locked_dispatch
+                    _compilemem.maybe_oom_report(
+                        e, program="serve.decode_block")
+                    raise
+            step["t_ready"] = time.monotonic_ns()
+        t_ready = step["t_ready"]
+        # the block's OWN interval, normalized per token: with one block
+        # always in flight the device starts this one when the previous
+        # one's tokens became ready, not when this one was dispatched
+        # (dispatch→readback would span two blocks)
+        block_wall = (t_ready - max(step["t_disp1"], self._t_prev_ready)) / 1e9
+        self._t_prev_ready = t_ready
         _M_TPOT.observe(block_wall / rec.k)
         if _trace.enabled() and not rec.cold:
-            # serving goodput: dispatch→readback is the decode slice (under
-            # async overlap it runs concurrently with host_emit/admit — the
-            # split reports attribution, not a partition of wall clock). A
-            # cold block already landed in 'compile' at dispatch.
+            # serving goodput: the decode slice (under async overlap it
+            # runs concurrently with host_emit/admit — the split reports
+            # attribution, not a partition of wall clock). A cold block
+            # already landed in 'compile' at dispatch.
             _goodput.serving_note("decode", block_wall)
         self.stats["decode_steps"] += rec.k
         retired = []
-        t_e0 = time.monotonic()
-        with _trace.span("serve.emit"):
+        emits = step["emits"] = []
+        with _trace.annotation("serve.emit"):
             for slot, r in rec.rows:
                 if r.finished or self._active.get(slot) is not r:
                     # retired while in flight (cancel/timeout/reroute):
                     # its overshoot tokens are discarded
+                    emits.append((r.rid, 0))
                     continue
                 if r.t_first_token is None:
                     # ragged graduation: the first token materializes at
@@ -2981,7 +3074,6 @@ class ContinuousBatchingEngine:
                     r.n_generated += 1
                     r.last_token = tok
                     emitted += 1
-                    _M_TOKENS.inc()
                     if r.on_token is not None:
                         r.on_token(r.rid, tok)
                     if r.n_generated >= r.max_new_tokens or (
@@ -2990,11 +3082,22 @@ class ContinuousBatchingEngine:
                         # mid-block EOS: rest of the block is discarded
                         retired.append(self._retire(slot))
                         break
+                _M_TOKENS.inc(emitted)
+                emits.append((r.rid, emitted))
                 if r.trace is not None:
                     r.trace.event("emit", tokens=emitted,
                                   n_generated=r.n_generated)
+        step["t_emit1"] = time.monotonic_ns()
+        _trace.commit_step(step, STEP_PHASES)
         if _trace.enabled():
-            _goodput.serving_note("host_emit", time.monotonic() - t_e0)
+            _goodput.serving_note("host_emit",
+                                  (step["t_emit1"] - t_ready) / 1e9)
+            # not a span(): span.serve.admit_s holds the batch path's sweeps
+            for rid, t_enqueue, t_admit, n_prompt in step["admits"]:
+                _trace.emit_record(_trace.span_record(
+                    "serve.admit", t_enqueue * 1e9, t_admit * 1e9,
+                    "serve.step", step=step["seq"], rid=rid,
+                    n_prompt=n_prompt))
         return retired
 
     def drain(self):

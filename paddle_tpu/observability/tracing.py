@@ -17,6 +17,19 @@ Request-scoped trace records (observability/request_trace.py) ride the
 same ring and sinks via :func:`emit_record`, so one ``spans.<rank>.jsonl``
 file carries both streams and scripts/trace_view.py can join them.
 
+The **step log** (:func:`new_step`, :func:`commit_step`,
+:func:`step_records`) is the always-on part: one record per dispatch of a
+stepping loop — the serving engine's (inference/continuous.py owns the
+fields and the phase table) — with stamps on ``time.monotonic_ns()`` and
+counts, in a bounded ring of its own. It is per dispatch (a few a second),
+never per token, so it follows the metric registry's cost model
+("publication is always on"), not the span's. While a record is built,
+each phase sits inside :func:`annotation`, a
+``jax.profiler.TraceAnnotation``: any xplane taken of the process shows the
+dispatcher thread beside the device lines, on the profiler's own clock.
+With tracing enabled the completed record also fans out as ordinary span
+records, a parent and its phases.
+
 Cost contract (asserted in tests/test_telemetry.py like chaos.site's):
 **disabled, an attr-less span is one module-global load + a None/False
 check** returning a shared no-op context manager — no allocation, no clock
@@ -31,6 +44,7 @@ scopes and docs/OBSERVABILITY.md).
 """
 import atexit
 import collections
+import itertools
 import json
 import os
 import sys
@@ -40,11 +54,16 @@ import time
 from ..utils.envs import env_bool, env_str
 
 __all__ = ["span", "enable", "disable", "enabled", "last_spans",
-           "add_jsonl_sink", "clear_sinks", "JsonlSpanSink", "emit_record"]
+           "add_jsonl_sink", "clear_sinks", "JsonlSpanSink", "emit_record",
+           "annotation", "new_step", "commit_step", "step_records",
+           "span_record"]
 
 _ENABLED = None           # tri-state: None = resolve from env on first use
 _RING_DEFAULT = 512
 _ring = collections.deque(maxlen=_RING_DEFAULT)
+#: the step log: ~27 minutes of dispatches at 5 a second
+steps = collections.deque(maxlen=8192)
+_step_seq = itertools.count()
 _sinks = []
 _local = threading.local()
 _tids = {}
@@ -189,8 +208,9 @@ def last_spans(n=64):
 
 
 def clear():
-    """Test hook: drop captured spans (sinks untouched)."""
+    """Test hook: drop captured spans and step records (sinks untouched)."""
     _ring.clear()
+    steps.clear()
 
 
 class _NullSpan:
@@ -302,3 +322,58 @@ def span(name, **attrs):
     if not (e if e is not None else _resolve_enabled()):
         return _NULL
     return _Span(name, attrs)
+
+
+# ---- the step log ----------------------------------------------------------
+
+def annotation(name):
+    """``jax.profiler.TraceAnnotation(name)``: a host event in whatever
+    profiler session is open, one flag check when none is. sys.modules
+    probe, as in ``_emit``: the telemetry layer never imports jax, and a
+    process that has not loaded it has no device trace to sit beside."""
+    prof = sys.modules.get("jax.profiler")
+    return _NULL if prof is None else prof.TraceAnnotation(name)
+
+
+def new_step(**fields):
+    """A step record under construction: the process-wide ``seq`` plus the
+    caller's identity fields. The caller fills in stamps
+    (``time.monotonic_ns()``, keys ``t_*``) and counts, then
+    :func:`commit_step`."""
+    return {"seq": next(_step_seq), **fields}
+
+
+def span_record(name, t0_ns, t1_ns, parent=None, **attrs):
+    """A span record from two stamps on the step log's clock. On Linux
+    ``monotonic_ns`` and the spans' ``perf_counter_ns`` read the same
+    clock, so ``ts_us`` lines up with :func:`span`'s."""
+    return {"name": name, "ts_us": t0_ns / 1e3,
+            "dur_us": (t1_ns - t0_ns) / 1e3, "time": time.time(),
+            "pid": os.getpid(), "tid": _small_tid(), "parent": parent,
+            "depth": 0 if parent is None else 1, "attrs": attrs}
+
+
+def commit_step(rec, phases=()):
+    """Append a completed step record to the ring — always. With tracing
+    enabled, also fan it out as spans, ``phases`` naming them as ``(span,
+    first stamp, last stamp)`` over the record's own keys: the first is the
+    parent and carries the record's counts (every field that is not a
+    stamp) with ``step=seq``, the others are its children with ``step``."""
+    steps.append(rec)
+    if not (phases and enabled()):
+        return
+    seq = rec["seq"]
+    counts = {k: v for k, v in rec.items()
+              if k != "seq" and not k.startswith("t_")}
+    (parent, a, b), *children = phases
+    spans = [span_record(parent, rec[a], rec[b], step=seq, **counts)]
+    spans += [span_record(name, rec[a], rec[b], parent, step=seq)
+              for name, a, b in children]
+    for span_rec in spans:
+        _emit(span_rec, span_rec["dur_us"])
+
+
+def step_records(n=None):
+    """The step log, oldest first (the last ``n`` records, or all)."""
+    buf = list(steps)
+    return buf if n is None else buf[-n:]
