@@ -9,6 +9,20 @@ of jax's Pallas TPU `paged_attention` kernel — plus a per-sequence page table
 occupancy (sum of actual context lengths, page-granular), not by
 B × max_len as the dense fixed-shape cache is.
 
+The pool's layout contract (stated here, for both writers and both kernels):
+the pool is [Hkv, P, bs, D] in the default row-major layout, from a
+program's parameter to its result, because both Mosaic kernels (jax's
+`paged_attention`, `_ragged_pallas`) read it so. A write must NOT be an XLA
+scatter whose window covers Hkv (`pages.at[:, page, off, :].set(...)`): the
+TPU compiler lays a scatter's operand out with the window dims minor
+([P, bs, Hkv, D] physically), carries the pool through the decode scan in
+that layout, and copies the whole pool back before every kernel call — 55%
+of the serving cell's device time when it was found (PERF.md, PR 27). So the
+writers make the head a scattered index: `write_token_kv` scatters rows
+(window D), `write_ragged_kv` whole pages (window bs x D).
+tests/test_chip_compile.py compiles both program shapes for a described v5e
+and fails if a pool-shaped copy comes back.
+
 Two decode tiers, chosen at trace time like ops/flash_attention.py:
 - kernel: `jax.experimental.pallas.ops.tpu.paged_attention` on TPU;
 - math: one vectorized page-table gather plus a masked dense softmax
@@ -82,6 +96,25 @@ def _dequantize(weight, scales, dtype=jnp.float32):
     return qu.from_int8(weight, scales, dtype=dtype)
 
 
+def store_kv(pages, new, put):
+    """The two writers' common half: `new` [N, Hkv, D] becomes rows in the
+    pool's own dtype, head-major like the pool, and every plane of the pool
+    (one array, or the int8 pool's values and scales) goes through
+    `put(plane, rows [Hkv, N, last])`."""
+    rows = jnp.swapaxes(new, 0, 1)
+    if is_quantized(pages):
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            quantization_utils as qu,
+        )
+
+        qt = qu.quantize_to_int8(rows.astype(jnp.float32))
+        return type(pages)(
+            weight=put(pages.weight, qt.weight),
+            scales=put(pages.scales, qt.scales.astype(pages.scales.dtype)),
+        )
+    return put(pages, rows.astype(pages.dtype))
+
+
 def write_token_kv(pages, page_indices, lengths, new):
     """Scatter one new token's K or V into the pool.
 
@@ -90,26 +123,19 @@ def write_token_kv(pages, page_indices, lengths, new):
     the HBM-bandwidth lever for decode). new: [B, Hkv, D]; the token lands
     at logical position `lengths[b]` → page page_indices[b, lengths[b]//bs],
     offset lengths[b] % bs. Pages belong to exactly one sequence, so rows
-    never collide."""
+    never collide (rows routed to the scratch page, only with each other).
+    The head is a scattered index: layout contract, module docstring."""
     bs = (pages.weight if is_quantized(pages) else pages).shape[2]
     page_of = jnp.take_along_axis(
         page_indices, (lengths // bs)[:, None], axis=1
     )[:, 0]  # [B]
     off = lengths % bs  # [B]
-    new_hb = jnp.swapaxes(new, 0, 1)  # [Hkv, B, D]
-    if is_quantized(pages):
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            quantization_utils as qu,
-        )
 
-        qt = qu.quantize_to_int8(new_hb.astype(jnp.float32))
-        return type(pages)(
-            weight=pages.weight.at[:, page_of, off, :].set(qt.weight),
-            scales=pages.scales.at[:, page_of, off, :].set(
-                qt.scales.astype(pages.scales.dtype)),
-        )
-    # advanced-index scatter: for each b, all kv heads at once
-    return pages.at[:, page_of, off, :].set(new_hb.astype(pages.dtype))
+    def put(plane, rows):
+        h = jnp.arange(plane.shape[0])[:, None]
+        return plane.at[h, page_of[None], off[None], :].set(rows)
+
+    return store_kv(pages, new, put)
 
 
 def _paged_math(q, k_pages, v_pages, lengths, page_indices, scale):

@@ -34,7 +34,9 @@ Two tiers, same contract as the decode kernel:
   in another order).
 
 Both handle the f32 pool and the int8 QuantizedTensor pool (weight
-[Hkv, P, bs, D] int8 + per-row absmax scales).
+[Hkv, P, bs, D] int8 + per-row absmax scales). The pool's layout contract
+(default layout; no write whose scatter window covers Hkv) is stated once,
+in ops/paged_attention.py's docstring, and holds for `write_ragged_kv`.
 """
 import dataclasses
 import functools
@@ -43,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.envs import env_str as _env_str
-from .paged_attention import _dequantize, is_quantized
+from .paged_attention import _dequantize, is_quantized, store_kv
 
 LAST_IMPL = None  # "ragged-kernel" | "ragged-kernel-interpret" | "ragged-math"
 
@@ -90,30 +92,53 @@ class RaggedLayerCache:
 
 
 def write_ragged_kv(pages, page_indices, row_of, token_pos, valid, new):
-    """Scatter a packed token stream's K or V rows into the pool.
+    """Store a packed token stream's K or V rows in the pool.
 
     new: [T, Hkv, D]. Token t lands at absolute position token_pos[t] of
     row row_of[t] -> page page_indices[row_of[t], token_pos[t]//bs],
     offset token_pos[t] % bs. Invalid (pad) tokens are routed to the
-    scratch page 0 offset 0; their duplicate scatter writes collide only
-    with each other, and the scratch page is never read."""
+    scratch page 0 offset 0; they collide only with each other, and the
+    scratch page is never read.
+
+    The stream is written a page at a time (`_merge_pages`), which needs
+    what the packed stream gives: a row's tokens are neighbours at
+    consecutive positions, pads trail, and rows write pages of their own.
+    Such a stream changes page at most T//bs + 2 S times, pads included."""
     bs = (pages.weight if is_quantized(pages) else pages).shape[2]
     page_of = jnp.where(
         valid, page_indices[row_of, token_pos // bs], 0)  # [T]
     off = jnp.where(valid, token_pos % bs, 0)             # [T]
-    new_ht = jnp.swapaxes(new, 0, 1)                      # [Hkv, T, D]
-    if is_quantized(pages):
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            quantization_utils as qu,
-        )
+    n_runs = row_of.shape[0] // bs + 2 * page_indices.shape[0] + 1
+    return store_kv(
+        pages, new,
+        lambda plane, rows: _merge_pages(plane, page_of, off, rows, n_runs))
 
-        qt = qu.quantize_to_int8(new_ht.astype(jnp.float32))
-        return type(pages)(
-            weight=pages.weight.at[:, page_of, off, :].set(qt.weight),
-            scales=pages.scales.at[:, page_of, off, :].set(
-                qt.scales.astype(pages.scales.dtype)),
-        )
-    return pages.at[:, page_of, off, :].set(new_ht.astype(pages.dtype))
+
+def _merge_pages(plane, page_of, off, rows, n_runs):
+    """rows [Hkv, T, last] -> plane[:, page_of[t], off[t]], by whole pages.
+
+    A run is a stretch of the stream that stays on one page. Each run's
+    page is read, its new rows are laid over it, and the page is written
+    back: T//bs + 2 S scatter rows of bs x last a head where a scatter by
+    token has T rows of `last` (0.25 ms against 1.26 ms a write at the
+    serving cell's widths, PERF.md PR 27). Two runs on one page would each
+    write back the other's old rows, hence the stream contract above; on
+    the scratch page that is harmless. The head is a scattered index
+    (layout contract, ops/paged_attention.py)."""
+    Hkv, T, last = rows.shape
+    P, bs = plane.shape[1:3]
+    turns = (page_of[1:] != page_of[:-1]).astype(jnp.int32)
+    run_of = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(turns)])
+    # unused runs point past the pool: read clipped, written nowhere
+    run_page = jnp.full((n_runs,), P, jnp.int32).at[run_of].set(
+        page_of, mode="drop")
+    token_at = jnp.full((n_runs * bs,), T, jnp.int32).at[
+        run_of * bs + off].set(jnp.arange(T), mode="drop")
+    is_new = (token_at < T).reshape(1, n_runs, bs, 1)
+    fresh = jnp.take(rows, token_at, axis=1, mode="clip").reshape(
+        Hkv, n_runs, bs, last)
+    at = plane.at[jnp.arange(Hkv)[:, None], run_page[None]]
+    return at.set(jnp.where(is_new, fresh, at.get(mode="clip")), mode="drop")
 
 
 def _ragged_meta(cu_q_lens, row_of, kv_lens):

@@ -9,12 +9,25 @@ kernel exactly as the ops module calls it, at LLaMA-7B widths (h4096 = 32
 heads x 128, page 16, T = prefill_chunk + max_seqs), and asserts the
 compiled program holds a Mosaic kernel (`tpu_custom_call`).
 
+The last two cases guard a layout, not a kernel: the KV-pool writers and the
+two paged kernels in the serving engine's two program shapes (a decode block:
+writer -> paged kernel in an 8-step `lax.scan`; a mixed step: ragged writer ->
+ragged kernel, then the 7-step scan), two layers at the benchmark cell's
+widths, pools donated. A write expressed as an XLA scatter whose window covers
+the head axis makes the TPU compiler keep the pool in another layout than the
+kernels read, and re-lay out the whole pool around every kernel call (55% of
+device time when it was found, PERF.md PR 27). A CPU compile shows nothing of
+it, so these two are the only guard a CPU suite can have against the layout
+coming back: no pool-shaped `copy` in the compiled text, temporaries under
+one pool.
+
 This is the ONLY test file that describes a topology, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so the
 call must not run while any module is imported (pytest-xdist workers all
 import every test file) nor in a child process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -141,17 +154,113 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(case, one_chip, monkeypatch):
+# the serving cell's widths (benchmarks/workloads/deepseek7b-chat-steady.json)
+CELL_HKV, CELL_ROWS, CELL_K, CELL_LAYERS = 32, 16, 8, 2
+CELL_T = PREFILL_CHUNK + CELL_ROWS
+CELL_POOL = (CELL_HKV, 1 + CELL_ROWS * NPAGES, PAGE, D)
+
+
+def _decode_scan(pools, q, new, table, lengths, steps):
+    """`steps` decode steps as the engine's scan body runs them: each layer
+    writes one token a row into K and V, then reads both pools."""
+    from paddle_tpu.ops.paged_attention import (
+        paged_decode_attention, write_token_kv,
+    )
+
+    def body(carry, _):
+        pools_c, lens = carry
+        acc, written = jnp.zeros_like(q), []
+        for kp, vp in pools_c:
+            kp = write_token_kv(kp, table, lens, new)
+            vp = write_token_kv(vp, table, lens, new)
+            acc += paged_decode_attention(q, kp, vp, lens + 1, table)
+            written.append((kp, vp))
+        return (tuple(written), lens + 1), acc
+
+    (pools, _), outs = jax.lax.scan(body, (pools, lengths), None,
+                                    length=steps)
+    return outs, pools
+
+
+def _decode_block_shape():
+    def fn(pools, q, new, table, lengths):
+        return _decode_scan(pools, q, new, table, lengths, CELL_K)
+
+    def args(sds):
+        pool = sds(CELL_POOL, jnp.bfloat16)
+        q = sds((CELL_ROWS, CELL_HKV, D), jnp.bfloat16)
+        return (((pool, pool),) * CELL_LAYERS, q, q,
+                sds((CELL_ROWS, NPAGES), jnp.int32),
+                sds((CELL_ROWS,), jnp.int32))
+
+    return fn, args
+
+
+def _mixed_step_shape():
+    from paddle_tpu.ops.ragged_paged_attention import (
+        _ragged_pallas, write_ragged_kv,
+    )
+
+    def fn(pools, q_t, new_t, q, new, table, lengths, cu, row_of, token_pos,
+           valid):
+        kv_lens = lengths + cu[1:] - cu[:-1]
+        acc, written = jnp.zeros_like(q_t), []
+        for kp, vp in pools:
+            kp = write_ragged_kv(kp, table, row_of, token_pos, valid, new_t)
+            vp = write_ragged_kv(vp, table, row_of, token_pos, valid, new_t)
+            acc += _ragged_pallas(q_t, kp, vp, kv_lens, table, cu,
+                                  D ** -0.5, interpret=False)
+            written.append((kp, vp))
+        return acc, _decode_scan(tuple(written), q, new, table, kv_lens,
+                                 CELL_K - 1)
+
+    def args(sds):
+        pool = sds(CELL_POOL, jnp.bfloat16)
+        q_t = sds((CELL_T, CELL_HKV, D), jnp.bfloat16)
+        q = sds((CELL_ROWS, CELL_HKV, D), jnp.bfloat16)
+        per_token = sds((CELL_T,), jnp.int32)
+        return (((pool, pool),) * CELL_LAYERS, q_t, q_t, q, q,
+                sds((CELL_ROWS, NPAGES), jnp.int32),
+                sds((CELL_ROWS,), jnp.int32),
+                sds((CELL_ROWS + 1,), jnp.int32), per_token, per_token,
+                sds((CELL_T,), jnp.bool_))
+
+    return fn, args
+
+
+POOL_CASES = {
+    "decode-block": _decode_block_shape,
+    "mixed-step": _mixed_step_shape,
+}
+
+
+def _compile_for_chip(shape, one_chip, monkeypatch, **jit_kw):
     from paddle_tpu.ops import flash_attention
 
     # jax.devices() still says CPU here: the tier choice follows the
-    # described chip in this test only, not through an option of the program
+    # described chip in these tests only, not through an option of the program
     monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
-    fn, args = CASES[case]()
+    fn, args = shape()
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(fn).lower(*args(sds)).compile()
+    compiled = jax.jit(fn, **jit_kw).lower(*args(sds)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, monkeypatch):
+    _compile_for_chip(CASES[case], one_chip, monkeypatch)
+
+
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_pool_stays_in_the_kernels_layout(case, one_chip, monkeypatch):
+    compiled = _compile_for_chip(POOL_CASES[case], one_chip, monkeypatch,
+                                 donate_argnums=(0,))
+    pool = ",".join(map(str, CELL_POOL))
+    copies = re.findall(rf"= bf16\[{pool}\][^ ]* copy\(", compiled.as_text())
+    assert not copies, f"{len(copies)} whole-pool re-layout copies"
+    pool_bytes = 2 * CELL_HKV * CELL_POOL[1] * PAGE * D
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
