@@ -1,26 +1,25 @@
-"""Builds the model a cell runs from its configuration file, through the
-program's ordinary classes. Widths are the file's; --rehearse swaps in a
-tiny model of the same family shape (GQA ratio kept) for the CPU."""
-import json
-import os
-
-HERE = os.path.dirname(os.path.abspath(__file__))
+"""Builder `model`: the Llama family (MHA and GQA decoders through
+`paddle_tpu.models.llama`). A configuration file selects it with
+`"builder": "model"`; everything that is this family's lives here
+(README, "A builder"). Widths are the file's; --rehearse swaps in a tiny
+model of the same family shape (GQA ratio kept) for the CPU."""
 
 #: --rehearse only: head_dim 64 is a width the kernel predicates accept
 TINY = {"hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 2,
         "num_attention_heads": 4, "head_dim": 64, "vocab_size": 512}
 
 
-def load_config(name, rehearse=False):
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
-        cfg = json.load(f)
+def load_config(raw, rehearse=False):
+    """The configuration as it is run, from the file's parsed JSON."""
+    cfg = dict(raw)
     if rehearse:
         group = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
         cfg.update(TINY)
         cfg["num_key_value_heads"] = max(1, TINY["num_attention_heads"] // group)
     if cfg["hidden_size"] != cfg["num_attention_heads"] * cfg["head_dim"]:
-        raise ValueError(f"{name}: the program's decoder derives head_dim as "
-                         "hidden_size / num_attention_heads")
+        raise ValueError(f"{cfg.get('source')}: the program's decoder "
+                         "derives head_dim as hidden_size / "
+                         "num_attention_heads")
     return cfg
 
 
@@ -49,3 +48,11 @@ def build(cfg, seed, train, max_len, rehearse=False, recompute=False):
     if not train:
         model.eval()
     return model
+
+
+def criterion():
+    """The training loss the step is built around: (logits or fused
+    output, labels) -> scalar."""
+    from paddle_tpu.models.llama import LlamaPretrainingCriterion
+
+    return LlamaPretrainingCriterion()

@@ -4,9 +4,13 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell needs is found by name (see benchmarks/README.md): the
-cell's file under workloads/, its configuration under configs/, its traffic
-mix under traffic/, its runner under runners/, and one file per metric under
-metrics/ naming the reader that computes it. The last line of standard
+cell's file under workloads/, its configuration under configs/ with the
+builder and the reference module that file names, its traffic mix under
+traffic/, its runner under runners/, the kernel tiers its `correct` demands
+(the workload file's `tiers`), and one file per metric under metrics/ naming
+the reader that computes it. Nothing on this path knows an architecture: the
+family lives in the builder, in flops.py and in the readers a cell's metrics
+name. The last line of standard
 output is the one JSON object the contract asks for; everything else goes
 on earlier lines or into the output directory (chiprun_out/bench/).
 
@@ -52,6 +56,11 @@ def _load(*parts):
 def manifest():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def _module_file(name):
+    """The file of module `name` under benchmarks/ (dots are directories)."""
+    return os.path.join(HERE, *name.split(".")) + ".py"
 
 
 def cell_metrics(man, cell, group):
@@ -117,6 +126,15 @@ def check(man):
             if not os.path.exists(os.path.join(
                     HERE, "runners", spec["runner"] + ".py")):
                 bad.append(f"{name}: no runner {spec['runner']}")
+            tiers = spec.get("tiers")
+            if not tiers:
+                bad.append(f"{name}: its workload file demands no kernel "
+                           f"tiers (`tiers`): a cell may not run on a "
+                           f"fallback by saying nothing")
+            elif not all(isinstance(t, dict) and t.get("module")
+                         and t.get("want") for t in tiers.values()):
+                bad.append(f"{name}: each of `tiers` needs a `module` and "
+                           f"the `want` its LAST_IMPL has to read")
         mine_e2e = {m["name"] for m in cell_metrics(man, name, "end_to_end")}
         mine_pl = cell_metrics(man, name, "per_layer")
         if len(mine_e2e) < 2 or not mine_pl:
@@ -125,8 +143,7 @@ def check(man):
         bad += [f"{name}: reports {m['name']} but not what it moves, "
                 f"{m['moves']}" for m in mine_pl if m["moves"] not in mine_e2e]
     for c in configs.values():
-        if not os.path.exists(os.path.join(ROOT, c["file"])):
-            bad.append(f"config file {c['file']}")
+        bad += _check_config(c)
         if not any(w["config"] == c["name"] for w in cells.values()):
             bad.append(f"config {c['name']} is used by no cell")
     four = sum(1 for w in cells.values() if w["chips"] == 4)
@@ -137,11 +154,41 @@ def check(man):
     return bad
 
 
+def _check_config(c):
+    """One `configs` entry of the manifest against its file."""
+    name = c["name"]
+    if c["file"] != f"benchmarks/configs/{name}.json":
+        return [f"config {name}: a cell finds its file by name, as "
+                f"benchmarks/configs/{name}.json, not {c['file']}"]
+    try:
+        raw = _load("configs", name + ".json")
+    except (OSError, ValueError) as e:
+        return [f"config file {c['file']}: {e}"]
+    bad = []
+    for key in ("builder", "reference"):
+        if not isinstance(raw.get(key), str):
+            bad.append(f"config {name}: its file names no {key}")
+        elif not os.path.exists(_module_file(raw[key])):
+            bad.append(f"config {name}: no {key} module "
+                       f"{os.path.relpath(_module_file(raw[key]), ROOT)}")
+    if raw.get("source") != c["source"]:
+        bad.append(f"config {name}: source differs between the manifest "
+                   f"and its file")
+    published = raw.get("published", {})
+    for key in c["reduced"]:
+        if key not in raw:
+            bad.append(f"config {name}: reduced key {key} is not in its file")
+        if key not in published:
+            bad.append(f"config {name}: reduced key {key} lacks its "
+                       f"published value (`published`)")
+    return bad
+
+
 class Ctx:
     """What a runner and a reader see of one run."""
 
     def __init__(self, args, man):
-        from benchmarks import model, traffic
+        from benchmarks import traffic
         from benchmarks.profiler import WindowTracer
 
         self.name = None
@@ -150,7 +197,11 @@ class Ctx:
         self.chips = self.cell["chips"]
         self.seed, self.rehearse = args.seed, args.rehearse
         self.seconds = float(args.seconds)
-        self.cfg = model.load_config(self.cell["config"], args.rehearse)
+        raw = _load("configs", self.cell["config"] + ".json")
+        self.builder = importlib.import_module(f"benchmarks.{raw['builder']}")
+        self.reference = importlib.import_module(
+            f"benchmarks.{raw['reference']}")
+        self.cfg = self.builder.load_config(raw, args.rehearse)
         self.traffic = traffic.sized(traffic.load(self.cell["traffic"]),
                                      args.rehearse)
         self.trace_dir = os.path.join(ROOT, ".bench_out", "trace",
@@ -172,6 +223,21 @@ class Ctx:
     @property
     def setup_s(self):
         return self.t_window - T_PROCESS_START
+
+    def tiers(self):
+        """(checks, problems) for the kernel tiers the workload file
+        demands: `tiers` maps a key of `checks` to the module whose
+        LAST_IMPL has to read `want` once the timed path has run. A
+        rehearsal runs off the chip, on other tiers: it records, and
+        demands nothing."""
+        checks, problems = {}, []
+        for key, tier in self.cell["tiers"].items():
+            got = importlib.import_module(tier["module"]).LAST_IMPL
+            checks[key] = got
+            if got != tier["want"] and not self.rehearse:
+                problems.append(f"{key}: {tier['module']} ran on {got!r}, "
+                                f"not {tier['want']!r}")
+        return checks, problems
 
 
 def _devices(ctx):

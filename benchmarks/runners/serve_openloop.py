@@ -2,7 +2,10 @@
 ContinuousBatchingEngine, driven open-loop from one thread on the schedule
 `traffic.open_loop` makes. Returns raw per-request records (stamps on the
 host's monotonic clock, relative to the window's start) and the facts
-`correct` needs; never a metric. Construction copied from
+`correct` needs; never a metric. The model and the reference are the
+builder's and the reference module's that the cell's configuration file
+names (`ctx.builder`, `ctx.reference`); the kernel tiers `correct` demands
+are the workload file's (`ctx.tiers()`). Construction copied from
 chip_smoke.serve_phase (41cde00).
 
 A request's clock starts when it was DUE, not when submit() ran: a stalled
@@ -13,8 +16,7 @@ import time
 
 import numpy as np
 
-from benchmarks import model as M
-from benchmarks import reference, traffic
+from benchmarks import traffic
 from benchmarks.profiler import WindowTracer, annotate
 
 
@@ -38,8 +40,8 @@ def _setup(ctx):
     knobs = dict(ctx.cell["engine"])
     if ctx.rehearse:
         knobs.update(ctx.cell.get("rehearse", {}).get("engine", {}))
-    model = M.build(ctx.cfg, ctx.seed, train=False, max_len=knobs["max_len"],
-                    rehearse=ctx.rehearse)
+    model = ctx.builder.build(ctx.cfg, ctx.seed, train=False,
+                              max_len=knobs["max_len"], rehearse=ctx.rehearse)
     eng = ContinuousBatchingEngine(model, **knobs)
     eng.warmup(buckets=[ctx.traffic["prompt"]["max"]])
     return model, eng, knobs
@@ -116,8 +118,6 @@ def _row_problems(reqs, rows):
 
 def run(ctx):
     from paddle_tpu.observability import compilemem
-    from paddle_tpu.ops import paged_attention as pa
-    from paddle_tpu.ops import ragged_paged_attention as rpa
     from paddle_tpu.serving import ServingFrontend
 
     tp = ctx.traffic
@@ -131,9 +131,10 @@ def run(ctx):
             t0 = ctx.mark_window_start(delay_s=tp.get("warm_in_s", 0))
             records, rows = _drive(fe, reqs, t0, ctx.seconds, ctx.tracer,
                                    tp["drain_timeout_s"])
+        tiers, tier_problems = ctx.tiers()
         checks = {"compiles_in_window":
                   compilemem.ledger.counts()["events"] - compiles_before,
-                  "ragged_impl": rpa.LAST_IMPL, "paged_impl": pa.LAST_IMPL}
+                  **tiers}
         problems = _row_problems(reqs, rows)
 
         # the shortest finished prompt (a decode row from its second step)
@@ -149,22 +150,18 @@ def run(ctx):
             problems.append("no finished prompt longer than one prefill chunk")
         else:
             try:
-                checks["reference"] = reference.check_served(
+                checks["reference"] = ctx.reference.check_served(
                     model, [reqs[i]["prompt"] for i in which],
                     [rows[i] for i in which])
                 checks["reference"]["prompt_lens"] = [
                     len(reqs[i]["prompt"]) for i in which]
-            except reference.Wrong as e:
+            except ctx.reference.Wrong as e:
                 problems.append(str(e))
     if checks["compiles_in_window"]:
         problems.append(f"{checks['compiles_in_window']} compile(s) after "
                         f"warm-up: "
                         f"{compilemem.ledger.report(recent=4)['recent']}")
-    if not ctx.rehearse:
-        if checks["ragged_impl"] != "ragged-kernel":
-            problems.append(f"ragged attention on {checks['ragged_impl']!r}")
-        if checks["paged_impl"] != "paged-kernel":
-            problems.append(f"paged attention on {checks['paged_impl']!r}")
+    problems += tier_problems
     measured = [r for r in records if r["measured"]]
     failed = [r for r in measured if r["t_done"] is None]
     checks["errors"] = sorted({r["error"] for r in failed if r["error"]})[:5]

@@ -1,15 +1,17 @@
 """Runner `train_steps`: whole optimizer steps of the program's jitted train
 step (TrainStep, or DistributedTrainStep on the cell's mesh) until the
 window is up. Returns raw per-step records and the facts `correct` needs;
-never a metric. Construction copied from chip_smoke.py (`_make_train_step`,
-`_train_losses`, `four_chip_phase`, 41cde00)."""
+never a metric. The model, its loss and the reference are the builder's
+and the reference module's that the cell's configuration file names
+(`ctx.builder`, `ctx.reference`); the kernel tiers `correct` demands are the
+workload file's (`ctx.tiers()`). Construction copied from chip_smoke.py
+(`_make_train_step`, `_train_losses`, `four_chip_phase`, 41cde00)."""
 import contextlib
 import time
 
 import numpy as np
 
-from benchmarks import model as M
-from benchmarks import reference, traffic
+from benchmarks import traffic
 from benchmarks.profiler import annotate
 
 WARM_STEPS = 3  # compile, DistributedTrainStep's known second compile, one steady
@@ -20,9 +22,7 @@ def run(ctx):
 
     import paddle_tpu as paddle
     from paddle_tpu import optimizer
-    from paddle_tpu.models.llama import LlamaPretrainingCriterion
     from paddle_tpu.observability import compilemem
-    from paddle_tpu.ops import flash_attention as fa
 
     cfg, tp, knobs = ctx.cfg, ctx.traffic, ctx.cell["step"]
     mesh_spec = ctx.cell.get("mesh")
@@ -43,16 +43,18 @@ def run(ctx):
 
     checks, steps = {}, []
     with guard:
-        model = M.build(cfg, ctx.seed, train=True, max_len=tp["seq"],
-                        rehearse=ctx.rehearse,
-                        recompute=knobs.get("recompute", False))
-        ref_loss = reference.reference_loss(model, batches[0])
+        model = ctx.builder.build(cfg, ctx.seed, train=True, max_len=tp["seq"],
+                                  rehearse=ctx.rehearse,
+                                  recompute=knobs.get("recompute", False))
+        ref_loss = ctx.reference.reference_loss(model, batches[0])
         opt = optimizer.AdamW(learning_rate=knobs["lr"],
                               parameters=model.parameters(),
                               weight_decay=knobs["weight_decay"])
 
+        criterion = ctx.builder.criterion()
+
         def loss_fn(*a):
-            return LlamaPretrainingCriterion()(*a)
+            return criterion(*a)
 
         if mesh_spec:
             from paddle_tpu.distributed.train_step import DistributedTrainStep
@@ -113,26 +115,21 @@ def run(ctx):
         "dispatch": steps[slow]["t_dispatched"] - steps[slow]["t_fed"],
         "device_wait": steps[slow]["t_done"] - steps[slow]["t_dispatched"],
         "median_step": float(np.median(np.diff(ends)))}
+    tiers, problems = ctx.tiers()
     checks.update(losses_first=losses[0], losses_last5=losses[-5:],
-                  reference_loss=ref_loss, flash_impl=fa.LAST_IMPL,
+                  reference_loss=ref_loss, **tiers,
                   params=model.num_parameters())
-    gqa = cfg["num_key_value_heads"] != cfg["num_attention_heads"]
-    problems = []
     if not np.all(np.isfinite(losses)):
         problems.append(f"non-finite loss: {losses}")
     if not np.mean(losses[-5:]) < losses[0]:
         problems.append(f"loss did not fall: {losses[0]} -> {losses[-5:]}")
-    if abs(losses[0] - ref_loss) > reference.TRAIN_LOSS_TOL:
+    if abs(losses[0] - ref_loss) > ctx.reference.TRAIN_LOSS_TOL:
         problems.append(f"step-0 loss {losses[0]} vs reference {ref_loss} "
-                        f"(tolerance {reference.TRAIN_LOSS_TOL})")
+                        f"(tolerance {ctx.reference.TRAIN_LOSS_TOL})")
     if checks["compiles_in_window"]:
         problems.append(f"{checks['compiles_in_window']} compile(s) inside "
                         f"the window: "
                         f"{compilemem.ledger.report(recent=4)['recent']}")
-    if not ctx.rehearse:
-        want = "splash" if gqa else "pallas"
-        if fa.LAST_IMPL != want:
-            problems.append(f"attention ran on {fa.LAST_IMPL!r}, not {want!r}")
     if mesh_spec:
         if checks["devices_with_shards"] != ctx.chips:
             problems.append(f"parameters live on "

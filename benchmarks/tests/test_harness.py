@@ -141,7 +141,9 @@ def test_percentile_and_backlog():
 def test_flops_from_shapes():
     from benchmarks import flops, model
 
-    cfg = model.load_config("mistral-7b-v0.3")
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "mistral-7b-v0.3.json")) as f:
+        cfg = model.load_config(json.load(f))
     # 218.1 M a layer + 134.2 M head (ISSUE 24's count, less the embedding)
     assert round(flops.matmul_params(cfg) / 1e6, 1) == round(
         4 * 218.1 + 134.2, 1)
