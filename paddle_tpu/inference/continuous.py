@@ -7,7 +7,7 @@ TPU-native shape: compute is two jitted programs with STATIC shapes —
 a bucketed PREFILL (compiled per prompt bucket, reusing the dense
 fixed-cache path) whose KV lands in pool pages via a jitted insert, and a
 single DECODE step over all `max_seqs` slots driving the model through
-`PagedLayerCache` entries (kernel-backed paged attention on TPU). The
+paged cache entries (kernel-backed paged attention on TPU). The
 scheduler is plain host Python between jitted calls: retire finished
 sequences, free their pages, admit queued requests into freed slots
 mid-flight of everyone else — the continuous part. Memory is bounded by
@@ -70,8 +70,7 @@ from ..observability import devprof as _devprof
 from ..observability import goodput as _goodput
 from ..observability import tracing as _trace
 from ..observability.metrics import registry as _registry
-from ..ops.paged_attention import PagedLayerCache
-from ..ops.ragged_paged_attention import RaggedLayerCache
+from ..ops.paged_attention import KVCacheSpec
 from ..testing import chaos
 from ..utils.envs import env_bool as _env_bool
 from ..utils.envs import env_int as _env_int
@@ -426,31 +425,19 @@ class ContinuousBatchingEngine:
         if self.num_pages < 2:
             raise ValueError("need at least one scratch + one real page")
         dtype = next(iter(model.parameters())).dtype
-        Hkv, D, L = cfg.num_key_value_heads, cfg.head_dim, cfg.num_hidden_layers
         self.kv_cache_dtype = kv_cache_dtype
-        if kv_cache_dtype == "int8":
-            # int8 KV pool (jax paged_attention QuantizedTensor layout):
-            # ~4x fewer HBM bytes per decode step vs f32, ~2x vs bf16 —
-            # the decode-bandwidth lever; scales are per (head, page, row)
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                quantization_utils as qu,
-            )
-
-            def zero_pool():
-                return qu.QuantizedTensor(
-                    weight=jnp.zeros((Hkv, self.num_pages, page_size, D), jnp.int8),
-                    scales=jnp.ones((Hkv, self.num_pages, page_size, 1), jnp.float32),
-                )
-
-            self.pools = [(zero_pool(), zero_pool()) for _ in range(L)]
-        elif kv_cache_dtype not in (None, "model"):
-            raise ValueError(f"unsupported kv_cache_dtype {kv_cache_dtype!r}")
-        else:
-            self.pools = [
-                (jnp.zeros((Hkv, self.num_pages, page_size, D), dtype),
-                 jnp.zeros((Hkv, self.num_pages, page_size, D), dtype))
-                for _ in range(L)
-            ]
+        # the model says what its layers cache (the model protocol:
+        # serving_cache_spec / serving_trunk / serving_head): K and V pools
+        # (ops.paged_attention.KVCacheSpec, also the default for a model
+        # that says nothing) or one pool of latent rows
+        # (ops.latent_pool.LatentCacheSpec). Pools, cache entries and bytes
+        # a token are the spec's; the allocator counts pages either way.
+        spec = getattr(model, "serving_cache_spec", None)
+        self._cache_spec = spec() if spec is not None else KVCacheSpec(
+            cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim)
+        self._latent = self._cache_spec.latent
+        self.pools = self._cache_spec.make_pools(
+            self.num_pages, page_size, dtype, kv_cache_dtype)
         self.free_pages = list(range(1, self.num_pages))  # page 0 = scratch
         self.free_slots = list(range(max_seqs))
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
@@ -595,10 +582,18 @@ class ContinuousBatchingEngine:
         # the fixed-k decode block per (sampling, kv-dtype, lora-rank).
         # PADDLE_SERVING_RAGGED=0 is the kill switch: every legacy path is
         # byte-for-byte untouched when off. Ragged needs the split
-        # trunk/head call (model.llama), so non-llama models fall back.
+        # trunk/head call (the model protocol's serving_trunk /
+        # serving_head), so models without it fall back.
         if ragged is None:
             ragged = _env_bool("PADDLE_SERVING_RAGGED", True)
-        self._ragged = bool(ragged) and getattr(model, "llama", None) is not None
+        self._ragged = bool(ragged) and hasattr(model, "serving_trunk")
+        if self._latent:
+            # the planes below were written for K and V pools; on a pool of
+            # latent rows each refuses by name, none falls back
+            if not self._ragged:
+                self._refuse_latent("the bucket-ladder plane (ragged=False)")
+            if self.enable_prefix_cache:
+                self._refuse_latent("the prefix cache (enable_prefix_cache)")
         # token budget for prompt chunks per mixed dispatch (token-granular:
         # ragged writes need no page alignment, unlike legacy prefill_chunk)
         self._ragged_chunk = max(self.prefill_chunk or min(256, max_len), 1)
@@ -994,8 +989,9 @@ class ContinuousBatchingEngine:
         def decode(state, toks, pools, page_table, lengths, caps, keys):
             overrides = {k: Tensor(v, stop_gradient=True) for k, v in state.items()}
             lengths_e = jnp.minimum(lengths, caps)
-            pkvs = [PagedLayerCache(kp, vp, page_table, lengths_e)
-                    for kp, vp in pools]
+            pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
+                                           caps > 0)
+                    for pool in pools]
             logits, presents = model.functional_call(
                 overrides, Tensor(toks),
                 position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
@@ -1003,7 +999,7 @@ class ContinuousBatchingEngine:
             )
             nxt = sampler(logits._data[:, -1], keys).astype(jnp.int32)
             return nxt, tuple(
-                (p.k_pages, p.v_pages) for p in presents
+                self._cache_spec.pool_of(p) for p in presents
             )
 
         # donate the pools: a single-token decode must UPDATE the pool in
@@ -1015,6 +1011,18 @@ class ContinuousBatchingEngine:
                                            len(self._decode_fns))
         return fn
 
+    def _append_counters(self, blk, counters):
+        """A model's per-dispatch counters (`serving_counters()`: int32, one
+        a name of `serving_counter_names`, valid in its forward's own trace
+        and summed here over the dispatch's forwards) as extra rows under the
+        [k, max_seqs] token block, so that they come to the host with the
+        block's one read-back (`_process_block` takes them off again)."""
+        n = counters.shape[0]
+        rows = -(-n // self.max_seqs)
+        flat = jnp.zeros((rows * self.max_seqs,), blk.dtype).at[:n].set(
+            counters.astype(blk.dtype))
+        return jnp.concatenate([blk, flat.reshape(rows, self.max_seqs)])
+
     def _decode_block_fn(self, sampling, k):
         """k decode steps fused into one dispatch: lax.scan over the
         single-step decode body, carrying (tokens, pools, lengths). Returns
@@ -1024,29 +1032,39 @@ class ContinuousBatchingEngine:
             return fn
         model = self.model
         sampler = _row_sampler(*sampling)
+        count = getattr(model, "serving_counters", None)
 
         def decode_block(state, toks, pools, page_table, lengths, caps, keys):
             overrides = {kk: Tensor(v, stop_gradient=True) for kk, v in state.items()}
 
             def body(carry, step_keys):
-                toks_c, pools_c, lengths_c = carry
+                toks_c, pools_c, lengths_c = carry[:3]
                 # freeze an over-budget row at its last reserved position
                 # (identity for rows within budget — see caps note above)
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [PagedLayerCache(kp, vp, page_table, lengths_e)
-                        for kp, vp in pools_c]
+                pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
+                                               caps > 0)
+                        for pool in pools_c]
                 logits, presents = model.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 nxt = sampler(logits._data[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple((p.k_pages, p.v_pages) for p in presents)
-                return (nxt[:, None], new_pools, lengths_e + 1), nxt
+                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents)
+                out = (nxt[:, None], new_pools, lengths_e + 1)
+                if count is not None:
+                    out += (carry[3] + count(),)
+                return out, nxt
 
-            (_, pools_out, _), toks_block = jax.lax.scan(
-                body, (toks, tuple(pools), lengths), keys)
-            return toks_block, pools_out
+            init = (toks, tuple(pools), lengths)
+            if count is not None:
+                init += (jnp.zeros((len(model.serving_counter_names),),
+                                   jnp.int32),)
+            carry, toks_block = jax.lax.scan(body, init, keys)
+            if count is not None:
+                toks_block = self._append_counters(toks_block, carry[3])
+            return toks_block, carry[1]
 
         fn = self._decode_block_fns[(sampling, k)] = _compilemem.ledgered_jit(
             decode_block, key=f"serve.decode_block[k{k},s{sampling}]",
@@ -1072,23 +1090,14 @@ class ContinuousBatchingEngine:
     # byte-for-byte the pre-LoRA engine.
 
     @staticmethod
-    def _lora_inner_overrides(state):
-        """Full-model raw state -> inner-model functional_call overrides
-        ("llama."-prefix keys stripped; the head weight stays behind for
-        the explicit base-head matmul below)."""
-        return {k[len("llama."):]: Tensor(v, stop_gradient=True)
-                for k, v in state.items() if k.startswith("llama.")}
-
-    @staticmethod
-    def _lora_base_head(h, state, tied):
-        """The base LM-head projection with the SAME ops the model's own
-        forward uses (F.linear / matmul(transpose_y=True)) — a zero-delta
-        lora row must sample the bit-identical token the base program
-        would have."""
-        if tied:
-            return h @ jnp.swapaxes(state["llama.embed_tokens.weight"],
-                                    -1, -2)
-        return h @ state["lm_head.weight"]
+    def _trunk_overrides(state, prefix):
+        """Full-model raw state -> functional_call overrides of the trunk
+        `model.serving_trunk()` names (its prefix stripped; the head weight
+        stays behind for `model.serving_head`, which runs the SAME ops the
+        model's own forward uses, so a zero-delta lora row samples the
+        bit-identical token the base program would have)."""
+        return {k[len(prefix):]: Tensor(v, stop_gradient=True)
+                for k, v in state.items() if k.startswith(prefix)}
 
     def _lora_prefill(self, bucket, sampling, rank):
         """Monolithic prefill + adapter head for the request's OWN A/B
@@ -1099,12 +1108,11 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
 
         def prefill(state, ids_p, true_len, key, a_w, b_w, scale):
-            overrides = self._lora_inner_overrides(state)
+            overrides = self._trunk_overrides(state, prefix)
             caches = model.init_cache(1, bucket)
             wrapped = [(Tensor(kc), Tensor(vc)) for kc, vc in caches]
             h, presents = inner.functional_call(
@@ -1114,7 +1122,7 @@ class ContinuousBatchingEngine:
             )
             h_last = jax.lax.dynamic_index_in_dim(h._data, true_len - 1,
                                                   axis=1, keepdims=False)
-            base = self._lora_base_head(h_last, state, tied)  # [1, V]
+            base = model.serving_head(h_last, state)  # [1, V]
             delta = ((h_last.astype(jnp.float32) @ a_w) @ b_w) * scale
             tok0 = sampler(base + delta, key[None])[0].astype(jnp.int32)
             ks = jnp.stack([p[0]._data[0] for p in presents])
@@ -1137,14 +1145,13 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
         plen = n_prefix_pages * self.page_size
 
         def prefill_suf(state, ks_pre, vs_pre, ids_suf, suf_len, key,
                         a_w, b_w, scale):
-            overrides = self._lora_inner_overrides(state)
+            overrides = self._trunk_overrides(state, prefix)
             caches = model.init_cache(1, plen + suffix_bucket)
             wrapped = []
             for l, (kc, vc) in enumerate(caches):
@@ -1158,7 +1165,7 @@ class ContinuousBatchingEngine:
             )
             h_last = jax.lax.dynamic_index_in_dim(h._data, suf_len - 1,
                                                   axis=1, keepdims=False)
-            base = self._lora_base_head(h_last, state, tied)
+            base = model.serving_head(h_last, state)
             delta = ((h_last.astype(jnp.float32) @ a_w) @ b_w) * scale
             tok0 = sampler(base + delta, key[None])[0].astype(jnp.int32)
             ks = jnp.stack([p[0]._data[0, plen:] for p in presents])
@@ -1183,23 +1190,23 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
 
         def decode(state, toks, pools, page_table, lengths, caps, keys,
                    a_stack, b_stack, scales, lora_idx):
-            overrides = self._lora_inner_overrides(state)
+            overrides = self._trunk_overrides(state, prefix)
             lengths_e = jnp.minimum(lengths, caps)
-            pkvs = [PagedLayerCache(kp, vp, page_table, lengths_e)
-                    for kp, vp in pools]
+            pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
+                                           caps > 0)
+                    for pool in pools]
             h, presents = inner.functional_call(
                 overrides, Tensor(toks),
                 position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                 past_key_values=pkvs, use_cache=True, training=False,
             )
             hd = h._data                       # [max_seqs, 1, hidden]
-            base = self._lora_base_head(hd, state, tied)
+            base = model.serving_head(hd, state)
             a_rows = a_stack[lora_idx]         # [max_seqs, hidden, r]
             b_rows = b_stack[lora_idx]         # [max_seqs, r, vocab]
             delta = jnp.einsum("bsh,bhr->bsr", hd.astype(jnp.float32),
@@ -1207,7 +1214,7 @@ class ContinuousBatchingEngine:
             delta = jnp.einsum("bsr,brv->bsv", delta, b_rows)
             logits = base + delta * scales[lora_idx][:, None, None]
             nxt = sampler(logits[:, -1], keys).astype(jnp.int32)
-            return nxt, tuple((p.k_pages, p.v_pages) for p in presents)
+            return nxt, tuple(self._cache_spec.pool_of(p) for p in presents)
 
         fn = self._lora_decode_fns[key2] = _compilemem.ledgered_jit(
             decode, key=f"serve.lora_decode[r{rank},s{sampling}]",
@@ -1225,13 +1232,12 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
 
         def decode_block(state, toks, pools, page_table, lengths, caps,
                          keys, a_stack, b_stack, scales, lora_idx):
-            overrides = self._lora_inner_overrides(state)
+            overrides = self._trunk_overrides(state, prefix)
             a_rows = a_stack[lora_idx]
             b_rows = b_stack[lora_idx]
             s_rows = scales[lora_idx][:, None, None]
@@ -1239,8 +1245,9 @@ class ContinuousBatchingEngine:
             def body(carry, step_keys):
                 toks_c, pools_c, lengths_c = carry
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [PagedLayerCache(kp, vp, page_table, lengths_e)
-                        for kp, vp in pools_c]
+                pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
+                                               caps > 0)
+                        for pool in pools_c]
                 h, presents = inner.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(
@@ -1248,13 +1255,13 @@ class ContinuousBatchingEngine:
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 hd = h._data
-                base = self._lora_base_head(hd, state, tied)
+                base = model.serving_head(hd, state)
                 delta = jnp.einsum("bsh,bhr->bsr",
                                    hd.astype(jnp.float32), a_rows)
                 delta = jnp.einsum("bsr,brv->bsv", delta, b_rows)
                 logits = base + delta * s_rows
                 nxt = sampler(logits[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple((p.k_pages, p.v_pages) for p in presents)
+                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents)
                 return (nxt[:, None], new_pools, lengths_e + 1), nxt
 
             (_, pools_out, _), toks_block = jax.lax.scan(
@@ -1285,9 +1292,9 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
+        count = getattr(model, "serving_counters", None)
         T = self._ragged_tokens
         k = self.decode_block
 
@@ -1296,7 +1303,7 @@ class ContinuousBatchingEngine:
                         lengths, caps, keys):
             overrides = {kk: Tensor(v, stop_gradient=True)
                          for kk, v in state.items()}
-            inner_ov = self._lora_inner_overrides(state)
+            inner_ov = self._trunk_overrides(state, prefix)
             q_lens = cu[1:] - cu[:-1]
             # decode rows chained off an in-flight block feed on its device
             # `last` tokens; each row's feed token sits at its span start.
@@ -1306,9 +1313,9 @@ class ContinuousBatchingEngine:
             upd = jnp.where(use_last[:, 0], last[:, 0], tok_block[first_idx])
             toks_in = tok_block.at[first_idx].set(upd)
             kv_lens = lengths + q_lens  # POST-write totals (ragged contract)
-            rcaches = [RaggedLayerCache(kp, vp, page_table, kv_lens, cu,
-                                        row_of, token_pos, valid)
-                       for kp, vp in pools]
+            rcaches = [self._cache_spec.ragged(pool, page_table, kv_lens, cu,
+                                              row_of, token_pos, valid)
+                       for pool in pools]
             h, presents = inner.functional_call(
                 inner_ov, Tensor(toks_in[None]),
                 position_ids=Tensor(token_pos[None].astype(jnp.int32)),
@@ -1317,28 +1324,36 @@ class ContinuousBatchingEngine:
             # each participant samples from its LAST packed token (span end)
             b_idx = jnp.clip(cu[1:] - 1, 0, T - 1)
             h_b = h._data[0, b_idx]                         # [max_seqs, H]
-            base = self._lora_base_head(h_b, state, tied)   # [max_seqs, V]
+            base = model.serving_head(h_b, state)   # [max_seqs, V]
             tok0 = sampler(base, keys[0]).astype(jnp.int32)
-            pools1 = tuple((p.k_pages, p.v_pages) for p in presents)
+            pools1 = tuple(self._cache_spec.pool_of(p) for p in presents)
+            init = (tok0[:, None], pools1, kv_lens)
+            if count is not None:
+                init += (count(),)  # the packed pass's; the scan adds its own
 
             def body(carry, step_keys):
-                toks_c, pools_c, lengths_c = carry
+                toks_c, pools_c, lengths_c = carry[:3]
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [PagedLayerCache(kp, vp, scan_table, lengths_e)
-                        for kp, vp in pools_c]
+                pkvs = [self._cache_spec.paged(pool, scan_table, lengths_e,
+                                               caps > 0)
+                        for pool in pools_c]
                 logits, presents2 = model.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 nxt = sampler(logits._data[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple((p.k_pages, p.v_pages) for p in presents2)
-                return (nxt[:, None], new_pools, lengths_e + 1), nxt
+                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents2)
+                out = (nxt[:, None], new_pools, lengths_e + 1)
+                if count is not None:
+                    out += (carry[3] + count(),)
+                return out, nxt
 
-            (_, pools_out, _), toks_tail = jax.lax.scan(
-                body, (tok0[:, None], pools1, kv_lens), keys[1:])
+            carry, toks_tail = jax.lax.scan(body, init, keys[1:])
             blk = jnp.concatenate([tok0[None], toks_tail], axis=0)
-            return blk, pools_out
+            if count is not None:
+                blk = self._append_counters(blk, carry[3])
+            return blk, carry[1]
 
         fn = self._ragged_fns[sampling] = _compilemem.ledgered_jit(
             ragged_step, key=f"serve.ragged[k{k},s{sampling}]",
@@ -1356,8 +1371,7 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         model = self.model
-        inner = model.llama
-        tied = model.lm_head is None
+        inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
         T = self._ragged_tokens
         k = self.decode_block
@@ -1366,7 +1380,7 @@ class ContinuousBatchingEngine:
                         use_last, last, pools, page_table, scan_table,
                         lengths, caps, keys, a_stack, b_stack, scales,
                         lora_idx):
-            inner_ov = self._lora_inner_overrides(state)
+            inner_ov = self._trunk_overrides(state, prefix)
             a_rows = a_stack[lora_idx]
             b_rows = b_stack[lora_idx]
             s_rows = scales[lora_idx]
@@ -1375,9 +1389,9 @@ class ContinuousBatchingEngine:
             upd = jnp.where(use_last[:, 0], last[:, 0], tok_block[first_idx])
             toks_in = tok_block.at[first_idx].set(upd)
             kv_lens = lengths + q_lens
-            rcaches = [RaggedLayerCache(kp, vp, page_table, kv_lens, cu,
-                                        row_of, token_pos, valid)
-                       for kp, vp in pools]
+            rcaches = [self._cache_spec.ragged(pool, page_table, kv_lens, cu,
+                                              row_of, token_pos, valid)
+                       for pool in pools]
             h, presents = inner.functional_call(
                 inner_ov, Tensor(toks_in[None]),
                 position_ids=Tensor(token_pos[None].astype(jnp.int32)),
@@ -1385,32 +1399,33 @@ class ContinuousBatchingEngine:
             )
             b_idx = jnp.clip(cu[1:] - 1, 0, T - 1)
             h_b = h._data[0, b_idx]
-            base = self._lora_base_head(h_b, state, tied)
+            base = model.serving_head(h_b, state)
             delta = jnp.einsum("bh,bhr->br", h_b.astype(jnp.float32), a_rows)
             delta = jnp.einsum("br,brv->bv", delta, b_rows)
             tok0 = sampler(base + delta * s_rows[:, None],
                            keys[0]).astype(jnp.int32)
-            pools1 = tuple((p.k_pages, p.v_pages) for p in presents)
+            pools1 = tuple(self._cache_spec.pool_of(p) for p in presents)
             s3 = s_rows[:, None, None]
 
             def body(carry, step_keys):
                 toks_c, pools_c, lengths_c = carry
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [PagedLayerCache(kp, vp, scan_table, lengths_e)
-                        for kp, vp in pools_c]
+                pkvs = [self._cache_spec.paged(pool, scan_table, lengths_e,
+                                               caps > 0)
+                        for pool in pools_c]
                 h2, presents2 = inner.functional_call(
                     inner_ov, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 hd = h2._data
-                base2 = self._lora_base_head(hd, state, tied)
+                base2 = model.serving_head(hd, state)
                 d2 = jnp.einsum("bsh,bhr->bsr", hd.astype(jnp.float32),
                                 a_rows)
                 d2 = jnp.einsum("bsr,brv->bsv", d2, b_rows)
                 logits = base2 + d2 * s3
                 nxt = sampler(logits[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple((p.k_pages, p.v_pages) for p in presents2)
+                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents2)
                 return (nxt[:, None], new_pools, lengths_e + 1), nxt
 
             (_, pools_out, _), toks_tail = jax.lax.scan(
@@ -1475,11 +1490,17 @@ class ContinuousBatchingEngine:
         """Why this adapter can never run on this engine (None = it can):
         admission fails the request alone instead of deferring forever."""
         hidden, vocab = self._lora_dims
-        if not hasattr(self.model, "llama") or hidden is None \
+        if self._latent:
+            return ValueError(
+                "the LoRA planes run copies of the K-and-V programs; this "
+                "model caches latent rows "
+                f"({type(self._cache_spec).__name__})")
+        if not hasattr(self.model, "serving_trunk") or hidden is None \
                 or vocab is None:
             return ValueError(
-                "LoRA adapters need a LlamaForCausalLM-shaped model "
-                "(inner .llama + hidden_size/vocab_size config)")
+                "LoRA adapters need a model with the serving protocol "
+                "(serving_trunk/serving_head + hidden_size/vocab_size "
+                "config)")
         if ad.a.shape[0] != hidden or ad.b.shape[1] != vocab:
             return ValueError(
                 f"adapter {ad.name!r} shapes {ad.a.shape}/{ad.b.shape} "
@@ -1536,8 +1557,23 @@ class ContinuousBatchingEngine:
                 for rank in lora_ranks:
                     for cfg in configs:
                         self._warmup_lora(prompt_lens, int(rank), *cfg)
+            self._publish_scopes()
         finally:
             _M_WARMUP.observe(time.monotonic() - t_warm0)
+
+    def _publish_scopes(self):
+        """For a model that names `serving_scopes` (its `jax.named_scope`s
+        inside the step programs): which scope each instruction of the two
+        warmed step programs lies under, into `tracing.program_scopes`, so
+        that a device trace's op events can be attributed to them. One
+        re-lowering a program through the compile cache, in warm-up."""
+        scopes = getattr(self.model, "serving_scopes", None)
+        if not scopes:
+            return
+        for key in list(_compilemem.memory.programs()):
+            if key.startswith(("serve.ragged[", "serve.decode_block[")):
+                _trace.note_program_scopes(
+                    key, _compilemem.memory.compiled(key).as_text(), scopes)
 
     def _warmup_one(self, prompt_lens, shared_prefix_lens, do_sample,
                     temperature, top_k, top_p):
@@ -1778,6 +1814,14 @@ class ContinuousBatchingEngine:
     # and continues bit-identically. All three hooks run on the owning
     # dispatcher thread (the engine's single-threaded contract).
 
+    def _refuse_latent(self, plane):
+        """A plane written for K and V pages, asked of a pool of latent
+        rows, says so by name: none may silently fall back."""
+        if self._latent:
+            raise ValueError(
+                f"{plane} moves K and V pages; this model caches latent "
+                f"rows ({type(self._cache_spec).__name__})")
+
     def _settle_inflight(self):
         """Read back the in-flight decode block NOW (instead of at the next
         step()) so every active request's emitted tokens equal its
@@ -1797,6 +1841,7 @@ class ContinuousBatchingEngine:
         when the request finished while the in-flight block settled —
         nothing left to hand off. Prefill-side only: the host sync here is
         deliberate and NOT part of any decode critical section."""
+        self._refuse_latent("export_pages (the KV handoff plane)")
         self._settle_inflight()
         req = self._active.get(slot)
         if req is None or req.finished:
@@ -1838,6 +1883,7 @@ class ContinuousBatchingEngine:
         stream, its tokens — are bit-identical to never having moved.
         Adopted pages are private (never prefix-indexed): their digests
         were validated against the bundle, not against this pool's index."""
+        self._refuse_latent("adopt_request (the KV handoff plane)")
         if not self.free_slots:
             return "deferred"
         if (self._active or self._prefilling) \
@@ -3006,6 +3052,8 @@ class ContinuousBatchingEngine:
         and the admissions since the previous record go on its record."""
         step["cold"] = cold
         step["rows"] = rows
+        if self._latent:  # latent pages in use, of those allocatable
+            step["pages"] = (self._pages_in_use, self.num_pages - 1)
         step["admits"] = list(self._admits)
         self._admits.clear()
 
@@ -3029,6 +3077,10 @@ class ContinuousBatchingEngine:
                         e, program="serve.decode_block")
                     raise
             step["t_ready"] = time.monotonic_ns()
+        if block.shape[0] > rec.k:
+            step["counters"] = dict(zip(
+                self.model.serving_counter_names,
+                block[rec.k:].reshape(-1).tolist()))
         t_ready = step["t_ready"]
         # the block's OWN interval, normalized per token: with one block
         # always in flight the device starts this one when the previous

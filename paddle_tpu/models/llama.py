@@ -781,6 +781,25 @@ class LlamaForCausalLM(GenerationMixin, Layer):
             return with_aux(LlamaPretrainingCriterion(self.config)(logits, labels))
         return logits
 
+    # ---- the serving engine's model protocol (inference/continuous.py):
+    # the trunk that returns hidden states and its state-dict prefix, the
+    # head as raw-array ops (the SAME ops forward() runs: F.linear /
+    # matmul(transpose_y=True)), and the kind of pool the layers cache in
+    def serving_trunk(self):
+        return self.llama, "llama."
+
+    def serving_head(self, h, state):
+        if self.lm_head is None:
+            return h @ jnp.swapaxes(state["llama.embed_tokens.weight"], -1, -2)
+        return h @ state["lm_head.weight"]
+
+    def serving_cache_spec(self):
+        from ..ops.paged_attention import KVCacheSpec
+
+        cfg = self.config
+        return KVCacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
+                           cfg.head_dim)
+
     def num_parameters(self):
         import numpy as np
 

@@ -47,6 +47,7 @@ import collections
 import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -56,7 +57,7 @@ from ..utils.envs import env_bool, env_str
 __all__ = ["span", "enable", "disable", "enabled", "last_spans",
            "add_jsonl_sink", "clear_sinks", "JsonlSpanSink", "emit_record",
            "annotation", "new_step", "commit_step", "step_records",
-           "span_record"]
+           "span_record", "program_scopes", "note_program_scopes"]
 
 _ENABLED = None           # tri-state: None = resolve from env on first use
 _RING_DEFAULT = 512
@@ -377,3 +378,31 @@ def step_records(n=None):
     """The step log, oldest first (the last ``n`` records, or all)."""
     buf = list(steps)
     return buf if n is None else buf[-n:]
+
+
+# ---- named scopes of compiled programs --------------------------------------
+
+#: {program ledger key: {HLO instruction name: scope}}: which of a model's
+#: ``jax.named_scope``s each instruction of a compiled program lies under.
+#: A device trace's op events carry instruction names and no scope; a reader
+#: joins them through this table, whatever implements a scope (fusions or a
+#: kernel). Filled by the serving engine's warm-up for a model that names
+#: ``serving_scopes`` (inference/continuous.py).
+program_scopes = {}
+
+_OP_NAME_RX = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"", re.M)
+
+
+def note_program_scopes(key, hlo_text, scopes):
+    """Record, for program ``key``, every instruction of its optimised HLO
+    text whose ``op_name`` lies under one of ``scopes`` (the innermost one
+    wins). Returns the table."""
+    rx = re.compile("/(%s)(?=/|$)" % "|".join(re.escape(s) for s in scopes))
+    table = {}
+    for name, op_name in _OP_NAME_RX.findall(hlo_text):
+        under = rx.findall(op_name)
+        if under:
+            table[name] = under[-1]
+    program_scopes[key] = table
+    return table

@@ -72,6 +72,58 @@ class PagedLayerCache:
         return (k.weight if is_quantized(k) else k).shape[2]
 
 
+class KVCacheSpec:
+    """What the serving engine asks of a model whose layers cache K and V:
+    how to make the pools, how to view one as a cache entry, and how to take
+    the pool back out of the entry a forward returns. A pool is the pair
+    (k_pages, v_pages). The latent twin is ops.latent_pool.LatentCacheSpec;
+    a model names its own through `serving_cache_spec()`."""
+
+    latent = False
+
+    def __init__(self, num_layers, num_kv_heads, head_dim):
+        self.num_layers = num_layers
+        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+
+    def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None):
+        shape = (self.num_kv_heads, num_pages, page_size, self.head_dim)
+        if kv_cache_dtype == "int8":
+            # int8 KV pool (jax paged_attention QuantizedTensor layout):
+            # ~4x fewer HBM bytes per decode step vs f32, ~2x vs bf16 —
+            # the decode-bandwidth lever; scales are per (head, page, row)
+            from jax.experimental.pallas.ops.tpu.paged_attention import (
+                quantization_utils as qu,
+            )
+
+            def zero_pool():
+                return qu.QuantizedTensor(
+                    weight=jnp.zeros(shape, jnp.int8),
+                    scales=jnp.ones(shape[:3] + (1,), jnp.float32))
+        elif kv_cache_dtype not in (None, "model"):
+            raise ValueError(f"unsupported kv_cache_dtype {kv_cache_dtype!r}")
+        else:
+            def zero_pool():
+                return jnp.zeros(shape, dtype)
+        return [(zero_pool(), zero_pool()) for _ in range(self.num_layers)]
+
+    @staticmethod
+    def paged(pool, page_table, lengths, live):
+        # `live` (the rows a request holds) is the latent twin's: here a
+        # dead row reads its one scratch token
+        return PagedLayerCache(*pool, page_table, lengths)
+
+    @staticmethod
+    def ragged(pool, page_table, kv_lens, cu, row_of, token_pos, valid):
+        from .ragged_paged_attention import RaggedLayerCache
+
+        return RaggedLayerCache(*pool, page_table, kv_lens, cu, row_of,
+                                token_pos, valid)
+
+    @staticmethod
+    def pool_of(present):
+        return (present.k_pages, present.v_pages)
+
+
 def is_quantized(pages):
     """True for the int8 pool form: a QuantizedTensor(weight, scales) pair
     (jax's paged_attention quantization_utils layout — weight int8

@@ -21,6 +21,14 @@ it, so these two are the only guard a CPU suite can have against the layout
 coming back: no pool-shaped `copy` in the compiled text, temporaries under
 one pool.
 
+The latent-attention configuration (benchmarks/configs/kimi-k2.7-code-ep32)
+adds its kernels to the first group (the absorbed decode kernel, and jax's
+grouped matmul through the dropless expert layer's own call) and, as its own
+case, the serving engine's two real programs lowered at the cell's sizes from
+an ABSTRACT model (4.85 B parameters as shapes): no copy shaped like the
+latent pool in either, and arguments + temporaries as the configuration file
+states them.
+
 This is the ONLY test file that describes a topology, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so the
 call must not run while any module is imported (pytest-xdist workers all
@@ -144,7 +152,54 @@ def _paged_decode():
     return fn, args
 
 
+# the latent-attention cell's widths
+# (benchmarks/workloads/kimi-k2.7-code-agent-steady.json)
+MLA_H, MLA_RANK, MLA_ROPE, MLA_STORED = 64, 512, 64, 640
+MLA_ROWS, MLA_MAX_LEN, MLA_CHUNK, MLA_K = 16, 17408, 2048, 8
+MLA_NPAGES = MLA_MAX_LEN // PAGE
+MLA_POOL = (1 + MLA_ROWS * MLA_NPAGES, PAGE, MLA_STORED)
+
+
+def _mla_decode():
+    from paddle_tpu.ops.mla_decode_attention import mla_decode_attention
+
+    def fn(q_lat, q_rope, pages, lengths, page_indices):
+        return mla_decode_attention(q_lat, q_rope, pages, lengths,
+                                    page_indices, 0.1)
+
+    def args(sds):
+        return (sds((MLA_ROWS, MLA_H, MLA_RANK), jnp.bfloat16),
+                sds((MLA_ROWS, MLA_H, MLA_ROPE), jnp.bfloat16),
+                sds(MLA_POOL, jnp.bfloat16), sds((MLA_ROWS,), jnp.int32),
+                sds((MLA_ROWS, MLA_NPAGES), jnp.int32))
+
+    return fn, args
+
+
+def _moe_gmm(tokens):
+    """jax's megablox grouped matmul through the dropless expert layer's own
+    call: 12 held experts of 7168 x 2048, worst-case `tokens x 8` rows."""
+    from paddle_tpu.incubate.distributed.models.moe.dropless import (
+        held_experts,
+    )
+
+    def fn(x, idx, w, gate, up, down):
+        return held_experts(x, idx, w, gate, up, down, first=0)
+
+    def args(sds):
+        return (sds((tokens, 7168), jnp.bfloat16), sds((tokens, 8), jnp.int32),
+                sds((tokens, 8), jnp.float32),
+                sds((12, 7168, 2048), jnp.bfloat16),
+                sds((12, 7168, 2048), jnp.bfloat16),
+                sds((12, 2048, 7168), jnp.bfloat16))
+
+    return fn, args
+
+
 CASES = {
+    "mla-decode": _mla_decode,
+    "moe-gmm-decode-rows": lambda: _moe_gmm(MLA_ROWS),
+    "moe-gmm-mixed-stream": lambda: _moe_gmm(MLA_CHUNK + MLA_ROWS),
     "ragged-bf16-pool": lambda: _ragged(quantized=False),
     "ragged-int8-pool": lambda: _ragged(quantized=True),
     "flash-fwd-s2048": lambda: _flash(grad=False),
@@ -264,3 +319,79 @@ def test_pool_stays_in_the_kernels_layout(case, one_chip, monkeypatch):
     assert not copies, f"{len(copies)} whole-pool re-layout copies"
     pool_bytes = 2 * CELL_HKV * CELL_POOL[1] * PAGE * D
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+
+# ---- the latent-attention cell's two programs, at the cell's sizes ---------
+
+def _abstract_kimi():
+    """(model, state shapes, configuration) of the benchmark's
+    kimi-k2.7-code-ep32 at its full depth, no parameter materialised: the
+    layers are built inside `jax.eval_shape`, and the programs below take
+    every parameter as an operand."""
+    import json
+
+    from benchmarks import kimi_model
+    from paddle_tpu.framework import random as prandom
+    from paddle_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "kimi-k2.7-code-ep32.json")) as f:
+        raw = json.load(f)
+    cfg = kimi_model.load_config(raw)
+    made = {}
+
+    def make():
+        with prandom.rng_guard(jax.random.PRNGKey(0)):
+            made["model"] = DeepseekV3ForCausalLM(
+                kimi_model.model_config(cfg, MLA_MAX_LEN, "bfloat16"))
+        return made["model"].raw_state_dict()
+
+    return made, jax.eval_shape(make), raw
+
+
+def test_latent_programs_at_the_cells_sizes(one_chip, monkeypatch):
+    """`serve.decode_block` and `serve.ragged` of the real engine over the
+    latent pool, lowered for the described v5e: the kernels are in, no copy
+    is shaped like a pool, and arguments + temporaries are what the
+    configuration file's `compile_memory_gib` says (under 15.0 GiB)."""
+    from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+    from paddle_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    made, state, raw = _abstract_kimi()
+    depth = raw["num_hidden_layers"]
+    eng = ContinuousBatchingEngine(
+        made["model"], max_seqs=MLA_ROWS, page_size=PAGE,
+        max_len=MLA_MAX_LEN, prefill_chunk=MLA_CHUNK, decode_block=MLA_K,
+        num_pages=2)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    st = {n: sds(v.shape, v.dtype) for n, v in state.items()}
+    pools = tuple((sds(MLA_POOL, jnp.bfloat16),) for _ in range(depth))
+    S, T = MLA_ROWS, MLA_CHUNK + MLA_ROWS
+    i32, greedy = jnp.int32, (False, 1.0, 0, 1.0)
+    table, per_row, keys = (sds((S, MLA_NPAGES), i32), sds((S,), i32),
+                            sds((MLA_K, S, 2), jnp.uint32))
+    lowered = {
+        "decode_block": eng._decode_block_fn(greedy, MLA_K)._jitted.lower(
+            st, sds((S, 1), i32), pools, table, per_row, per_row, keys),
+        "ragged": eng._ragged_fn(greedy)._jitted.lower(
+            st, sds((T,), i32), sds((S + 1,), i32), sds((T,), i32),
+            sds((T,), i32), sds((T,), jnp.bool_), sds((S, 1), jnp.bool_),
+            sds((S, 1), i32), pools, table, table, per_row, per_row, keys),
+    }
+    pool = ",".join(map(str, MLA_POOL))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "mla_decode_attention" in text and "gmm" in text, name
+        copies = re.findall(rf"= bf16\[{pool}\][^ ]* copy\(", text)
+        assert not copies, f"{name}: {len(copies)} pool-shaped copies"
+        ma = compiled.memory_analysis()
+        gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
+        stated = raw["compile_memory_gib"][name]
+        assert gib < 15.0 and abs(gib - stated) < 0.05, (name, gib, stated)
