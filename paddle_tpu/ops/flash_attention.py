@@ -13,7 +13,6 @@ jax's kernel wants [batch, heads, seq, head_dim], so we transpose around it —
 XLA fuses the transposes into the surrounding ops.
 """
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -106,7 +105,42 @@ def _pallas_flash(q, k, v, causal, scale):
 _SPLASH_CACHE = {}
 
 
-def _splash_kernel(hq, sq, sk_len, causal, cache_tag=""):
+def _fit(cap, seq):
+    """Largest multiple of 128 that divides `seq` and is at most `cap`."""
+    block = min(cap, seq) // 128 * 128
+    while seq % block:
+        block -= 128
+    return block
+
+
+def _splash_block_sizes(sq, sk_len, head_dim):
+    """The splash kernel's tiles, from the shape alone (PERF.md §6, PR 30,
+    measured on a v5e at seq 384 to 8192, head dim 64 to 256): 1024 query
+    rows x 1024 keys in memory, 512 keys a compute step, forward and
+    backward alike, won at every shape; the library's default of 128
+    everywhere is 9x slower at seq 4096. VMEM sets the caps: 2048 x 2048
+    is refused, and past head dim 256 so is 1024, so a tile holds at most
+    256 Ki elements of a head. The fused backward (dq inside the dkv kernel,
+    7 matmuls for 9, a fifth faster) leaves one partial of dq per key
+    block in HBM, in q's dtype, and sums them afterwards: taken while those
+    are at most 8 Mi elements a head and row (seq 8192 at head dim 128, the
+    largest measured), the three-kernel backward beyond."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    cap = min(1024, max(128, (1 << 18) // head_dim // 128 * 128))
+    bq, bkv = _fit(cap, sq), _fit(cap, sk_len)
+    bkv_compute = _fit(512, bkv)
+    fused = (sk_len // bkv) * sq * head_dim <= 8 << 20
+    dq = {} if fused else {"block_q_dq": bq, "block_kv_dq": bkv}
+    return sk.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv_compute,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv_compute,
+        use_fused_bwd_kernel=fused, **dq)
+
+
+def _splash_kernel(hq, sq, sk_len, head_dim, causal, cache_tag=""):
     """Build (and cache) a splash-attention kernel for static shapes.
 
     Construction MUST stay concrete even when the cache miss happens inside
@@ -121,26 +155,15 @@ def _splash_kernel(hq, sq, sk_len, causal, cache_tag=""):
         splash_attention_mask as sm,
     )
 
-    # FLAGS_splash_block_q/kv: on-chip-tunable kernel tiles (same pattern as
-    # FLAGS_flash_block_q/k for the MHA kernel); None = library defaults
-    env_q = os.environ.get("FLAGS_splash_block_q")
-    env_kv = os.environ.get("FLAGS_splash_block_kv")
-    key = (cache_tag, hq, sq, sk_len, causal, env_q, env_kv)
+    key = (cache_tag, hq, sq, sk_len, head_dim, causal)
     kernel = _SPLASH_CACHE.get(key)
     if kernel is None:
         mk = sm.CausalMask if causal else (lambda shape: sm.FullMask(shape))
         mask = sm.MultiHeadMask([mk((sq, sk_len)) for _ in range(hq)])
-        kw = {}
-        if env_q or env_kv:
-            bq = min(int(env_q or 512), sq)
-            bkv = min(int(env_kv or 512), sk_len)
-            kw["block_sizes"] = sk.BlockSizes(
-                block_q=bq, block_kv=bkv, block_kv_compute=bkv,
-                block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
-                block_q_dq=bq, block_kv_dq=bkv)
         with jax.ensure_compile_time_eval():
-            kernel = sk.make_splash_mha(mask=mask, head_shards=1,
-                                        q_seq_shards=1, **kw)
+            kernel = sk.make_splash_mha(
+                mask=mask, head_shards=1, q_seq_shards=1,
+                block_sizes=_splash_block_sizes(sq, sk_len, head_dim))
         _SPLASH_CACHE[key] = kernel
     return kernel
 
@@ -148,7 +171,8 @@ def _splash_kernel(hq, sq, sk_len, causal, cache_tag=""):
 def _splash_impl(qt, kt, vt, causal, scale):
     """GQA/MQA-native Pallas splash-attention kernel — kv heads stay
     unexpanded (the repeat-based fallback materializes hq/hk× more KV)."""
-    kernel = _splash_kernel(qt.shape[1], qt.shape[2], kt.shape[2], causal)
+    kernel = _splash_kernel(qt.shape[1], qt.shape[2], kt.shape[2], qt.shape[3],
+                            causal)
     out = jax.vmap(kernel)((qt * scale).astype(vt.dtype), kt, vt)
     return out
 
@@ -267,7 +291,7 @@ def _splash_varlen(q, k, v, cu_q, cu_k, causal, scale):
     qt = jnp.swapaxes(qp, 0, 1)  # [H, T, D]
     kt = jnp.swapaxes(kp, 0, 1)
     vt = jnp.swapaxes(vp, 0, 1)
-    kernel = _splash_kernel(hq, qt.shape[1], kt.shape[1], causal,
+    kernel = _splash_kernel(hq, qt.shape[1], kt.shape[1], d, causal,
                             cache_tag="varlen")
     seg = sk.SegmentIds(q=seg_q, kv=seg_k)
     out = kernel((qt * scale).astype(vt.dtype), kt, vt, segment_ids=seg)
@@ -314,7 +338,8 @@ def flash_attention_packed(q, k, v, segment_ids, causal=True, scale=None):
         )
 
         S = qt.shape[2]
-        kernel = _splash_kernel(hq, S, S, causal, cache_tag="packed")
+        kernel = _splash_kernel(hq, S, S, head_dim, causal,
+                                cache_tag="packed")
         # splash is GQA-native: kv heads stay unexpanded in kb/vb
         def one(qb, kb, vb, sb):
             return kernel((qb * scale).astype(vb.dtype), kb, vb,

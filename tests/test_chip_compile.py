@@ -310,6 +310,58 @@ def test_kernel_compiles_for_v5e(case, one_chip, monkeypatch):
     _compile_for_chip(CASES[case], one_chip, monkeypatch)
 
 
+# the training cell's attention call (benchmarks/workloads/
+# mistral7b-pretrain-4k.json: batch 2 x seq 4096), and three shapes that hold
+# the rule's caps: the widest head at the full tile, a head past it (where
+# VMEM refuses 1024 rows), and the longest context, the last two with the
+# three-kernel backward (for one row of 32768 the fused one's dq partials
+# alone would need 8 GiB)
+SPLASH_GRAD_CASES = {
+    "cell-b2-s4096-d128": (2, 4096, D, 1024, True),
+    "b2-s4096-d256": (2, 4096, 256, 1024, True),
+    "b2-s4096-d512": (2, 4096, 512, 512, False),
+    "b1-s32768-d128": (1, 32768, D, 1024, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPLASH_GRAD_CASES))
+def test_splash_grad_runs_on_the_rules_tiles(case, one_chip, monkeypatch):
+    """`jax.grad` of `_splash_impl`, 32 / 8 heads, causal, lowered for the
+    described v5e: the Mosaic kernels are in, and their scratch is shaped by
+    the rule's query tile, not by the library's 128 x 128 default (9x slower
+    at the cell's shape, PERF.md PR 30). The only guard a CPU suite can have
+    against a tile the chip's VMEM refuses."""
+    from paddle_tpu.ops.flash_attention import _splash_impl
+
+    batch, seq, head_dim, bq, fused = SPLASH_GRAD_CASES[case]
+
+    def fn(q, k, v):
+        def loss(q, k, v):
+            out = _splash_impl(q, k, v, True, head_dim ** -0.5)
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def args(sds):
+        kv = sds((batch, HKV_GQA, seq, head_dim), jnp.bfloat16)
+        return (sds((batch, H, seq, head_dim), jnp.bfloat16), kv, kv)
+
+    compiled = _compile_for_chip(lambda: (fn, args), one_chip, monkeypatch)
+    # '%splash_mha_fwd_residuals.1 = (f32[2,1024,128]{..}, ..) custom-call(':
+    # the kernel's name and its results, its f32 scratch first: the forward's
+    # (bq, 128) statistics, the backward's (bkv, head_dim) accumulators
+    calls = re.findall(r"%splash_mha_(fwd|dkv|dq)\w*[.\d]* = (.*?) custom-call\(",
+                       compiled.as_text())
+    assert {kind for kind, _ in calls} == (
+        {"fwd", "dkv"} if fused else {"fwd", "dkv", "dq"})
+    lead = f"{batch}," if batch > 1 else ""   # vmap over one row is no dim
+    for kind, results in calls:
+        assert f"f32[{lead}128," not in results, (kind, results)
+        tile = f"f32[{lead}{bq},{128 if kind == 'fwd' else head_dim}]"
+        assert tile in results, (kind, results)
+    # the fused backward's dq partials, or none: under 1 GiB either way
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("case", sorted(POOL_CASES))
 def test_pool_stays_in_the_kernels_layout(case, one_chip, monkeypatch):
     compiled = _compile_for_chip(POOL_CASES[case], one_chip, monkeypatch,

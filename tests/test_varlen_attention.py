@@ -145,7 +145,8 @@ def test_splash_kernel_construction_is_trace_safe():
 
     def f(x):
         # unique shape so the cache misses inside THIS trace
-        built["k"] = fa._splash_kernel(2, 384, 384, True, cache_tag="regress")
+        built["k"] = fa._splash_kernel(2, 384, 384, 128, True,
+                                       cache_tag="regress")
         return x * 2
 
     jax.jit(f)(jnp.ones(()))
