@@ -453,8 +453,7 @@ def run_paged_serve(hidden=2048, layers=12, heads=16, kv_heads=None, inter=5504,
             model, max_seqs=max_seqs, page_size=pc_page,
             max_len=1024,
             decode_block=8, enable_prefix_cache=flag)
-        e2.warmup([len(p) for p in pc_prompts],
-                  shared_prefix_lens=[sys_len] if flag else ())
+        e2.warmup([len(p) for p in pc_prompts])
         if flag:
             # seed the cache so the timed serve hits it
             e2.serve([pc_prompts[0]], max_new_tokens=1)

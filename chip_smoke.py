@@ -260,7 +260,7 @@ def serve_phase(sizes, seed, strict):
     eng = ContinuousBatchingEngine(
         model, max_seqs=sizes.max_seqs, page_size=sizes.page,
         max_len=sizes.max_len, prefill_chunk=sizes.prefill_chunk,
-        decode_block=sizes.decode_block, ragged=True)
+        decode_block=sizes.decode_block)
     t0 = time.perf_counter()
     eng.warmup(buckets=sorted(sizes.prompt_lens))
     warmup_s = time.perf_counter() - t0

@@ -24,8 +24,8 @@ from ..engine import Finding, rule
 #: engine functions forming the decode dispatch critical section
 DECODE_CRITICAL = {
     "paddle_tpu/inference/continuous.py": {
-        "step", "_step_ladder", "_dispatch_decode", "_read_back_in_lock",
-        "_process_block", "_advance_prefill", "drain",
+        "step", "_dispatch_decode", "_read_back_in_lock",
+        "_process_block", "drain",
         # disaggregation (ISSUE 16): adopting a handed-off request inserts
         # pages on the decode replica's dispatch path — it must stay as
         # host-sync-free as any other admission (jnp.asarray uploads only;

@@ -3,19 +3,21 @@ capability: paddle/fluid/inference AnalysisPredictor's serving class +
 PaddleNLP block-attention / vLLM-style continuous batching; PAPERS.md
 ragged-paged-attention).
 
-TPU-native shape: compute is two jitted programs with STATIC shapes —
-a bucketed PREFILL (compiled per prompt bucket, reusing the dense
-fixed-cache path) whose KV lands in pool pages via a jitted insert, and a
-single DECODE step over all `max_seqs` slots driving the model through
-paged cache entries (kernel-backed paged attention on TPU). The
+TPU-native shape: compute is two jitted programs with STATIC shapes per
+sampling configuration — a MIXED step (one packed [T]-token forward that
+carries every pending prompt chunk and every decode row through the ragged
+paged cache, then scans the remaining decode_block-1 decode steps) and a
+decode BLOCK (decode_block steps over all `max_seqs` slots through paged
+cache entries; kernel-backed paged attention on TPU). Prompt length is a
+runtime operand of the mixed step, not a compile-time bucket. The
 scheduler is plain host Python between jitted calls: retire finished
 sequences, free their pages, admit queued requests into freed slots
 mid-flight of everyone else — the continuous part. Memory is bounded by
 the page pool, not by max_seqs × max_len:
 
-- admission is reservation-based: a request enters only when
-  ceil((true_len + max_new) / page_size) pages (and the prefill bucket's
-  pages) are free, so decode can never deadlock on pool exhaustion;
+- admission is reservation-based and does no device work: a request
+  enters only when ceil((true_len + max_new) / page_size) pages are free,
+  so decode can never deadlock on pool exhaustion;
 - page 0 is scratch: inactive slots' page tables point at it, their
   writes land there harmlessly (lengths masks it out of every real row).
 
@@ -27,14 +29,12 @@ QuantizedTensor layout the Pallas kernel consumes natively.
 Data-plane pipeline (ISSUE 6): the engine overlaps host scheduling with
 device compute instead of ping-ponging between them —
 
-- **chunked prefill** (``prefill_chunk=``): a long prompt lands in
-  page-aligned chunks scheduled BETWEEN decode blocks (each chunk is the
-  prefix-cache machinery's gather + suffix-prefill over the pages already
-  inserted), so a 2048-token prompt no longer stalls every co-tenant's
-  TPOT for one monolithic bucketed dispatch, and the big prompt-bucket
-  programs are replaced by a handful of chunk-shaped ones. Mid-prefill
-  slots keep their page-table row at scratch, so concurrent decode
-  dispatches can't write into half-built pages.
+- **chunked prefill** (``prefill_chunk=``): the mixed step's budget of
+  prompt tokens. A long prompt streams into the pool at most that many
+  tokens a dispatch, shortest remainder first, beside everyone's decode
+  rows, so a 2048-token prompt does not stall every co-tenant's TPOT.
+  A mid-prefill slot's scan rows are routed to the scratch page, so the
+  decode steps of a dispatch cannot write into half-built pages.
 - **double-buffered async decode** (``async_decode=``): decode block k+1
   is dispatched chained off block k's device-resident last-token row
   BEFORE block k's tokens are read back; the host retire/admit/emit work
@@ -64,7 +64,7 @@ import numpy as np
 
 from ..framework import core as _core
 from ..framework.core import Tensor
-from ..generation import _make_sampler, prompt_bucket
+from ..generation import _make_sampler
 from ..observability import compilemem as _compilemem
 from ..observability import devprof as _devprof
 from ..observability import goodput as _goodput
@@ -72,7 +72,6 @@ from ..observability import tracing as _trace
 from ..observability.metrics import registry as _registry
 from ..ops.paged_attention import KVCacheSpec
 from ..testing import chaos
-from ..utils.envs import env_bool as _env_bool
 from ..utils.envs import env_int as _env_int
 from ..utils.metrics_bus import counters
 from ..utils.retry import RetryPolicy
@@ -364,25 +363,23 @@ def _row_sampler(do_sample, temperature, top_k, top_p):
 
 
 class _PrefillState:
-    """One slot mid-chunked-prefill: the full page reservation plus how
-    many of those pages already hold valid KV. The engine's page_table row
-    and lengths entry stay ZERO until graduation, so decode dispatches
-    running between chunks write this slot's fed token to the scratch page
-    instead of into half-built pages."""
+    """One slot mid-prefill: the full page reservation plus how many of
+    the prompt's tokens already sit in the pool. The slot's page-table row
+    is installed at admission; until graduation its row of the mixed
+    step's scan table is zero, so the decode steps of a dispatch write
+    this slot's fed token to the scratch page instead of into half-built
+    pages."""
 
-    __slots__ = ("req", "pages", "filled_pages", "n_pre0", "digests",
-                 "consumed")
+    __slots__ = ("req", "pages", "n_pre0", "digests", "consumed")
 
-    def __init__(self, req, pages, n_pre, digests):
+    def __init__(self, req, pages, n_pre, digests, consumed):
         self.req = req
         self.pages = pages          # full reservation (shared + new)
-        self.filled_pages = n_pre   # pages holding valid KV (page-aligned)
         self.n_pre0 = n_pre         # prefix-cache hit width at admission
         self.digests = digests      # prompt-page digest chain (for indexing)
-        # ragged mode: prompt TOKENS already streamed into the pool
-        # (token-granular — ragged chunks need no page alignment); the
-        # legacy chunk path keeps its page-granular filled_pages instead
-        self.consumed = None
+        # prompt TOKENS already in the pool (token-granular: a chunk needs
+        # no page alignment); starts past the prefix-cache hit
+        self.consumed = consumed
 
 
 class _InflightBlock:
@@ -407,7 +404,22 @@ class ContinuousBatchingEngine:
     def __init__(self, model, max_seqs=4, page_size=16, num_pages=None,
                  max_len=512, kv_cache_dtype=None, decode_block=8,
                  enable_prefix_cache=False, prefill_chunk=None,
-                 async_decode=True, dispatch_lock=None, ragged=None):
+                 async_decode=True, dispatch_lock=None, ragged=True):
+        if ragged is not None and not ragged:
+            # the keyword selects nothing. It is still taken because the
+            # benchmark's workload files pass `"ragged": true` and only a
+            # `benchmark` PR may edit them; it goes when they drop the key
+            raise ValueError(
+                "ragged=False named the bucket-ladder plane, which is "
+                "deleted: the ragged plane is the engine")
+        missing = [name for name in ("serving_trunk", "serving_head")
+                   if not hasattr(model, name)]
+        if missing:
+            raise TypeError(
+                f"{type(model).__name__} lacks {' and '.join(missing)}: the "
+                "engine's step programs call a model as trunk and head (the "
+                "serving protocol of models/llama.py and "
+                "models/deepseek_v3.py)")
         cfg = model.config
         self.model = model
         model.eval()
@@ -442,8 +454,7 @@ class ContinuousBatchingEngine:
         self.free_slots = list(range(max_seqs))
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
         self.lengths = np.zeros(max_seqs, np.int32)
-        self._prefill_fns = {}
-        self._insert_fns = {}
+        self._insert_fns = {}    # the KV handoff plane's (adopt_request)
         self._decode_fns = {}
         self._decode_block_fns = {}
         # ---- automatic prefix caching (vLLM-class; PAPERS.md ragged paged
@@ -451,40 +462,31 @@ class ContinuousBatchingEngine:
         # holding tokens [j*bs, (j+1)*bs) of some prompt is indexed by the
         # exact byte string of the prompt's first (j+1)*bs tokens, so a later
         # request sharing that prefix points its page table at the SAME page
-        # (refcounted) and prefills only its suffix — attention over the
-        # shared prefix is served by a jitted page-gather instead of
-        # recompute. Pages with refcount 0 stay cached (LRU-evictable) until
+        # (refcounted) and streams in only its suffix — the mixed step reads
+        # the shared pages through the row's page table like any other
+        # page. Pages with refcount 0 stay cached (LRU-evictable) until
         # the allocator needs them. Shared pages are never written: decode
         # writes at positions >= true_len and the match is capped at
         # (true_len-1)//bs pages, so every write lands in a private page.
         # ---- data-plane pipeline knobs (ISSUE 6) --------------------------
-        # prefill_chunk: page-aligned token count per prefill chunk; None/0
-        # disables chunking (monolithic bucketed prefill, the legacy path).
-        # Prompts whose post-prefix suffix fits one chunk still prefill
-        # monolithically — chunking only changes behavior for longer ones.
+        # prefill_chunk: the mixed step's budget of prompt tokens a
+        # dispatch, rounded down to whole pages; None/0 takes the default
+        # budget below. It composes with every pool dtype: a token attends
+        # through the pool whatever chunk wrote it, so the chunk size
+        # cannot change an output.
         if prefill_chunk:
-            if kv_cache_dtype == "int8":
-                # chunk j re-reads earlier chunks' KV through the pool; an
-                # int8 pool would make that read lossy while the monolithic
-                # path attends to exact float KV — refuse rather than break
-                # the engine's exact-equality contract (same rule as the
-                # prefix cache)
-                raise ValueError("prefill_chunk does not compose with "
-                                 "kv_cache_dtype='int8' (lossy chunk "
-                                 "re-reads would change outputs vs the "
-                                 "monolithic path)")
             prefill_chunk = max(int(prefill_chunk) // page_size, 1) * page_size
         self.prefill_chunk = int(prefill_chunk or 0)
         self.async_decode = bool(async_decode)
-        # per-engine execution lock (injectable so bench_serving.py can
-        # reproduce the pre-ISSUE-6 process-wide lock by sharing one
-        # instance across baseline engines); first-trace additionally takes
-        # the global _COMPILE_LOCK — see _locked_dispatch()
+        # per-engine execution lock (injectable: engines handed one
+        # instance serialize their jitted sections like the pre-ISSUE-6
+        # process-wide lock did); first-trace additionally takes the
+        # global _COMPILE_LOCK — see _locked_dispatch()
         self.dispatch_lock = dispatch_lock or _StampedRLock(
             name="inference.dispatch_lock")
         self._warm = set()          # program keys that have run successfully
         self._last_dispatch_cold = False  # last _locked_dispatch traced?
-        self._prefilling = {}       # slot -> _PrefillState (chunked prefill)
+        self._prefilling = {}       # slot -> _PrefillState (mid-prefill)
         self._inflight = None       # the ONE in-flight _InflightBlock
         # requests retired while an out-of-band caller (export_pages'
         # _settle_inflight) processed the in-flight block: step() returns
@@ -525,8 +527,7 @@ class ContinuousBatchingEngine:
         from collections import OrderedDict
 
         self._evictable = OrderedDict()  # page_id -> None; LRU order
-        self._gather_fns = {}
-        self._prefill_suffix_fns = {}
+        self._gather_fns = {}    # the KV handoff plane's (export_pages)
         self._cache_weights_version = None
         # decode_block: max decode steps fused into ONE device dispatch
         # (lax.scan). Each dispatch costs a full host→device round trip, so
@@ -568,34 +569,20 @@ class ContinuousBatchingEngine:
         self._lora_slots = _env_int("PADDLE_LORA_SLOTS", 4)
         self._lora_device = OrderedDict()   # digest -> (a_dev, b_dev); LRU
         self._lora_stack_cache = OrderedDict()  # (rank, digests) -> stacks
-        self._lora_prefill_fns = {}
-        self._lora_suffix_fns = {}
         self._lora_decode_fns = {}
         self._lora_block_fns = {}
         self._lora_dims = (getattr(cfg, "hidden_size", None),
                            getattr(cfg, "vocab_size", None))
-        # ---- ragged dispatch plane (ISSUE 20) -----------------------------
+        # ---- the dispatch plane (ISSUE 20) --------------------------------
         # One packed [T]-token forward carries every prefill chunk AND every
-        # decode row per step (ops/ragged_paged_attention.py), so the
-        # per-bucket program ladder (prefill[b]/suffix[p,b]/insert[b]/
-        # gather[p] × sampling × rank) collapses to ONE mixed program plus
-        # the fixed-k decode block per (sampling, kv-dtype, lora-rank).
-        # PADDLE_SERVING_RAGGED=0 is the kill switch: every legacy path is
-        # byte-for-byte untouched when off. Ragged needs the split
-        # trunk/head call (the model protocol's serving_trunk /
-        # serving_head), so models without it fall back.
-        if ragged is None:
-            ragged = _env_bool("PADDLE_SERVING_RAGGED", True)
-        self._ragged = bool(ragged) and hasattr(model, "serving_trunk")
-        if self._latent:
-            # the planes below were written for K and V pools; on a pool of
-            # latent rows each refuses by name, none falls back
-            if not self._ragged:
-                self._refuse_latent("the bucket-ladder plane (ragged=False)")
-            if self.enable_prefix_cache:
-                self._refuse_latent("the prefix cache (enable_prefix_cache)")
-        # token budget for prompt chunks per mixed dispatch (token-granular:
-        # ragged writes need no page alignment, unlike legacy prefill_chunk)
+        # decode row per step (ops/ragged_paged_attention.py): ONE mixed
+        # program plus the fixed-k decode block per (sampling, kv-dtype,
+        # lora-rank), whatever the prompt lengths.
+        if self._latent and self.enable_prefix_cache:
+            # written for K and V pools; on a pool of latent rows it
+            # refuses by name
+            self._refuse_latent("the prefix cache (enable_prefix_cache)")
+        # token budget for prompt chunks per mixed dispatch
         self._ragged_chunk = max(self.prefill_chunk or min(256, max_len), 1)
         # packed token-stream width: chunk budget + one feed token per slot
         self._ragged_tokens = self._ragged_chunk + max_seqs
@@ -742,16 +729,21 @@ class ContinuousBatchingEngine:
                 self._prefix_index[key] = pages[j]
                 self._page_hash[pages[j]] = key
 
-    # ---- prefix-cache jitted pieces ---------------------------------------
+    # ---- the KV handoff plane's jitted pieces (ISSUE 16) ------------------
+    # export_pages gathers a request's pages dense, adopt_request scatters
+    # them into another engine's pool (_insert below). Compiled by a
+    # handoff, never by warm-up; no serving dispatch uses either.
     def _gather_prefix(self, n_pages):
-        """pools + page ids [n_pages] -> dense prefix KV [L, n*bs, Hkv, D]."""
+        """pools + page ids [n_pages] -> dense KV [L, n*bs, Hkv, D]: what
+        export_pages puts in a handoff bundle."""
         fn = self._gather_fns.get(n_pages)
         if fn is not None:
             return fn
         bs = self.page_size
 
         def read(pool, page_ids):
-            # float pools only: int8 + prefix cache is refused in __init__
+            # float pools only: an int8 pool raises here and export_pages'
+            # caller degrades to blended serving
             arr = pool[:, page_ids]
             # [Hkv, n, bs, D] -> [n*bs, Hkv, D]
             arr = jnp.transpose(arr, (1, 2, 0, 3))
@@ -768,161 +760,15 @@ class ContinuousBatchingEngine:
                                            len(self._gather_fns))
         return fn
 
-    def _prefill_suffix(self, n_prefix_pages, suffix_bucket, sampling):
-        """Prefill ONLY the suffix, attending to the gathered prefix KV via
-        the model's fixed-cache path (cache_position = prefix length, whose
-        absolute-position mask handles the offset). Compiled per
-        (prefix-page-count, suffix bucket, sampling) — repeated system
-        prompts hit a handful of distinct prefix lengths, so the program
-        cache stays small."""
-        key3 = (n_prefix_pages, suffix_bucket, sampling)
-        fn = self._prefill_suffix_fns.get(key3)
-        if fn is not None:
-            return fn
-        model = self.model
-        sampler = _row_sampler(*sampling)
-        plen = n_prefix_pages * self.page_size
-
-        def prefill_suf(state, ks_pre, vs_pre, ids_suf, suf_len, key):
-            overrides = {k: Tensor(v, stop_gradient=True) for k, v in state.items()}
-            caches = model.init_cache(1, plen + suffix_bucket)
-            wrapped = []
-            for l, (kc, vc) in enumerate(caches):
-                kc = kc.at[0, :plen].set(ks_pre[l].astype(kc.dtype))
-                vc = vc.at[0, :plen].set(vs_pre[l].astype(vc.dtype))
-                wrapped.append((Tensor(kc), Tensor(vc)))
-            logits, presents = model.functional_call(
-                overrides, Tensor(ids_suf), past_key_values=wrapped,
-                cache_position=Tensor(jnp.int32(plen)), use_cache=True,
-                training=False,
-            )
-            last = jax.lax.dynamic_index_in_dim(logits._data, suf_len - 1,
-                                                axis=1, keepdims=False)
-            tok0 = sampler(last, key[None])[0].astype(jnp.int32)
-            ks = jnp.stack([p[0]._data[0, plen:] for p in presents])
-            vs = jnp.stack([p[1]._data[0, plen:] for p in presents])
-            return tok0, ks, vs
-
-        fn = self._prefill_suffix_fns[key3] = _compilemem.ledgered_jit(
-            prefill_suf,
-            key=f"serve.suffix[p{n_prefix_pages},b{suffix_bucket},"
-                f"s{sampling}]")
-        _compilemem.ledger.note_cache_size("serve.suffix",
-                                           len(self._prefill_suffix_fns))
-        return fn
-
-    # ---- dispatch locking -------------------------------------------------
-    @contextmanager
-    def _locked_dispatch(self, *keys):
-        """Guard a jitted section. Warm program keys take only this
-        engine's execution lock; any cold key additionally takes the
-        process-wide compile lock for the duration (first call = trace).
-        Keys are marked warm only after the section SUCCEEDS, so a
-        retried transient failure recompiles under the lock again.
-        ``_last_dispatch_cold`` records whether THIS section traced — the
-        serving-goodput split attributes cold sections to 'compile'
-        instead of prefill/decode."""
-        cold = [k for k in keys if k not in self._warm]
-        self._last_dispatch_cold = bool(cold)
-        try:
-            if not cold:
-                with self.dispatch_lock:
-                    chaos.site("obs.oom")
-                    yield
-                return
-            with _COMPILE_LOCK, self.dispatch_lock:
-                chaos.site("obs.oom")
-                yield
-            self._warm.update(cold)
-        except Exception as e:
-            # OOM-forensics seam (ISSUE 8): every engine dispatch —
-            # prefill, gather/suffix, insert, decode — funnels through
-            # here, so one interception covers them all. The report
-            # commits (ledger + HBM budget + active slots/pages) before
-            # the exception continues into the per-request isolation /
-            # replica-death machinery.
-            _compilemem.maybe_oom_report(
-                e, program=str(keys[0]) if keys else None)
-            raise
-
-    def _xprof_annotation(self, req):
-        """Host-side profiler annotation carrying the request's trace_id
-        (``rtrace:<id>``): xprof's trace viewer shows it on the host
-        timeline aligned with the device ops this dispatch enqueued — the
-        join key between request traces and device profiles. Per-request
-        program metadata is impossible (programs are compiled once per
-        bucket and shared across requests), so the correlation is by host
-        timeline, not op name. No-op without a trace."""
-        if req.trace is None:
-            return nullcontext()
-        try:
-            return jax.profiler.TraceAnnotation(
-                f"rtrace:{req.trace.ctx.trace_id}")
-        except Exception:
-            return nullcontext()
-
-    def _captured_state(self):
-        """The version-checked raw_state_dict capture shared by admission
-        and decode — keeps the O(n_params) tree walk off the latency-
-        critical loop. Version read BEFORE the capture: a mutation landing
-        in between tags fresh state with a stale version, which merely
-        forces an extra refresh next time — never a stale serve.
-
-        The refresh happens under the COMPILE lock: a sibling replica
-        tracing the shared model temporarily rebinds its state through the
-        framework's thread-oblivious Tensor plumbing, and a concurrent
-        raw_state_dict() walk would capture those tracers (then feed them
-        to a compiled program — the exact leak the old process-wide
-        dispatch lock hid). Cache hits stay lock-free: a cached capture
-        was taken outside any trace window and holds real arrays."""
-        ver = _core.tensor_mutation_version()
-        cache = self._decode_state_cache
-        if cache is not None and cache[0] == ver:
-            return cache[1]
-        with _COMPILE_LOCK:
-            state = self.model.raw_state_dict()
-        self._decode_state_cache = (ver, state)
-        return state
-
-    # ---- jitted pieces ----------------------------------------------------
-    def _prefill(self, bucket, sampling):
-        fn = self._prefill_fns.get((bucket, sampling))
-        if fn is not None:
-            return fn
-        model = self.model
-        sampler = _row_sampler(*sampling)
-
-        def prefill(state, ids_p, true_len, key):
-            overrides = {k: Tensor(v, stop_gradient=True) for k, v in state.items()}
-            caches = model.init_cache(1, bucket)
-            wrapped = [(Tensor(kc), Tensor(vc)) for kc, vc in caches]
-            logits, presents = model.functional_call(
-                overrides, Tensor(ids_p), past_key_values=wrapped,
-                cache_position=Tensor(jnp.int32(0)), use_cache=True,
-                training=False,
-            )
-            last = jax.lax.dynamic_index_in_dim(logits._data, true_len - 1,
-                                                axis=1, keepdims=False)  # [1, V]
-            tok0 = sampler(last, key[None])[0].astype(jnp.int32)
-            ks = jnp.stack([p[0]._data[0] for p in presents])  # [L, S0b, Hkv, D]
-            vs = jnp.stack([p[1]._data[0] for p in presents])
-            return tok0, ks, vs
-
-        fn = self._prefill_fns[(bucket, sampling)] = _compilemem.ledgered_jit(
-            prefill, key=f"serve.prefill[b{bucket},s{sampling}]")
-        _compilemem.ledger.note_cache_size("serve.prefill",
-                                           len(self._prefill_fns))
-        return fn
-
     @staticmethod
     def _pages_for_bucket(bucket, bs):
         return -(-bucket // bs)  # ceil: a bucket smaller than a page still needs one
 
     def _insert(self, bucket):
-        """Scatter a bucket's dense prefill KV into this slot's pool pages.
-        The bucket is padded up to a whole number of pages (a 16-token
-        bucket under page_size=64 still writes one page; the pad region is
-        masked out by `lengths` everywhere)."""
+        """Scatter a handoff bundle's dense KV (`bucket` tokens, what
+        _gather_prefix exported) into the pool pages adopt_request
+        reserved. The bucket is padded up to a whole number of pages (the
+        pad region is masked out by `lengths` everywhere)."""
         fn = self._insert_fns.get(bucket)
         if fn is not None:
             return fn
@@ -967,6 +813,80 @@ class ContinuousBatchingEngine:
                                            len(self._insert_fns))
         return fn
 
+    # ---- dispatch locking -------------------------------------------------
+    @contextmanager
+    def _locked_dispatch(self, *keys):
+        """Guard a jitted section. Warm program keys take only this
+        engine's execution lock; any cold key additionally takes the
+        process-wide compile lock for the duration (first call = trace).
+        Keys are marked warm only after the section SUCCEEDS, so a
+        retried transient failure recompiles under the lock again.
+        ``_last_dispatch_cold`` records whether THIS section traced — the
+        serving-goodput split attributes cold sections to 'compile'
+        instead of decode."""
+        cold = [k for k in keys if k not in self._warm]
+        self._last_dispatch_cold = bool(cold)
+        try:
+            if not cold:
+                with self.dispatch_lock:
+                    chaos.site("obs.oom")
+                    yield
+                return
+            with _COMPILE_LOCK, self.dispatch_lock:
+                chaos.site("obs.oom")
+                yield
+            self._warm.update(cold)
+        except Exception as e:
+            # OOM-forensics seam (ISSUE 8): every engine dispatch — mixed
+            # step, decode block, handoff insert — funnels through
+            # here, so one interception covers them all. The report
+            # commits (ledger + HBM budget + active slots/pages) before
+            # the exception continues into the per-request isolation /
+            # replica-death machinery.
+            _compilemem.maybe_oom_report(
+                e, program=str(keys[0]) if keys else None)
+            raise
+
+    def _xprof_annotation(self, req):
+        """Host-side profiler annotation carrying the request's trace_id
+        (``rtrace:<id>``): xprof's trace viewer shows it on the host
+        timeline aligned with the device ops this dispatch enqueued — the
+        join key between request traces and device profiles. Per-request
+        program metadata is impossible (programs are compiled once and
+        shared across requests), so the correlation is by host
+        timeline, not op name. No-op without a trace."""
+        if req.trace is None:
+            return nullcontext()
+        try:
+            return jax.profiler.TraceAnnotation(
+                f"rtrace:{req.trace.ctx.trace_id}")
+        except Exception:
+            return nullcontext()
+
+    def _captured_state(self):
+        """The version-checked raw_state_dict capture shared by admission
+        and decode — keeps the O(n_params) tree walk off the latency-
+        critical loop. Version read BEFORE the capture: a mutation landing
+        in between tags fresh state with a stale version, which merely
+        forces an extra refresh next time — never a stale serve.
+
+        The refresh happens under the COMPILE lock: a sibling replica
+        tracing the shared model temporarily rebinds its state through the
+        framework's thread-oblivious Tensor plumbing, and a concurrent
+        raw_state_dict() walk would capture those tracers (then feed them
+        to a compiled program — the exact leak the old process-wide
+        dispatch lock hid). Cache hits stay lock-free: a cached capture
+        was taken outside any trace window and holds real arrays."""
+        ver = _core.tensor_mutation_version()
+        cache = self._decode_state_cache
+        if cache is not None and cache[0] == ver:
+            return cache[1]
+        with _COMPILE_LOCK:
+            state = self.model.raw_state_dict()
+        self._decode_state_cache = (ver, state)
+        return state
+
+    # ---- jitted pieces ----------------------------------------------------
     # Per-row length CAPS (ISSUE 6): the block size is chosen from the
     # LARGEST remaining token budget in the batch, so rows with smaller
     # budgets ride past their budget inside the block (their overshoot
@@ -1079,7 +999,7 @@ class ContinuousBatchingEngine:
     #     logits = base_head(h) + scale * (h @ A) @ B
     #
     # with A [hidden, r] / B [r, vocab] float32. The lora program variants
-    # run the INNER transformer (model.llama) through functional_call —
+    # run the model's trunk (model.serving_trunk()) through functional_call —
     # exactly the ops the base programs run — then apply the same-ops base
     # head plus the gathered per-row delta. The compile-time constants are
     # (sampling, rank, block k); adapter WEIGHTS are runtime operands
@@ -1098,87 +1018,6 @@ class ContinuousBatchingEngine:
         bit-identical token the base program would have)."""
         return {k[len(prefix):]: Tensor(v, stop_gradient=True)
                 for k, v in state.items() if k.startswith(prefix)}
-
-    def _lora_prefill(self, bucket, sampling, rank):
-        """Monolithic prefill + adapter head for the request's OWN A/B
-        (per-request operands — prefill is one request wide, no stacking
-        needed). Same return contract as _prefill."""
-        key3 = (bucket, sampling, rank)
-        fn = self._lora_prefill_fns.get(key3)
-        if fn is not None:
-            return fn
-        model = self.model
-        inner, prefix = model.serving_trunk()
-        sampler = _row_sampler(*sampling)
-
-        def prefill(state, ids_p, true_len, key, a_w, b_w, scale):
-            overrides = self._trunk_overrides(state, prefix)
-            caches = model.init_cache(1, bucket)
-            wrapped = [(Tensor(kc), Tensor(vc)) for kc, vc in caches]
-            h, presents = inner.functional_call(
-                overrides, Tensor(ids_p), past_key_values=wrapped,
-                cache_position=Tensor(jnp.int32(0)), use_cache=True,
-                training=False,
-            )
-            h_last = jax.lax.dynamic_index_in_dim(h._data, true_len - 1,
-                                                  axis=1, keepdims=False)
-            base = model.serving_head(h_last, state)  # [1, V]
-            delta = ((h_last.astype(jnp.float32) @ a_w) @ b_w) * scale
-            tok0 = sampler(base + delta, key[None])[0].astype(jnp.int32)
-            ks = jnp.stack([p[0]._data[0] for p in presents])
-            vs = jnp.stack([p[1]._data[0] for p in presents])
-            return tok0, ks, vs
-
-        fn = self._lora_prefill_fns[key3] = _compilemem.ledgered_jit(
-            prefill, key=f"serve.lora_prefill[r{rank},b{bucket},s{sampling}]")
-        _compilemem.ledger.note_cache_size("serve.lora_prefill",
-                                           len(self._lora_prefill_fns))
-        return fn
-
-    def _lora_prefill_suffix(self, n_prefix_pages, suffix_bucket, sampling,
-                             rank):
-        """Prefix-cache-hit suffix prefill + adapter head. Prefix KV is
-        HEAD-independent (the adapter only touches logits), so adapter
-        requests share cached prompt pages with everyone else."""
-        key4 = (n_prefix_pages, suffix_bucket, sampling, rank)
-        fn = self._lora_suffix_fns.get(key4)
-        if fn is not None:
-            return fn
-        model = self.model
-        inner, prefix = model.serving_trunk()
-        sampler = _row_sampler(*sampling)
-        plen = n_prefix_pages * self.page_size
-
-        def prefill_suf(state, ks_pre, vs_pre, ids_suf, suf_len, key,
-                        a_w, b_w, scale):
-            overrides = self._trunk_overrides(state, prefix)
-            caches = model.init_cache(1, plen + suffix_bucket)
-            wrapped = []
-            for l, (kc, vc) in enumerate(caches):
-                kc = kc.at[0, :plen].set(ks_pre[l].astype(kc.dtype))
-                vc = vc.at[0, :plen].set(vs_pre[l].astype(vc.dtype))
-                wrapped.append((Tensor(kc), Tensor(vc)))
-            h, presents = inner.functional_call(
-                overrides, Tensor(ids_suf), past_key_values=wrapped,
-                cache_position=Tensor(jnp.int32(plen)), use_cache=True,
-                training=False,
-            )
-            h_last = jax.lax.dynamic_index_in_dim(h._data, suf_len - 1,
-                                                  axis=1, keepdims=False)
-            base = model.serving_head(h_last, state)
-            delta = ((h_last.astype(jnp.float32) @ a_w) @ b_w) * scale
-            tok0 = sampler(base + delta, key[None])[0].astype(jnp.int32)
-            ks = jnp.stack([p[0]._data[0, plen:] for p in presents])
-            vs = jnp.stack([p[1]._data[0, plen:] for p in presents])
-            return tok0, ks, vs
-
-        fn = self._lora_suffix_fns[key4] = _compilemem.ledgered_jit(
-            prefill_suf,
-            key=f"serve.lora_suffix[r{rank},p{n_prefix_pages},"
-                f"b{suffix_bucket},s{sampling}]")
-        _compilemem.ledger.note_cache_size("serve.lora_suffix",
-                                           len(self._lora_suffix_fns))
-        return fn
 
     def _lora_decode(self, sampling, rank):
         """Single-step batched multi-adapter decode: per-row indices
@@ -1276,16 +1115,16 @@ class ContinuousBatchingEngine:
                                            len(self._lora_block_fns))
         return fn
 
-    # ---- ragged mixed programs (ISSUE 20) ---------------------------------
-    # ONE program per (sampling, kv-dtype[, lora-rank]) replaces the whole
-    # bucket ladder. The packed pass runs every prompt chunk and every
-    # decode feed token in a single [T]-token forward through the ragged
-    # paged cache (prompt length is a RUNTIME operand — cu_q_lens — not a
-    # compile-time bucket), samples each participant's boundary token, then
-    # scans the remaining k-1 fixed decode steps with the legacy block
-    # body. Mid-prefill rows are excluded from the scan by construction:
-    # their caps are 0 (write position frozen at 0) and their scan_table
-    # row is all-zeros, so their scan writes land in the scratch page.
+    # ---- mixed programs (ISSUE 20) ----------------------------------------
+    # ONE program per (sampling, kv-dtype[, lora-rank]). The packed pass
+    # runs every prompt chunk and every decode feed token in a single
+    # [T]-token forward through the ragged paged cache (prompt length is a
+    # RUNTIME operand — cu_q_lens — not a compile-time bucket), samples
+    # each participant's boundary token, then scans the remaining k-1
+    # fixed decode steps with the decode block's body. Mid-prefill rows
+    # are excluded from the scan by construction: their caps are 0 (write
+    # position frozen at 0) and their scan_table row is all-zeros, so
+    # their scan writes land in the scratch page.
 
     def _ragged_fn(self, sampling):
         fn = self._ragged_fns.get(sampling)
@@ -1508,34 +1347,32 @@ class ContinuousBatchingEngine:
         return None
 
     def warmup(self, prompt_lens=None, do_sample=False, temperature=1.0,
-               top_k=0, top_p=1.0, shared_prefix_lens=(), buckets=None,
-               sampling=None, lora_ranks=()):
-        """Compile every program serve() can hit for prompts of these
-        lengths BEFORE latency-sensitive serving (reference:
-        AnalysisPredictor warmup / TRT engine build-ahead): one dummy
-        request per prompt bucket (prefill + page-insert programs — under
-        ``prefill_chunk`` the dummy serves walk the chunk ladder instead,
-        which is exactly the program set real traffic will hit), and one
-        serve of 2*decode_block-1 tokens whose shrinking tail walks every
-        power-of-two block-decode program (k = decode_block, ..., 2, 1).
-        Without this, the k=32/16/8 block programs compile inside the
-        serving loop — seconds per compile against the milliseconds of the
-        dispatch they fuse.
+               top_k=0, top_p=1.0, buckets=None, sampling=None,
+               lora_ranks=()):
+        """Compile every program serve() can hit BEFORE latency-sensitive
+        serving (reference: AnalysisPredictor warmup / TRT engine
+        build-ahead): one dummy serve per sampling configuration, which
+        dispatches the mixed step and the decode block — the whole program
+        set of steady-state traffic. Without this both compile inside the
+        serving loop, seconds against the milliseconds of a dispatch.
 
-        ``buckets`` is an alias for ``prompt_lens`` (the AOT-precompile
-        vocabulary the serving frontend uses at replica start).
-        ``sampling`` precompiles for a LIST of sampling configs in one
-        call — each entry is a ``(do_sample, temperature, top_k, top_p)``
-        tuple (or a single tuple) — since the sampler is a compile-time
-        constant of every prefill/decode program. Wall time lands in the
+        ``prompt_lens`` / ``buckets`` (an alias: the AOT-precompile
+        vocabulary the serving frontend uses at replica start) name the
+        traffic a caller expects; one of them is required, and neither
+        selects a program, prompt length being a runtime operand of the
+        mixed step. ``sampling`` precompiles for a LIST of sampling
+        configs in one call — each entry is a ``(do_sample, temperature,
+        top_k, top_p)`` tuple (or a single tuple) — since the sampler is a
+        compile-time constant of both programs. Wall time lands in the
         ``serve.compile_warmup_s`` histogram.
 
         ``lora_ranks`` (ISSUE 19) additionally compiles the per-request
-        LoRA program set for each adapter rank — lora prefill per prompt
-        bucket plus the lora decode/block ladder — by serving a
-        zero-weight adapter of that rank (adapter weights are runtime
-        operands, so warming any adapter warms them all for the rank).
-        The prefix-cache lora_suffix programs compile on first hit."""
+        LoRA program pair for each adapter rank by serving a zero-weight
+        adapter of that rank (adapter weights are runtime operands, so
+        warming any adapter warms them all for the rank).
+
+        The KV handoff plane's gather and insert programs are compiled by a
+        handoff (export_pages / adopt_request), never here."""
         if buckets is not None:
             prompt_lens = buckets
         if prompt_lens is None:
@@ -1553,10 +1390,10 @@ class ContinuousBatchingEngine:
             # the bench contract separate them by this label
             with _compilemem.ledger.trigger("warmup"):
                 for cfg in configs:
-                    self._warmup_one(prompt_lens, shared_prefix_lens, *cfg)
+                    self._warmup_serve(*cfg)
                 for rank in lora_ranks:
                     for cfg in configs:
-                        self._warmup_lora(prompt_lens, int(rank), *cfg)
+                        self._warmup_serve(*cfg, lora_rank=int(rank))
             self._publish_scopes()
         finally:
             _M_WARMUP.observe(time.monotonic() - t_warm0)
@@ -1575,187 +1412,34 @@ class ContinuousBatchingEngine:
                 _trace.note_program_scopes(
                     key, _compilemem.memory.compiled(key).as_text(), scopes)
 
-    def _warmup_one(self, prompt_lens, shared_prefix_lens, do_sample,
-                    temperature, top_k, top_p):
+    def _warmup_serve(self, do_sample, temperature, top_k, top_p,
+                      lora_rank=None):
+        """One dummy serve of a one-token prompt: max_new = decode_block + 1
+        touches the mixed program (the graduation step) AND the decode
+        block (the following step); a sampled configuration builds the key
+        program inside those dispatches. With ``lora_rank`` the request
+        carries a zero-weight adapter of that rank (delta == 0, as
+        harmless as the base serve), which compiles the rank's lora
+        pair."""
         kw = dict(do_sample=do_sample, temperature=temperature,
                   top_k=top_k, top_p=top_p)
+        if lora_rank is not None:
+            from ..serving.adapters import LoRAAdapter
+
+            hidden, vocab = self._lora_dims
+            kw["adapters"] = LoRAAdapter(
+                f"warmup-r{lora_rank}",
+                np.zeros((hidden, lora_rank), np.float32),
+                np.zeros((lora_rank, vocab), np.float32))
         stats_before = dict(self.stats)  # warmup must not pollute diagnostics
-        # bypass the prefix cache during the dummy serves: the all-ones
-        # prompts would cross-hit each other, compiling suffix programs
-        # INSTEAD of the full-prefill programs real cache-miss requests need
-        # (the exact mid-serve compile stall warmup exists to prevent) and
-        # leaving junk ones-pages indexed
+        # bypass the prefix cache: the all-ones dummy prompt must leave no
+        # junk page indexed
         pfx, self.enable_prefix_cache = self.enable_prefix_cache, False
         try:
-            self._warmup_serves(prompt_lens, kw)
-        finally:
-            self.enable_prefix_cache = pfx  # lint: shared-mutation-without-lock-ok (engine fields are dispatcher-owned — single-threaded by contract)
-            self.stats = stats_before  # lint: shared-mutation-without-lock-ok (same dispatcher-owned contract)
-        if pfx and shared_prefix_lens:
-            # compile the cache-HIT programs too: for each expected shared
-            # prefix length, the page gather + suffix prefill a matching
-            # request will dispatch. Pure dummy calls — no cache state or
-            # pool contents are touched (gather reads, prefill returns).
-            sampling = ((False, 1.0, 0, 1.0) if not do_sample else
-                        (True, float(temperature), int(top_k), float(top_p)))
-            with _COMPILE_LOCK:  # no tracer capture while a sibling traces
-                state = self.model.raw_state_dict()
-            bs = self.page_size
-            for sp in shared_prefix_lens:
-                for l in prompt_lens:
-                    if l <= sp:
-                        continue
-                    n_pre = min(int(sp) // bs, (int(l) - 1) // bs)
-                    while n_pre:
-                        suffix_len = int(l) - n_pre * bs
-                        if self.prefill_chunk \
-                                and suffix_len > self.prefill_chunk:
-                            region = self._chunk_plan(suffix_len)[2]
-                        else:
-                            region = self._pages_for_bucket(
-                                prompt_bucket(suffix_len), bs)
-                        if n_pre + region <= self.pages_per_seq:
-                            break
-                        n_pre -= 1
-                    if not n_pre:
-                        continue
-                    # the programs a HIT request will actually dispatch:
-                    # under chunking that is the chunk ladder shifted by
-                    # the hit width (gather+suffix at filled = n_pre,
-                    # n_pre + chunk_pages, ...), NOT the monolithic
-                    # cache-hit suffix program — warming the wrong one
-                    # leaves the real ladder to compile mid-serve
-                    suffix_len = int(l) - n_pre * bs
-                    if self.prefill_chunk \
-                            and suffix_len > self.prefill_chunk:
-                        n_full, flen, _ = self._chunk_plan(suffix_len)
-                        cpg = self.prefill_chunk // bs
-                        stages = [(n_pre + j * cpg, self.prefill_chunk)
-                                  for j in range(n_full)]
-                        stages.append((n_pre + n_full * cpg,
-                                       prompt_bucket(flen)))
-                    else:
-                        stages = [(n_pre, prompt_bucket(suffix_len))]
-                    for filled, cbucket in stages:
-                        with self._locked_dispatch(
-                                ("gather", filled),
-                                ("suffix", filled, cbucket, sampling),
-                                ("insert", cbucket)):
-                            ks, vs = self._gather_prefix(filled)(
-                                tuple(self.pools),
-                                jnp.zeros((filled,), jnp.int32))  # scratch
-                            _, cks, cvs = self._prefill_suffix(
-                                filled, cbucket, sampling)(
-                                state, ks, vs,
-                                jnp.zeros((1, cbucket), jnp.int32),
-                                jnp.int32(1), jax.random.PRNGKey(0))
-                            # dummy insert aimed at page 0: scratch absorbs
-                            # the writes, and the hit path's insert program
-                            # for this chunk shape is now warm too
-                            npg = self._pages_for_bucket(cbucket, bs)
-                            self.pools = list(self._insert(cbucket)(
-                                tuple(self.pools), cks, cvs,
-                                jnp.zeros((npg,), jnp.int32)))
-
-    def _warmup_serves(self, prompt_lens, kw):
-        if self._ragged:
-            # ragged mode (ISSUE 20): prompt length is a RUNTIME operand of
-            # the mixed program, so the whole bucket/chunk ladder collapses
-            # to ONE dummy serve per sampling config. max_new=decode_block+1
-            # touches the mixed program (graduation step) AND the fixed-k
-            # decode-only block (the following step) — the full program set
-            # steady-state traffic dispatches; sampled configs build the
-            # key program inside those dispatches.
             fit = min(self.max_len - 1,
                       self._available_pages() * self.page_size - 1)
             n = max(min(self.decode_block + 1, fit), 1)
             self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
-            return
-        # Decode-program ladder on a length-1 dummy prompt: the decode/block
-        # programs don't depend on prompt length, and the shortest prompt
-        # maximizes the admissible walk under both the max_len check and the
-        # page pool (tight pools are the engine's documented configuration).
-        # max_new=walk: remaining after the prefill token is walk-1 = 2k-2,
-        # so the loop's shrinking k visits decode_block, ..., 4, 2 exactly
-        # once each; max_new=2 leaves remaining=1 and compiles the k=1
-        # (plain per-token decode) program, which the even walk never hits.
-        ladder_bucket = prompt_bucket(1)
-        fit = min(self.max_len - 1,
-                  self._available_pages() * self.page_size - ladder_bucket)
-        runs = [2]  # k=1 (plain per-token decode) program
-        if self.decode_block > 1:
-            runs.append(2 * self.decode_block - 1)  # k = decode_block..2
-        # cap to what the pool/max_len admit: a capped walk still compiles
-        # every block program a same-pool serve can reach (k is bounded by
-        # the shrinking `remaining` either way)
-        runs = sorted({min(n, fit) for n in runs if fit >= 2})
-        for n in runs:
-            self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
-        # Prefill + page-insert programs: one representative REAL length per
-        # PROGRAM SIGNATURE (a prompt of the bucket length itself may not be
-        # servable when the bucket touches max_len). Monolithic prompts
-        # share programs per bucket; chunked prompts share them per
-        # (full-chunk count, final-chunk bucket) — two prompts in the same
-        # bucket can walk different chunk ladders, and a ladder left cold
-        # here compiles inside the latency-sensitive serve instead.
-        rep = {}
-        for l in prompt_lens:
-            l = int(l)
-            if self.prefill_chunk and l > self.prefill_chunk:
-                n_full, flen, _ = self._chunk_plan(l)
-                key = ("chunk", n_full, prompt_bucket(flen))
-            else:
-                key = ("mono", prompt_bucket(l))
-            rep[key] = min(rep.get(key, l), l)
-        for key in sorted(rep, key=str):
-            if key == ("mono", ladder_bucket) and runs:
-                continue  # the ladder serves above already compiled it
-            self.serve([np.ones(rep[key], np.int32)], max_new_tokens=1, **kw)
-
-    def _warmup_lora(self, prompt_lens, rank, do_sample, temperature,
-                     top_k, top_p):
-        """Compile the rank's lora program set with a zero-weight dummy
-        adapter (delta == 0, so the dummy serves stay as harmless as the
-        base warmup's). Adapter requests always prefill monolithically,
-        so the bucket walk is mono-only regardless of prefill_chunk."""
-        from ..serving.adapters import LoRAAdapter
-
-        hidden, vocab = self._lora_dims
-        ad = LoRAAdapter(f"warmup-r{rank}",
-                         np.zeros((hidden, rank), np.float32),
-                         np.zeros((rank, vocab), np.float32))
-        kw = dict(do_sample=do_sample, temperature=temperature,
-                  top_k=top_k, top_p=top_p, adapters=ad)
-        stats_before = dict(self.stats)
-        pfx, self.enable_prefix_cache = self.enable_prefix_cache, False
-        try:
-            if self._ragged:
-                # one dummy serve per rank covers the mixed lora program +
-                # the fixed-k lora block (same collapse as _warmup_serves)
-                fit = min(self.max_len - 1,
-                          self._available_pages() * self.page_size - 1)
-                n = max(min(self.decode_block + 1, fit), 1)
-                self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
-                return
-            ladder_bucket = prompt_bucket(1)
-            fit = min(self.max_len - 1,
-                      self._available_pages() * self.page_size
-                      - ladder_bucket)
-            runs = [2]
-            if self.decode_block > 1:
-                runs.append(2 * self.decode_block - 1)
-            runs = sorted({min(n, fit) for n in runs if fit >= 2})
-            for n in runs:
-                self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
-            rep = {}
-            for l in prompt_lens:
-                b = prompt_bucket(int(l))
-                rep[b] = min(rep.get(b, int(l)), int(l))
-            for b in sorted(rep):
-                if b == ladder_bucket and runs:
-                    continue
-                self.serve([np.ones(rep[b], np.int32)],
-                           max_new_tokens=1, **kw)
         finally:
             self.enable_prefix_cache = pfx  # lint: shared-mutation-without-lock-ok (engine fields are dispatcher-owned — single-threaded by contract)
             self.stats = stats_before  # lint: shared-mutation-without-lock-ok (same dispatcher-owned contract)
@@ -1995,9 +1679,8 @@ class ContinuousBatchingEngine:
         st = self._prefilling.pop(slot)
         self._unref_pages(st.pages)
         self.free_slots.append(slot)
-        # legacy mid-prefill slots keep their row at scratch by invariant
-        # and ragged admission installs it up front: reset either way, so
-        # no stale row leaks to the slot's next tenant
+        # admission installed the row up front: reset it, so no stale row
+        # leaks to the slot's next tenant
         self.page_table[slot] = 0
         self.lengths[slot] = 0
         self._slot_adapter.pop(slot, None)
@@ -2036,12 +1719,14 @@ class ContinuousBatchingEngine:
         _M_POOL_FRAG.set(evict / (free + evict) if free + evict else 0.0)
 
     def try_admit_one(self, req):
-        """Non-blocking admission of one :class:`EngineRequest`: page
-        reservation + bucketed prefill + pool insert. Returns
+        """Non-blocking admission of one :class:`EngineRequest`: a slot, a
+        page reservation and the slot's page-table row — no device work,
+        for any prompt length, adapter or kv dtype. The prompt streams
+        into the pool inside step()'s mixed dispatches, beside everyone's
+        decode rows, and its first token comes with the block that
+        graduates it. Returns
 
-        - ``"admitted"``  — prefilled into a slot; drive it with step()
-        - ``"done"``      — admitted AND retired (eos/max_new on the first
-                            token); ``req.result`` is set
+        - ``"admitted"``  — holds a slot; drive it with step()
         - ``"failed"``    — terminally failed in isolation (``req.error``)
         - ``"deferred"``  — try again later: no free slot, the running
                             group's sampling differs, or the pool is busy
@@ -2082,11 +1767,10 @@ class ContinuousBatchingEngine:
         adm = req.trace.child("admit") if req.trace is not None else None
         prompt = req.prompt
         true_len = len(prompt)
-        bucket = prompt_bucket(true_len)
-        if true_len + req.max_new_tokens > self.max_len or bucket > self.max_len:
+        if true_len + req.max_new_tokens > self.max_len:
             # invalid request — reject IT, not the whole batch
             self._fail_request(req, ValueError(
-                f"request {req.rid}: len {true_len} (bucket {bucket}) + "
+                f"request {req.rid}: len {true_len} + "
                 f"{req.max_new_tokens} exceeds max_len={self.max_len}"))
             if adm is not None:
                 adm.end("error", error=req.error_message)
@@ -2100,42 +1784,17 @@ class ContinuousBatchingEngine:
                 if adm is not None:
                     adm.end("error", error=req.error_message)
                 return "failed"
-        # reuse the version-checked capture across admissions AND decode
-        # steps — the O(n_params) tree walk stays off the TTFT-critical path
-        state = self._captured_state()
         bs_ = self.page_size
         if self.enable_prefix_cache:
-            self._refresh_cache_guard(state)
+            # the version-checked capture, shared with the decode steps: the
+            # O(n_params) tree walk stays off the TTFT-critical path
+            self._refresh_cache_guard(self._captured_state())
             n_pre, shared, digests = self._match_prefix(prompt, true_len)
         else:
             n_pre, shared, digests = 0, [], None
-
-        def _region_for(suffix_len):
-            # pages the PREFILL writes: the chunk ladder's exact page
-            # counts under chunking, the bucket-rounded region otherwise.
-            # Ragged prefill (ISSUE 20) writes token-exact — no bucket
-            # rounding, so reservations shrink to the true footprint.
-            if self._ragged:
-                return -(-suffix_len // bs_)
-            if self.prefill_chunk and suffix_len > self.prefill_chunk:
-                return self._chunk_plan(suffix_len)[2]
-            return self._pages_for_bucket(prompt_bucket(suffix_len), bs_)
-
-        # shrink the hit until prefix + the prefill region fit the page-
-        # table row: the suffix bucket rounds up independently, so a
-        # full-width hit can otherwise need pages_per_seq+1 pages
-        suffix_len = true_len
-        while n_pre:
-            suffix_len = true_len - n_pre * bs_
-            if n_pre + _region_for(suffix_len) <= self.pages_per_seq:
-                break
-            n_pre -= 1
-            shared = shared[:n_pre]
-        if not n_pre:
-            suffix_len = true_len
-        region = _region_for(suffix_len)
-        total_need = max(n_pre + region,
-                         -(-(true_len + req.max_new_tokens) // bs_))
+        # prompt chunks land token-exact, so a hit of any width fits the
+        # page-table row and the reservation is the request's true footprint
+        total_need = -(-(true_len + req.max_new_tokens) // bs_)
         # hold the shared pages BEFORE the availability check: shared pages
         # sitting in _evictable would otherwise be double-counted as
         # allocatable, letting _alloc_pages run dry
@@ -2173,316 +1832,32 @@ class ContinuousBatchingEngine:
         req.slot = slot
         req.t_admit = time.monotonic()
         self._admits.append((req.rid, req.t_enqueue, req.t_admit, true_len))
+        req.tokens = list(prompt)  # the first token is appended at readback
+        if n_pre:
+            self.stats["prefix_hit_pages"] += n_pre
+            _M_PREFIX_HIT.inc(n_pre)
         sampling = req.sampling
-        if self._ragged:
-            # ---- ragged admission (ISSUE 20): reserve pages and install
-            # the page-table row NOW; the prompt streams into the pool via
-            # step()'s MIXED ragged dispatches (prefill chunks co-scheduled
-            # with everyone's decode rows in one program) — admission does
-            # no device work at all, for any prompt length, adapter, or
-            # kv dtype. Prefix-cache hits seed `consumed` past the shared
-            # pages, exactly like the legacy chunk ladder's filled_pages.
-            req.tokens = list(prompt)  # tok0 appended at graduation
-            if n_pre:
-                self.stats["prefix_hit_pages"] += n_pre
-                _M_PREFIX_HIT.inc(n_pre)
-            if sampling[0] and req.key_base is None:
-                req.key_base = np.asarray(
-                    jax.random.fold_in(jax.random.PRNGKey(req.seed),
-                                       req.rid))
-            row = np.zeros(self.pages_per_seq, np.int32)
-            row[:len(pages)] = pages
-            self.page_table[slot] = row
-            self.lengths[slot] = n_pre * bs_
-            st = _PrefillState(req, pages, n_pre, digests)
-            st.consumed = n_pre * bs_
-            self._prefilling[slot] = st
-            self._active_sampling = sampling
-            if ad is not None:
-                self._slot_adapter[slot] = ad
-                self._active_lora_rank = ad.rank
-            if adm is not None:
-                adm.end("ok", slot=slot, pages=len(pages),
-                        prefix_hit_pages=n_pre, ragged=True)
-            return "admitted"
-        if self.prefill_chunk and suffix_len > self.prefill_chunk \
-                and ad is None:
-            # reserve-then-stream admission: the prompt lands chunk by
-            # chunk in step(), interleaved with everyone else's decode
-            # blocks, instead of one monolithic bucketed dispatch.
-            # Adapter requests take the monolithic path below instead —
-            # a scoped degradation (one big dispatch, never wrong tokens)
-            # that keeps the chunk ladder free of lora program variants
-            req.tokens = list(prompt)  # tok0 appended at graduation
-            if n_pre:
-                self.stats["prefix_hit_pages"] += n_pre
-                _M_PREFIX_HIT.inc(n_pre)
-            self._prefilling[slot] = _PrefillState(req, pages, n_pre,
-                                                  digests)
-            self._active_sampling = sampling
-            if adm is not None:
-                adm.end("ok", slot=slot, pages=len(pages),
-                        prefix_hit_pages=n_pre, chunked=True)
-            # the FIRST chunk dispatches here — admission stays one
-            # bounded unit of device work, like a short prompt's prefill
-            return self._prefill_chunk_step(slot)
-        sbucket = prompt_bucket(suffix_len)
-        ids_p = np.zeros((1, sbucket), np.int32)
-        ids_p[0, :suffix_len] = prompt[n_pre * bs_:]
-        if ad is None:
-            progs = ([("gather", n_pre),
-                      ("suffix", n_pre, sbucket, sampling)]
-                     if n_pre else [("prefill", sbucket, sampling)])
-        else:
-            progs = ([("gather", n_pre),
-                      ("lora_suffix", n_pre, sbucket, sampling, ad.rank)]
-                     if n_pre
-                     else [("lora_prefill", sbucket, sampling, ad.rank)])
-            # per-request adapter operands (digest-keyed device cache);
-            # eager transfers, hoisted outside the locked dispatch
-            a_dev, b_dev = self._lora_dev(ad)
-            scale_dev = jnp.float32(ad.scale)
         if sampling[0] and req.key_base is None:
             # key_base = fold_in(PRNGKey(seed), rid): the request's own
             # stream root, so its sampled tokens are independent of which
-            # co-tenants (or which replica) it landed with. Materialized
-            # BEFORE the locked dispatch (blocking-under-lock): it depends
-            # only on (seed, rid) — pure jax, no framework Tensor state —
-            # and its 8-byte device->host pull must not extend the hold
-            # every sibling dispatcher queues behind
+            # co-tenants (or which replica) it landed with
             req.key_base = np.asarray(
                 jax.random.fold_in(jax.random.PRNGKey(req.seed), req.rid))
-        t_p0 = time.monotonic()
-        try:
-            with self._locked_dispatch(*progs, ("insert", sbucket)), \
-                    _trace.span("serve.prefill"), self._xprof_annotation(req):
-                k0 = (jax.random.fold_in(jnp.asarray(req.key_base), 0)
-                      if sampling[0]
-                      else jnp.zeros((2,), jnp.uint32))  # greedy ignores it
-                chaos.site("serve.prefill")
-                if n_pre:
-                    self.stats["prefix_hit_pages"] += n_pre
-                    _M_PREFIX_HIT.inc(n_pre)
-                    ks_pre, vs_pre = self._gather_prefix(n_pre)(
-                        tuple(self.pools), jnp.asarray(shared, jnp.int32))
-                    if ad is None:
-                        tok0, ks, vs = self._prefill_suffix(
-                            n_pre, sbucket, sampling)(
-                            state, ks_pre, vs_pre, jnp.asarray(ids_p),
-                            jnp.int32(suffix_len), k0)
-                    else:
-                        tok0, ks, vs = self._lora_prefill_suffix(
-                            n_pre, sbucket, sampling, ad.rank)(
-                            state, ks_pre, vs_pre, jnp.asarray(ids_p),
-                            jnp.int32(suffix_len), k0, a_dev, b_dev,
-                            scale_dev)
-                elif ad is None:
-                    tok0, ks, vs = self._prefill(sbucket, sampling)(
-                        state, jnp.asarray(ids_p), jnp.int32(suffix_len), k0)
-                else:
-                    tok0, ks, vs = self._lora_prefill(
-                        sbucket, sampling, ad.rank)(
-                        state, jnp.asarray(ids_p), jnp.int32(suffix_len),
-                        k0, a_dev, b_dev, scale_dev)
-                page_ids = jnp.asarray(new_pages[:region], jnp.int32)
-                self.pools = list(self._insert(sbucket)(
-                    tuple(self.pools), ks, vs, page_ids))
-                # sync INSIDE the guard: device-side prefill errors surface
-                # at this host transfer, not at dispatch — outside the try
-                # they would leak the popped slot + reffed pages and
-                # (online) kill the whole replica instead of failing this
-                # request alone. This is the prefill's designated readback
-                # (the first token gates admission bookkeeping).
-                tok0 = int(tok0)
-        except Exception as e:  # error isolation: fail THIS request alone
-            self._unref_pages(pages)
-            self.free_slots.append(slot)
-            self._fail_request(req, e)
-            if adm is not None:
-                adm.end("error", error=req.error_message)
-            return "failed"
-        dt = time.monotonic() - t_p0
-        if _trace.enabled():
-            # serving goodput split: a cold section is compile stall, not
-            # prefill throughput (ISSUE 7 satellite)
-            _goodput.serving_note(
-                "compile" if self._last_dispatch_cold else "prefill", dt)
-        if adm is not None:
-            adm.span_at("prefill", dt, dt, bucket=sbucket,
-                        prefix_hit_pages=n_pre,
-                        cold=self._last_dispatch_cold)
-        if self.enable_prefix_cache:
-            self._index_prompt_pages(true_len, pages, n_pre, digests)
-        req.tokens = list(prompt)
-        status = self._activate(slot, req, tok0)
+        row = np.zeros(self.pages_per_seq, np.int32)
+        row[:len(pages)] = pages
+        self.page_table[slot] = row
+        self.lengths[slot] = n_pre * bs_
+        # a prefix-cache hit starts the stream past the shared pages
+        self._prefilling[slot] = _PrefillState(req, pages, n_pre, digests,
+                                               consumed=n_pre * bs_)
+        self._active_sampling = sampling
+        if ad is not None:
+            self._slot_adapter[slot] = ad
+            self._active_lora_rank = ad.rank
         if adm is not None:
             adm.end("ok", slot=slot, pages=len(pages),
                     prefix_hit_pages=n_pre)
-        return status
-
-    def _activate(self, slot, req, tok0):
-        """Shared admission epilogue (monolithic prefill AND chunked
-        graduation — one copy, so the activation protocol cannot drift
-        between the two paths): install the page-table row, stamp the
-        first token, register the request in the decode group, fire the
-        callback, and retire immediately on a first-token eos / exhausted
-        budget. Returns "done" or "admitted"."""
-        row = np.zeros(self.pages_per_seq, np.int32)
-        row[:len(req.pages)] = req.pages
-        self.page_table[slot] = row
-        self.lengths[slot] = len(req.prompt)
-        now = time.monotonic()
-        req.t_first_token = now
-        _M_TTFT.observe(now - req.t_enqueue)
-        if req.trace is not None:
-            req.trace.event("first_token",
-                            ttft_s=round(now - req.t_enqueue, 6))
-        _M_TOKENS.inc()
-        req.tokens.append(tok0)
-        req.n_generated = 1
-        req.n_dispatched = 1
-        req.last_token = tok0
-        # register BEFORE the user callback: if it raises, the cleanup path
-        # must see this slot to free its pages
-        self._active[slot] = req
-        self._active_sampling = req.sampling
-        if req.adapter is not None:
-            # the group becomes (or stays) a lora group of this rank:
-            # decode dispatches switch to the lora programs, plain
-            # co-tenants ride the zero slot bit-identically
-            self._slot_adapter[slot] = req.adapter
-            self._active_lora_rank = req.adapter.rank
-        if req.on_token is not None:
-            req.on_token(req.rid, tok0)
-        if (req.eos_token_id is not None and tok0 == req.eos_token_id) \
-                or req.n_generated >= req.max_new_tokens:
-            self._retire(slot)
-            return "done"
         return "admitted"
-
-    def _chunk_plan(self, suffix_len):
-        """(full_chunks, final_len, region_pages) for a chunked suffix.
-        Non-final chunks are exactly ``prefill_chunk`` tokens (a whole
-        number of pages, so the next chunk's prefix gather reads no pad);
-        the final chunk keeps >=1 token so its logits produce the first
-        sampled token, and pads to its own prompt bucket like the
-        monolithic path."""
-        c = self.prefill_chunk
-        n_full = (suffix_len - 1) // c
-        final_len = suffix_len - n_full * c
-        region = (n_full * (c // self.page_size)
-                  + self._pages_for_bucket(prompt_bucket(final_len),
-                                           self.page_size))
-        return n_full, final_len, region
-
-    def _prefill_chunk_step(self, slot):
-        """Dispatch ONE prefill chunk for ``slot``. Chunk j is the prefix-
-        cache machinery applied to the engine's own partial work: gather
-        the pages already inserted, prefill the next chunk against them,
-        scatter its KV into the next pages. On the final chunk the request
-        graduates — samples tok0 with the same per-request key the
-        monolithic path uses (bit-identical first token), installs its
-        page-table row, and joins the decode group. Returns "admitted"
-        (still prefilling, or now decoding), "done" (graduated AND retired
-        on its first token), or "failed" (isolated failure; resources
-        freed, co-tenants unaffected)."""
-        st = self._prefilling[slot]
-        req = st.req
-        bs = self.page_size
-        prompt = req.prompt
-        true_len = len(prompt)
-        filled = st.filled_pages
-        done_tokens = filled * bs
-        rest = true_len - done_tokens
-        final = rest <= self.prefill_chunk
-        clen = rest if final else self.prefill_chunk
-        cbucket = prompt_bucket(clen) if final else clen
-        npg = self._pages_for_bucket(cbucket, bs)
-        sampling = req.sampling
-        state = self._captured_state()
-        ids = np.zeros((1, cbucket), np.int32)
-        ids[0, :clen] = prompt[done_tokens:done_tokens + clen]
-        progs = ([("gather", filled), ("suffix", filled, cbucket, sampling)]
-                 if filled else [("prefill", cbucket, sampling)])
-        if final and sampling[0] and req.key_base is None:
-            # same hoist as the unchunked admission path: (seed, rid)-only
-            # work plus an 8-byte pull stays outside the locked dispatch
-            req.key_base = np.asarray(
-                jax.random.fold_in(jax.random.PRNGKey(req.seed), req.rid))
-        t_p0 = time.monotonic()
-        try:
-            with self._locked_dispatch(*progs, ("insert", cbucket)), \
-                    _trace.span("serve.prefill"), self._xprof_annotation(req):
-                k0 = (jax.random.fold_in(jnp.asarray(req.key_base), 0)
-                      if final and sampling[0]
-                      else jnp.zeros((2,), jnp.uint32))
-                chaos.site("serve.prefill")
-                if filled:
-                    ks_pre, vs_pre = self._gather_prefix(filled)(
-                        tuple(self.pools),
-                        jnp.asarray(st.pages[:filled], jnp.int32))
-                    tok0, ks, vs = self._prefill_suffix(
-                        filled, cbucket, sampling)(
-                        state, ks_pre, vs_pre, jnp.asarray(ids),
-                        jnp.int32(clen), k0)
-                else:
-                    tok0, ks, vs = self._prefill(cbucket, sampling)(
-                        state, jnp.asarray(ids), jnp.int32(clen), k0)
-                page_ids = jnp.asarray(st.pages[filled:filled + npg],
-                                       jnp.int32)
-                self.pools = list(self._insert(cbucket)(
-                    tuple(self.pools), ks, vs, page_ids))
-                # readback INSIDE the try for EVERY chunk (the monolithic
-                # path's designated sync point, same rationale): a device-
-                # side chunk failure must surface here, where this
-                # request's resources free and it fails ALONE — deferred,
-                # it would materialize at a later unrelated decode
-                # readback, outside any per-request guard, and take the
-                # whole replica down. The wait itself costs little: this
-                # chunk chains behind the in-flight decode block whose
-                # readback happens later in the same step() anyway.
-                tok0 = int(tok0)
-        except Exception as e:
-            self._fail_prefill(slot, e)
-            return "failed"
-        _M_CHUNKS.inc()
-        dt = time.monotonic() - t_p0
-        if _trace.enabled():
-            _goodput.serving_note(
-                "compile" if self._last_dispatch_cold else "prefill", dt)
-        if req.trace is not None:
-            req.trace.span_at("prefill_chunk", dt, dt,
-                              filled_pages=filled, tokens=clen, final=final,
-                              cold=self._last_dispatch_cold)
-        if not final:
-            st.filled_pages = filled + npg
-            return "admitted"
-        # ---- graduation: join the decode group -----------------------------
-        del self._prefilling[slot]
-        if self.enable_prefix_cache:
-            self._index_prompt_pages(true_len, st.pages, st.n_pre0,
-                                     st.digests)
-        return self._activate(slot, req, tok0)
-
-    def _advance_prefill(self):
-        """Land ONE pending prefill chunk per mid-prefill slot (called
-        between decode blocks, so a long prompt pays out its prefill
-        without ever monopolizing the device — each slot advances one
-        small chunk per decode block). Advancing every slot instead of
-        round-robining ONE keeps co-admitted long prompts graduating
-        nearly together: a decode block costs the same at any occupancy,
-        so staggered graduations that decode 1-2 rows at a time nearly
-        double the block count for the same tokens (measured 133 vs 76
-        steps on a 4-long + 12-short workload). Returns the requests that
-        reached a terminal state (graduated straight to done, or failed
-        in isolation)."""
-        out = []
-        for slot in list(self._prefilling):
-            req = self._prefilling[slot].req
-            status = self._prefill_chunk_step(slot)
-            if status in ("done", "failed"):
-                out.append(req)
-        return out
 
     def _admit_from(self, queue):
         """Admit from the head of ``queue`` (a deque of EngineRequests)
@@ -2500,8 +1875,8 @@ class ContinuousBatchingEngine:
         return admitted
 
     def step(self):
-        """One scheduling round: sweep cancellations, land at most one
-        prefill chunk, advance the decode pipeline, sweep timeouts.
+        """One scheduling round: sweep cancellations, advance the decode
+        pipeline (pending prompt chunks ride in its dispatch), sweep timeouts.
         Returns the EngineRequests that reached a terminal state during
         this step; ``[]`` when idle.
 
@@ -2516,12 +1891,11 @@ class ContinuousBatchingEngine:
         reservation, and any page later reallocated is fully rewritten by
         the new tenant's prefill/decode before it is ever read). The sync
         path (``async_decode=False``) dispatches and reads back in one
-        call — the pre-pipeline behavior, kept as the bench baseline."""
+        call — the pre-pipeline behavior, which tests alone set."""
         self._t_step0 = time.monotonic_ns()
         with _trace.annotation("serve.step"):
             try:
-                return (self._step_ragged() if self._ragged
-                        else self._step_ladder())
+                return self._step_ragged()
             finally:
                 self._phase(None)  # a raise left a phase open
 
@@ -2548,61 +1922,14 @@ class ContinuousBatchingEngine:
         self._phase("serve.pack", step, "t_pack0")
         return step
 
-    def _step_ladder(self):
-        """step() for the bucket-ladder plane (``ragged=False``)."""
+    def _step_ragged(self):
+        """step()'s body (ISSUE 20): pending prompt chunks ride inside the
+        decode dispatch itself (_dispatch_ragged), so a step is one
+        dispatch + one readback whatever the admission mix, between a
+        cancellation sweep and a timeout sweep."""
         # requests that retired under an out-of-band _settle_inflight
         # readback surface here, so the frontend's step-driven finish path
         # sees every terminal request exactly once
-        retired = self._pending_retired
-        self._pending_retired = []
-        # cancellation sweep first: no decode/prefill compute for a dead
-        # request
-        for slot in list(self._active):
-            if self._active[slot].cancelled:
-                retired.append(self._retire(slot))
-        for slot in list(self._prefilling):
-            if self._prefilling[slot].req.cancelled:
-                retired.append(self._abort_prefill(slot))
-        # one prefill chunk between decode blocks: long prompts pay out
-        # without stalling in-flight requests' TPOT
-        retired.extend(self._advance_prefill())
-        if self.async_decode:
-            prev = self._inflight
-            if prev is not None:
-                # overlap: enqueue block k+1 BEFORE block k's readback —
-                # the emit/retire work below runs under its execution
-                self._inflight = self._dispatch_decode(chain=prev)
-                retired.extend(self._process_block(prev))
-            if self._inflight is None and self._active:
-                self._inflight = self._dispatch_decode()
-        elif self._active:
-            rec = self._dispatch_decode()
-            if rec is not None:
-                retired.extend(self._process_block(rec))
-        now = time.monotonic()
-        for slot in list(self._active):
-            r = self._active[slot]
-            if r.timeout_s is not None and now - r.t_admit > r.timeout_s:
-                # deadline hit: return what it got, free the slot
-                self.stats["timed_out_requests"] += 1
-                counters.bump("fault.serve.request_timeout")
-                r.timed_out = True
-                retired.append(self._retire(slot))
-        for slot in list(self._prefilling):
-            r = self._prefilling[slot].req
-            if r.timeout_s is not None and now - r.t_admit > r.timeout_s:
-                self.stats["timed_out_requests"] += 1
-                counters.bump("fault.serve.request_timeout")
-                retired.append(self._abort_prefill(slot, timed_out=True))
-        self._update_gauges()
-        return retired
-
-    def _step_ragged(self):
-        """step() twin for ragged mode (ISSUE 20): NO separate prefill
-        advancement — pending prompt chunks ride inside the decode
-        dispatch itself (_dispatch_ragged), so a step is one mixed
-        dispatch + one readback whatever the admission mix. Cancellation
-        and timeout sweeps are the legacy step()'s, verbatim."""
         retired = self._pending_retired
         self._pending_retired = []
         for slot in list(self._active):
@@ -2645,8 +1972,8 @@ class ContinuousBatchingEngine:
     def _dispatch_ragged(self, chain=None):
         """Dispatch one ragged step: when prompt chunks are pending, the
         MIXED program carries them alongside every decode row; with no
-        prefill in flight the fixed-k decode block (via _dispatch_decode,
-        which pins k = decode_block in ragged mode) runs alone."""
+        prefill in flight the fixed-k decode block (_dispatch_decode)
+        runs alone."""
         if self._prefilling:
             return self._dispatch_ragged_mixed(chain)
         return self._dispatch_decode(chain=chain)
@@ -2796,10 +2123,9 @@ class ContinuousBatchingEngine:
             if not _compilemem.is_oom(e):
                 raise  # the decode seam's contract: an outage ends serve()
             # an OOM is the prompts' — the chunk tokens are what size this
-            # dispatch, a decode row adds one token — so, as on the legacy
-            # chunk seam (_prefill_chunk_step), they fail ALONE, after
-            # _locked_dispatch committed the forensics, and free all they
-            # held. Decode rows that rode along are untouched (their
+            # dispatch, a decode row adds one token — so they fail ALONE,
+            # after _locked_dispatch committed the forensics, and free all
+            # they held. Decode rows that rode along are untouched (their
             # bookkeeping happens after a dispatch succeeds) and go out
             # again with the next step.
             for slot, _, _, _ in sched:
@@ -2868,29 +2194,20 @@ class ContinuousBatchingEngine:
             return None
         budgets = [r.max_new_tokens - r.n_dispatched
                    for r in self._active.values()]
-        # Async pipeline: block size from the LARGEST remaining budget
-        # (power of two so the compile cache stays at log2(decode_block)
-        # programs) — short-budget rows ride along under their in-program
-        # length caps instead of dragging k down to the batch minimum,
-        # which under staggered admissions fragments every block to k=1-2
-        # and doubles dispatches. Sync mode keeps the pre-pipeline
-        # min-remaining policy verbatim (it IS the pre-PR engine — the
-        # bench baseline; the caps are the identity there since k never
-        # exceeds any row's budget).
+        # Async pipeline: a block goes out while the LARGEST remaining
+        # budget is positive — short-budget rows ride along under their
+        # in-program length caps (their overshoot is discarded at emit).
+        # Sync mode asks it of the smallest, as the pre-pipeline engine
+        # did: there a spent row retired at the readback before.
         remaining = max(budgets) if self.async_decode else min(budgets)
         if remaining <= 0:
             return None  # every row fully dispatched: read back, retire
         sampling = self._active_sampling
         lora_rank = self._active_lora_rank
         state = self._captured_state()
-        if self._ragged:
-            # ragged mode (ISSUE 20): ONE fixed block size — the
-            # power-of-two k ladder is gone; short-budget rows ride under
-            # their in-program caps and overshoot is discarded at emit
-            k = self.decode_block
-        else:
-            k = min(self.decode_block, remaining)
-            k = 1 << (k.bit_length() - 1)
+        # ONE fixed block size (ISSUE 20): short-budget rows ride under
+        # their in-program caps and overshoot is discarded at emit
+        k = self.decode_block
         step = self._begin_step("decode", k, chain)
         rows = list(self._active.items())
         # a chained slot must still belong to the SAME request — a slot
@@ -3106,9 +2423,8 @@ class ContinuousBatchingEngine:
                     emits.append((r.rid, 0))
                     continue
                 if r.t_first_token is None:
-                    # ragged graduation: the first token materializes at
-                    # THIS readback (legacy paths stamp in _activate, where
-                    # the prefill dispatch synced — never reached here)
+                    # graduation: the first token materializes at THIS
+                    # readback (an adopted request arrives with its stamp)
                     now_ft = time.monotonic()
                     r.t_first_token = now_ft
                     _M_TTFT.observe(now_ft - r.t_enqueue)

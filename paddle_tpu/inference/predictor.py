@@ -125,18 +125,15 @@ class Predictor:
         (inference.continuous.ContinuousBatchingEngine): variable-length
         prompts queue, join mid-flight as slots/pages free, and each result
         equals that prompt's dense generate(). Pass `engine` to reuse a warm
-        engine (compiled prefill/decode programs + pool) across calls."""
+        engine (compiled step programs + pool) across calls."""
         from .continuous import ContinuousBatchingEngine
 
         if engine is None:
             if max_len is None:
-                from ..generation import prompt_bucket
-
                 longest = max(len(np.asarray(p).reshape(-1)) for p in prompts)
-                # must cover BOTH the prefill bucket of the longest prompt
-                # and its full decode extent, rounded to whole pages
-                max_len = max(prompt_bucket(longest), longest + max_new_tokens)
-                max_len = -(-max_len // page_size) * page_size
+                # the longest prompt's full decode extent, in whole pages
+                max_len = -(-(longest + max_new_tokens) // page_size) \
+                    * page_size
             engine = ContinuousBatchingEngine(
                 self._layer, max_seqs=max_seqs, page_size=page_size,
                 num_pages=num_pages, max_len=max_len)

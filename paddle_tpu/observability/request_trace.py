@@ -19,7 +19,7 @@ the same in-memory ring the hang watchdog dumps, so
 request timeline.  Each record carries::
 
     {"trace": "<trace_id>", "span": "<trace_id>/3", "parent": "<id>|null",
-     "name": "prefill_chunk", "rid": 7, "t0": <wall start>, "dur_s": 0.012,
+     "name": "decode_block", "rid": 7, "t0": <wall start>, "dur_s": 0.012,
      "time": <wall end>, "pid": ..., "status": "ok", "attrs": {...}}
 
 Wall-clock stamps (``time.time()``) are the cross-process alignment, same

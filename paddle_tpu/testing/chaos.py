@@ -48,7 +48,7 @@ elastic.regrow            controller watch loop capacity-return probe:
                           hardware churn (production signal: touch the
                           PADDLE_ELASTIC_REGROW_PATH file)
 dataloader.worker         io/dataloader.py forked worker, per batch
-serve.prefill             inference/continuous.py per-request prefill
+serve.prefill             inference/continuous.py adopt_request's page insert
 serve.decode              inference/continuous.py per decode dispatch
 serving.handoff.send      serving/handoff.py per publish attempt — a fault
                           here exercises the bounded-backoff retry and the

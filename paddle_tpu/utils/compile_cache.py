@@ -4,9 +4,8 @@ The cache directory is part of the cache key, so it must never move between
 runs: when the environment names one (``JAX_COMPILATION_CACHE_DIR``, which
 JAX reads by itself) nothing is set in code; otherwise it is the fixed
 ``<checkout>/.jax_cache`` (listed in .gitignore) — never a tempdir, a pid
-or a timestamp. Entry points call this (chip_smoke.py, bench.py,
-bench_serving.py, the launcher's worker bootstrap); ``import paddle_tpu``
-does not.
+or a timestamp. Entry points call this (chip_smoke.py, bench.py, the
+launcher's worker bootstrap); ``import paddle_tpu`` does not.
 """
 import os
 

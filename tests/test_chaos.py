@@ -488,26 +488,38 @@ def _engine(model, **kw):
 
 class TestServingFaults:
     def test_failed_prefill_retires_slot_not_batch(self, tiny_engine_setup):
+        """A prompt's chunk rides a mixed dispatch beside its co-tenants'
+        decode rows; when that dispatch runs out of memory (the chunk is
+        what sizes it) the PROMPT fails alone and the decode row goes out
+        again with the next step."""
         model, prompts = tiny_engine_setup
-        # ragged=False: the per-request prefill dispatch under fault
-        # injection is the LEGACY admission path — ragged admission does
-        # no device work (prompts stream inside shared mixed dispatches,
-        # where a failure is not attributable to one request)
-        eng = _engine(model, ragged=False)
-        ref = eng.serve(prompts, max_new_tokens=4)
+        eng = _engine(model, decode_block=4)
+        new = [4, 12, 4]  # rid 1 still decodes when rid 2 is admitted
+        ref = eng.serve(prompts, max_new_tokens=new)
         counters.reset("fault.")
-        with chaos.FaultPlan().fail("serve.prefill", times=1):
-            outs = eng.serve(prompts, max_new_tokens=4)
-        assert outs[0] is None
-        assert isinstance(eng.request_errors[0], chaos.FaultInjected)
+        mixed, fired = eng._dispatch_ragged_mixed, []
+
+        def oom_beside_a_decode_row(chain):
+            if eng._active and not fired:
+                fired.append(sorted(eng._prefilling))
+                with chaos.FaultPlan().fail("obs.oom", times=1):
+                    return mixed(chain)
+            return mixed(chain)
+
+        eng._dispatch_ragged_mixed = oom_beside_a_decode_row
+        outs = eng.serve(prompts, max_new_tokens=new)
+        del eng._dispatch_ragged_mixed
+        assert len(fired[0]) == 1  # one prompt's chunk was in that dispatch
+        assert outs[2] is None
+        assert isinstance(eng.request_errors[2], chaos.FaultInjected)
         assert eng.stats["failed_requests"] == 1
         # co-tenants unaffected AND semantics preserved exactly
+        np.testing.assert_array_equal(outs[0], ref[0])
         np.testing.assert_array_equal(outs[1], ref[1])
-        np.testing.assert_array_equal(outs[2], ref[2])
         # no leaked pages/slots: the warm engine serves the full set again
         assert len(eng.free_pages) == eng.num_pages - 1
         assert sorted(eng.free_slots) == [0, 1]
-        outs2 = eng.serve(prompts, max_new_tokens=4)
+        outs2 = eng.serve(prompts, max_new_tokens=new)
         for o, r in zip(outs2, ref):
             np.testing.assert_array_equal(o, r)
 
@@ -553,14 +565,14 @@ class TestServingFaults:
 
     def test_request_deadline_returns_partial(self, tiny_engine_setup):
         model, prompts = tiny_engine_setup
-        # ragged=False: the "partial includes the first token" guarantee
-        # is the legacy admission's (tok0 sampled synchronously at admit);
-        # ragged first tokens arrive at the first block readback, so an
-        # instant deadline can return a prompt-only partial
-        eng = _engine(model, max_seqs=1, decode_block=1, ragged=False)
+        # async_decode=False: the deadline sweep runs after the step's
+        # readback, so the partial holds the first token; under the async
+        # pipeline the block is still in flight at the sweep and an instant
+        # deadline returns the prompt alone (tests/test_ragged_attention.py)
+        eng = _engine(model, max_seqs=1, decode_block=1, async_decode=False)
         outs = eng.serve([prompts[0]], max_new_tokens=30, request_timeout_s=0.0)
         assert eng.stats["timed_out_requests"] == 1
-        # partial result: the prompt plus at least the prefill token
+        # partial result: the prompt plus at least the first token
         assert outs[0] is not None
         assert len(prompts[0]) < len(outs[0]) < len(prompts[0]) + 30
 

@@ -271,10 +271,10 @@ class TestEngineLedger:
         keys = set(rep["by_key"])
         # ledger completeness: every compiled program the engine holds has
         # a ledger entry with the matching key family
-        assert len([k for k in keys if k.startswith("serve.prefill[")]) \
-            == len(eng._prefill_fns)
+        assert len([k for k in keys if k.startswith("serve.ragged[")]) \
+            == len(eng._ragged_fns) > 0
         assert len([k for k in keys if k.startswith("serve.insert[")]) \
-            == len(eng._insert_fns)
+            == len(eng._insert_fns) == 0
         n_dec = (len([k for k in keys if k.startswith("serve.decode[")])
                  + len([k for k in keys
                         if k.startswith("serve.decode_block[")]))

@@ -332,7 +332,7 @@ def test_llama_serves_through_the_same_protocol():
     assert k.shape == v.shape == (4, 3, 16, 16)
     eng = ContinuousBatchingEngine(model, max_seqs=2, page_size=16,
                                    max_len=64, prefill_chunk=16)
-    assert eng._ragged and not eng._latent
+    assert not eng._latent
     ids = np.arange(1, 20, dtype=np.int32)
     out = eng.serve([ids], max_new_tokens=4)[0]
     logits = np.asarray(model(Tensor(jnp.asarray(out[None])))._data[0])
@@ -344,9 +344,48 @@ def test_llama_serves_through_the_same_protocol():
         np.asarray(model.lm_head(Tensor(h))._data))
 
 
+def test_a_model_without_the_serving_protocol_is_refused_by_name():
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+
+    model = GPTForCausalLM(gpt_tiny())  # a decoder with generate(), no trunk
+    with pytest.raises(TypeError, match="GPTForCausalLM lacks serving_trunk "
+                                        "and serving_head"):
+        ContinuousBatchingEngine(model, max_seqs=2, page_size=16, max_len=64)
+
+
+@pytest.mark.parametrize("call, kwargs, error", [
+    ("__init__", dict(ragged=False), (ValueError, "bucket-ladder plane")),
+    ("warmup", dict(shared_prefix_lens=(16,)),
+     (TypeError, "unexpected keyword")),
+])
+def test_the_deleted_planes_options_select_nothing(call, kwargs, error):
+    """One dispatch plane, no option selects it (PR 31): the bucket ladder's
+    warm-up argument went with it, and its switch is refused by name.
+    `ragged=True` is still taken, and does nothing, because the benchmark's
+    workload files pass it (`benchmarks/workloads/*.json`)."""
+    with pytest.raises(error[0], match=error[1]):
+        getattr(ContinuousBatchingEngine, call)(None, None, **kwargs)
+
+
+def test_the_benchmarks_engine_knobs_are_taken():
+    """Every key of a serving workload file's `engine` block (what
+    `benchmarks/runners/serve_openloop.py` hands the constructor) is a
+    parameter of the engine."""
+    import glob
+    import inspect
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    taken = set(inspect.signature(ContinuousBatchingEngine).parameters)
+    cells = [json.load(open(p)) for p in sorted(glob.glob(
+        os.path.join(root, "benchmarks", "workloads", "*.json")))]
+    blocks = [c["engine"] for c in cells if "engine" in c]
+    assert blocks and all(set(b) <= taken for b in blocks)
+
+
 @pytest.mark.parametrize("plane, kwargs", [
     ("kv_cache_dtype", dict(kv_cache_dtype="int8")),
-    ("bucket-ladder plane", dict(ragged=False)),
     ("prefix cache", dict(enable_prefix_cache=True)),
 ])
 def test_a_plane_that_cannot_take_a_latent_pool_refuses(share_model, plane,

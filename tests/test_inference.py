@@ -161,7 +161,7 @@ class TestRaggedBatchGenerate:
     batch (internal left-alignment): each row's continuation must equal the
     single-row generate() of that prompt alone."""
 
-    def _ragged(self, m, V, l0, l1, new):
+    def _check_ragged_pair(self, m, V, l0, l1, new):
         rng = np.random.RandomState(7)
         r0 = rng.randint(0, V, (l0,)).astype(np.int32)
         r1 = rng.randint(0, V, (l1,)).astype(np.int32)
@@ -182,7 +182,7 @@ class TestRaggedBatchGenerate:
 
         m = LlamaForCausalLM(llama_tiny(num_hidden_layers=2))
         m.eval()
-        self._ragged(m, 128, 5, 9, 5)
+        self._check_ragged_pair(m, 128, 5, 9, 5)
 
     def test_left_padded_mask_matches_right_padded(self):
         """Callers pad on either side: the prompt must be gathered by the
@@ -214,7 +214,7 @@ class TestRaggedBatchGenerate:
         m = GPTForCausalLM(gpt_tiny(hidden_dropout_prob=0.0,
                                     attention_probs_dropout_prob=0.0))
         m.eval()
-        self._ragged(m, 128, 4, 7, 4)
+        self._check_ragged_pair(m, 128, 4, 7, 4)
 
     def test_ragged_with_repetition_penalty(self):
         """Penalty composes with the ragged path: per-row parity against

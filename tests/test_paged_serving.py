@@ -97,11 +97,11 @@ class TestContinuousBatching:
         # serial per-request decoding would need
         assert eng.stats["decode_steps"] < len(prompts) * (new - 1)
 
-    def test_warmup_compiles_ladder_and_preserves_streams(self):
-        """warmup() must compile the k=1 decode + every power-of-two block
-        program + each prompt bucket's prefill, and a post-warmup serve must
-        be token-identical to a fresh engine's (warmup mutates no state the
-        scheduler depends on)."""
+    def test_warmup_compiles_both_programs_and_preserves_streams(self):
+        """warmup() must compile exactly the mixed step and the decode
+        block, a post-warmup serve must add no program, and its streams
+        must be token-identical to a fresh engine's (warmup mutates no
+        state the scheduler depends on)."""
         m, cfg = self._model()
         rng = np.random.RandomState(9)
         lens = [5, 11, 37]
@@ -110,23 +110,28 @@ class TestContinuousBatching:
         new = 7
         mk = lambda: ContinuousBatchingEngine(  # noqa: E731
             m, max_seqs=2, page_size=16, num_pages=12, max_len=64,
-            decode_block=4, ragged=False)  # the LEGACY ladder under test
+            decode_block=4)
         warm, cold = mk(), mk()
         warm.warmup(lens)
         # every program the serve loop can hit is already compiled
-        from paddle_tpu.generation import prompt_bucket
-
         sampling = (False, 1.0, 0, 1.0)
-        assert {b for b, s in warm._prefill_fns} >= {prompt_bucket(l) for l in lens}
-        assert sampling in warm._decode_fns  # k=1 program
-        assert {k for s, k in warm._decode_block_fns} == {2, 4}
-        before = dict(warm._prefill_fns), dict(warm._decode_block_fns)
+
+        def programs():
+            return {name: set(getattr(warm, name)) for name in (
+                "_ragged_fns", "_decode_block_fns", "_decode_fns",
+                "_insert_fns", "_gather_fns")}
+
+        before = programs()
+        assert before == {"_ragged_fns": {sampling},
+                          "_decode_block_fns": {(sampling, 4)},
+                          "_decode_fns": set(), "_insert_fns": set(),
+                          "_gather_fns": set()}
         outs = warm.serve(prompts, max_new_tokens=new)
         refs = cold.serve(prompts, max_new_tokens=new)
         for o, r in zip(outs, refs):
             np.testing.assert_array_equal(o, r)
         # the timed serve added no new programs
-        assert (dict(warm._prefill_fns), dict(warm._decode_block_fns)) == before
+        assert programs() == before
 
     def test_pool_smaller_than_dense_and_admission_defers(self):
         """The memory contract: pool bytes < the dense fixed-shape caches the
@@ -137,8 +142,8 @@ class TestContinuousBatching:
         prompts = [rng.randint(1, cfg.vocab_size, (l,)).astype(np.int32)
                    for l in [5, 9, 6, 12, 4]]
         new = 4
-        # page_size=4: a 16-bucket prompt needs 4 pages; 6 usable pages can
-        # hold only ONE such request at a time -> the second must defer
+        # page_size=4: the 5-token prompt + 4 new needs 3 pages and the
+        # 9-token one 4; 6 usable pages cannot hold both -> the second defers
         eng = ContinuousBatchingEngine(m, max_seqs=2, page_size=4,
                                        num_pages=7, max_len=64)
         outs = eng.serve(prompts, max_new_tokens=new)
@@ -151,9 +156,9 @@ class TestContinuousBatching:
                        * cfg.head_dim * dtype_bytes * 2 * cfg.num_hidden_layers)
         assert eng.pool_bytes() < dense_bytes, (eng.pool_bytes(), dense_bytes)
 
-    def test_page_size_larger_than_prompt_bucket(self):
-        """A 16-bucket prompt under page_size=32 must still land its KV
-        (regression: npg floored to 0 and silently dropped the prompt)."""
+    def test_page_size_larger_than_prompt(self):
+        """A prompt shorter than a page (page_size=32) must still land its
+        KV (regression: npg floored to 0 and silently dropped the prompt)."""
         m, cfg = self._model()
         rng = np.random.RandomState(8)
         prompts = [rng.randint(1, cfg.vocab_size, (l,)).astype(np.int32)
@@ -165,14 +170,15 @@ class TestContinuousBatching:
             ref = m.generate(p[None], max_new_tokens=4).numpy()[0]
             np.testing.assert_array_equal(o, ref)
 
-    def test_predictor_serve_auto_max_len_covers_bucket(self):
-        """Predictor.serve must size max_len to the longest prompt's BUCKET,
-        not just len+new (regression: valid requests raised ValueError)."""
+    def test_predictor_serve_auto_max_len_admits_the_longest_prompt(self):
+        """Predictor.serve sizes max_len to the longest prompt plus its new
+        tokens, in whole pages, and the engine admits it (regression: the
+        bucket ladder asked for the prompt's BUCKET and raised)."""
         from paddle_tpu.inference import Predictor
 
         m, cfg = self._model()
         rng = np.random.RandomState(9)
-        # len 17 -> bucket 32 > 17 + 1 = 18: the old rounding raised
+        # len 17 + 1 = 18 tokens: two pages of 16
         prompts = [rng.randint(1, cfg.vocab_size, (17,)).astype(np.int32)]
         outs = Predictor(m).serve(prompts, max_new_tokens=1, page_size=16,
                                   max_seqs=1)
@@ -403,19 +409,17 @@ class TestPrefixCache:
         base = ContinuousBatchingEngine(m, max_seqs=2, page_size=8,
                                         num_pages=32, max_len=96)
         want = base.serve(prompts, max_new_tokens=new)
-        # ragged=False: hit-count timing under test is the MONOLITHIC
-        # path's (pages index at admission, so co-admitted requests hit
-        # each other); ragged indexes at graduation like the chunk ladder
         eng = ContinuousBatchingEngine(m, max_seqs=2, page_size=8,
                                        num_pages=32, max_len=96,
-                                       enable_prefix_cache=True,
-                                       ragged=False)
+                                       enable_prefix_cache=True)
         got = eng.serve(prompts, max_new_tokens=new)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
-        # 33-token shared prefix @ page 8 = 4 full shared pages; requests
-        # 2..4 should each have hit them
-        assert eng.stats["prefix_hit_pages"] >= 3 * 4, eng.stats
+        # 33-token shared prefix @ page 8 = 4 full shared pages. A prompt's
+        # pages are indexed when it graduates, so the two requests admitted
+        # together miss each other and requests 3 and 4, admitted after a
+        # graduation, each hit all four
+        assert eng.stats["prefix_hit_pages"] == 2 * 4, eng.stats
 
     def test_identical_prompts_second_serve_hits_cache(self):
         """Cache persists across serve() calls on a warm engine."""
@@ -511,19 +515,17 @@ class TestPrefixCache:
         np.testing.assert_array_equal(out, ref.serve([big], max_new_tokens=2)[0])
 
     def test_warmup_bypasses_prefix_cache(self):
-        """warmup() must compile the FULL-prefill programs (all-ones dummy
-        prompts would otherwise cross-hit the cache and compile suffix
-        programs instead) and must not leave junk pages indexed."""
+        """warmup() with the prefix cache on compiles the mixed step and
+        the decode block alone — the gather and insert programs are the KV
+        handoff plane's, compiled by a handoff — and its all-ones dummy
+        prompt must not leave junk pages indexed."""
         m, cfg = self._model()
         eng = ContinuousBatchingEngine(m, max_seqs=1, page_size=8,
                                        num_pages=40, max_len=256,
-                                       enable_prefix_cache=True,
-                                       ragged=False)  # legacy ladder programs
+                                       enable_prefix_cache=True)
         eng.warmup([20, 70])
-        from paddle_tpu.generation import prompt_bucket
-
-        assert prompt_bucket(20) in {k[0] for k in eng._prefill_fns}
-        assert prompt_bucket(70) in {k[0] for k in eng._prefill_fns}
+        assert len(eng._ragged_fns) == len(eng._decode_block_fns) == 1
+        assert not eng._insert_fns and not eng._gather_fns
         assert not eng._prefix_index and not eng._evictable
         assert eng.enable_prefix_cache  # restored
 

@@ -5,11 +5,13 @@ Oracle strategy, two levels:
   softmax over the page pool (mixed decode rows, mid-prompt chunks, fresh
   prefills, empty rows in ONE call), with the interpret-mode Pallas tier
   matching the math tier — CPU tier-1 exercises the real kernel body;
-- engine: a ragged-mode ContinuousBatchingEngine must emit bit-identical
-  tokens to the legacy bucket-ladder engine (the PR 6 oracle pattern) on
-  every path that composes — greedy/sampled, async/sync, EOS mid-block,
-  prefix cache, chunked long prompts, int8 pool, LoRA batches — while
-  compiling ONE mixed program per (sampling, rank) instead of the ladder.
+- engine: a ContinuousBatchingEngine must emit the tokens of the plain
+  reference (the model's no-cache forward, token by token:
+  tests/_serving_reference.py) on every path that composes —
+  greedy/sampled, async/sync, EOS mid-block, prefix cache, chunked long
+  prompts — and, where the reference cannot say (an int8 pool rounds, an
+  adapter changes the head), the tokens of the same request served alone;
+  it compiles ONE mixed program per (sampling, rank).
 """
 import numpy as np
 import pytest
@@ -19,6 +21,8 @@ from paddle_tpu.inference.continuous import ContinuousBatchingEngine
 from paddle_tpu.ops import ragged_paged_attention as rpa
 
 import jax.numpy as jnp
+
+from _serving_reference import reference_stream, reference_streams
 
 
 @pytest.fixture(scope="module")
@@ -194,16 +198,24 @@ def _prompts(rng, lens, vocab=100):
     return [rng.randint(1, vocab, size=n).astype(np.int32) for n in lens]
 
 
-def _serve_pair(model, prompts, ragged_kw=None, legacy_kw=None, **serve_kw):
-    """(legacy tokens, ragged tokens) for the same workload."""
-    base = dict(max_seqs=4, page_size=16, max_len=160)
-    legacy = ContinuousBatchingEngine(model, ragged=False,
-                                      **{**base, **(legacy_kw or {})})
-    ragged = ContinuousBatchingEngine(model, ragged=True,
-                                      **{**base, **(ragged_kw or {})})
-    assert ragged._ragged and not legacy._ragged
-    return (legacy.serve(prompts, **serve_kw),
-            ragged.serve(prompts, **serve_kw))
+def _engine(model, **kw):
+    return ContinuousBatchingEngine(
+        model, **{**dict(max_seqs=4, page_size=16, max_len=160), **kw})
+
+
+def _serve_pair(model, prompts, eng_kw=None, **serve_kw):
+    """(reference tokens, engine tokens) for the same workload."""
+    return (reference_streams(model, prompts, **serve_kw),
+            _engine(model, **(eng_kw or {})).serve(prompts, **serve_kw))
+
+
+def _served_alone(model, prompts, adapters=None, eng_kw=None, **serve_kw):
+    """Each request by itself on a one-slot engine whose chunk budget
+    holds it whole: no co-tenant, no second chunk, no prefix cache."""
+    eng = _engine(model, max_seqs=1, **(eng_kw or {}))
+    assert max(map(len, prompts)) <= eng._ragged_chunk
+    return [eng.serve([p], adapters=ad, **serve_kw)[0]
+            for p, ad in zip(prompts, adapters or [None] * len(prompts))]
 
 
 class TestRaggedEngine:
@@ -211,8 +223,8 @@ class TestRaggedEngine:
         rng = np.random.RandomState(7)
         prompts = _prompts(rng, (3, 17, 41, 9, 28))
         for mode in ({}, {"async_decode": False}):
-            want, got = _serve_pair(model, prompts, ragged_kw=mode,
-                                    legacy_kw=mode, max_new_tokens=12)
+            want, got = _serve_pair(model, prompts, eng_kw=mode,
+                                    max_new_tokens=12)
             for w, g in zip(want, got):
                 np.testing.assert_array_equal(w, g)
 
@@ -225,9 +237,28 @@ class TestRaggedEngine:
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
 
-    @pytest.mark.parametrize("ragged", [True, False])
+    @pytest.mark.parametrize("max_seqs, chunk", [(4, None), (1, 16)],
+                             ids=["co-tenants", "alone-chunked"])
+    def test_sampled_key_scheme_under_two_schedules(self, model, max_seqs,
+                                                    chunk):
+        """The reference draws token i of request rid under
+        fold_in(fold_in(PRNGKey(seed), rid), i) and knows no schedule: an
+        engine that serves the four requests side by side and one that
+        serves them one after another, 16 prompt tokens a dispatch, both
+        give its streams."""
+        rng = np.random.RandomState(41)
+        prompts = _prompts(rng, (5, 33, 12, 20))
+        kw = dict(max_new_tokens=[9, 4, 10, 6], do_sample=True,
+                  temperature=0.9, top_p=0.9, seed=5)
+        want, got = _serve_pair(
+            model, prompts, eng_kw=dict(max_seqs=max_seqs,
+                                        prefill_chunk=chunk), **kw)
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(w, g)
+
+    @pytest.mark.parametrize("decode_block", [8, 1])
     def test_dispatch_operands_do_not_alias_engine_arrays(self, model,
-                                                          ragged):
+                                                          decode_block):
         """The race behind the token mismatches that only some processes
         showed (alignment decides, load times it), without a clock: the
         engine mutates `lengths`/`page_table` in place right after an
@@ -235,8 +266,7 @@ class TestRaggedEngine:
         the CPU backend jnp.asarray of a 64-byte-aligned numpy buffer does
         — and, read back after all the bookkeeping, each must still hold
         what the host held at dispatch."""
-        eng = ContinuousBatchingEngine(model, max_seqs=4, page_size=16,
-                                       max_len=160, ragged=ragged)
+        eng = _engine(model, decode_block=decode_block)
         eng.lengths = _aligned_like(eng.lengths)
         eng.page_table = _aligned_like(eng.page_table)
         calls = []  # (operands that mirror engine arrays, host values then)
@@ -252,13 +282,16 @@ class TestRaggedEngine:
                 return call
             return build
 
-        # positional layouts of the two programs' operands
-        eng._decode_block_fn = spy(eng._decode_block_fn,
-                                   [(3, "page_table"), (4, "lengths")])
+        # positional layouts of the programs' operands (decode_block 1
+        # dispatches the k=1 twin of the decode block)
+        decode_layout = [(3, "page_table"), (4, "lengths")]
+        eng._decode_block_fn = spy(eng._decode_block_fn, decode_layout)
+        eng._decode = spy(eng._decode, decode_layout)
         eng._ragged_fn = spy(eng._ragged_fn, [(9, "page_table")])
         rng = np.random.RandomState(17)
-        eng.serve(_prompts(rng, (5, 21)), max_new_tokens=2 * eng.decode_block)
-        assert calls
+        eng.serve(_prompts(rng, (5, 21)),
+                  max_new_tokens=2 * eng.decode_block + 1)
+        assert {len(args) for _, args in calls} == {7, 14}  # both programs
         for mirrored, args in calls:
             for a in args:
                 if hasattr(a, "shape"):
@@ -271,7 +304,7 @@ class TestRaggedEngine:
     def test_eos_mid_block_truncates_identically(self, model):
         rng = np.random.RandomState(13)
         prompts = _prompts(rng, (6, 25, 14))
-        ref, _ = _serve_pair(model, prompts, max_new_tokens=16)
+        ref = reference_streams(model, prompts, max_new_tokens=16)
         # pick an eos that really fires mid-stream for some request
         eos = int(np.asarray(ref[0])[len(prompts[0]) + 3])
         want, got = _serve_pair(model, prompts, max_new_tokens=16,
@@ -286,35 +319,39 @@ class TestRaggedEngine:
         shared = rng.randint(1, 100, size=24).astype(np.int32)
         prompts = [np.concatenate([shared, p])
                    for p in _prompts(rng, (3, 17, 41, 9))]
-        kw = {"page_size": 8, "enable_prefix_cache": True}
-        legacy = ContinuousBatchingEngine(model, max_seqs=4, max_len=160,
-                                          ragged=False, **kw)
-        ragged = ContinuousBatchingEngine(model, max_seqs=4, max_len=160,
-                                          ragged=True, **kw)
-        for eng in (legacy, ragged):  # second serve hits the prefix cache
-            eng.r1 = eng.serve(prompts, max_new_tokens=6)
-            eng.r2 = eng.serve(prompts, max_new_tokens=6)
-        for w, g in zip(legacy.r1 + legacy.r2, ragged.r1 + ragged.r2):
-            np.testing.assert_array_equal(w, g)
-        assert ragged.stats["prefix_hit_pages"] > 0
-        # chunked long prompts against the legacy chunk ladder
+        want = reference_streams(model, prompts, max_new_tokens=6)
+        eng = _engine(model, page_size=8, enable_prefix_cache=True)
+        for _ in range(2):  # the second serve hits the prefix cache
+            for w, g in zip(want, eng.serve(prompts, max_new_tokens=6)):
+                np.testing.assert_array_equal(w, g)
+        assert eng.stats["prefix_hit_pages"] > 0
+        # long prompts under a chunk budget a quarter of the longest
         long_prompts = _prompts(rng, (90, 130, 5))
-        ck = {"prefill_chunk": 32, "max_len": 256}
-        want, got = _serve_pair(model, long_prompts, ragged_kw=ck,
-                                legacy_kw=ck, max_new_tokens=10)
+        want, got = _serve_pair(
+            model, long_prompts, eng_kw={"prefill_chunk": 32, "max_len": 256},
+            max_new_tokens=10)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
 
-    def test_int8_pool_matches_legacy(self, model):
+    def test_int8_pool_is_schedule_and_chunk_independent(self, model):
+        """An int8 pool rounds every K and V it stores, so the float
+        reference cannot say its tokens; every token attends through the
+        pool whatever chunk wrote it and whoever shares the dispatch, so
+        the same request served alone can, under the default chunk budget
+        and under one smaller than the prompts."""
         rng = np.random.RandomState(19)
         prompts = _prompts(rng, (3, 17, 41, 9, 28))
         kw = {"kv_cache_dtype": "int8"}
-        want, got = _serve_pair(model, prompts, ragged_kw=kw, legacy_kw=kw,
-                                max_new_tokens=8)
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(w, g)
+        want = _served_alone(model, prompts, eng_kw=kw, max_new_tokens=8)
+        for eng_kw in (kw, {**kw, "prefill_chunk": 16}):
+            got = _engine(model, **eng_kw).serve(prompts, max_new_tokens=8)
+            for w, g in zip(want, got):
+                np.testing.assert_array_equal(w, g)
 
-    def test_lora_batch_matches_legacy(self, model):
+    def test_lora_batch_rows(self, model):
+        """A mixed batch: the rows without an adapter and with a zero one
+        are the plain reference's; a row with an adapter (the reference
+        has no adapter head) is the same request's served alone."""
         from paddle_tpu.serving.adapters import LoRAAdapter
 
         rng = np.random.RandomState(23)
@@ -325,36 +362,29 @@ class TestRaggedEngine:
         zad = LoRAAdapter("z0", np.zeros((hidden, 4), np.float32),
                           np.zeros((4, vocab), np.float32))
         prompts = _prompts(rng, (3, 17, 41, 9))
-        want, got = _serve_pair(model, prompts, max_new_tokens=8,
-                                adapters=[ad, None, zad, ad])
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(w, g)
-
-    def test_kill_switch_env(self, model, monkeypatch):
-        monkeypatch.setenv("PADDLE_SERVING_RAGGED", "0")
-        eng = ContinuousBatchingEngine(model, max_seqs=2, page_size=16,
-                                       max_len=64)
-        assert not eng._ragged  # byte-for-byte the legacy engine paths
-        monkeypatch.setenv("PADDLE_SERVING_RAGGED", "1")
-        eng = ContinuousBatchingEngine(model, max_seqs=2, page_size=16,
-                                       max_len=64)
-        assert eng._ragged
+        adapters = [ad, None, zad, ad]
+        got = _engine(model).serve(prompts, max_new_tokens=8,
+                                   adapters=adapters)
+        alone = _served_alone(model, prompts, adapters, max_new_tokens=8)
+        for rid, (p, a) in enumerate(zip(prompts, adapters)):
+            want = (alone[rid] if a is ad
+                    else reference_stream(model, p, 8, rid=rid))
+            np.testing.assert_array_equal(want, got[rid])
 
     def test_warmup_covers_ragged_programs(self, model):
         """After warmup, a mixed serve (short + long prompts, two sampling
         configs) must add NO program keys and NO serve.* compile-ledger
-        events — the steady-state zero-recompile contract, now with a
-        warmup that is one dummy serve per config instead of a ladder."""
+        events — the steady-state zero-recompile contract, with a warmup
+        that is one dummy serve per config whatever the prompt lengths."""
         from paddle_tpu.observability import compilemem
 
-        eng = ContinuousBatchingEngine(model, max_seqs=4, page_size=16,
-                                       max_len=160, ragged=True)
+        eng = _engine(model)
         eng.warmup(prompt_lens=[3, 17, 41],
                    sampling=[(False, 1.0, 0, 1.0), (True, 0.8, 20, 1.0)])
         # collapsed program count: ONE mixed + one block program per
         # sampling config (plus k=1 decode only when decode_block == 1)
         assert len(eng._ragged_fns) == 2
-        assert not eng._prefill_fns and not eng._insert_fns
+        assert not eng._insert_fns and not eng._gather_fns
         warm_before = set(eng._warm)
 
         def _serve_counts():
@@ -381,9 +411,7 @@ class TestRaggedEngine:
         try:
             # small chunk budget -> several mixed dispatches per prompt, so
             # warm (post-compile) dispatches exist for the cadence to time
-            eng = ContinuousBatchingEngine(model, max_seqs=2, page_size=16,
-                                           max_len=160, prefill_chunk=16,
-                                           ragged=True)
+            eng = _engine(model, max_seqs=2, prefill_chunk=16)
             rng = np.random.RandomState(31)
             eng.serve(_prompts(rng, (40, 55)), max_new_tokens=6)
             table = devprof.plane()._table()
@@ -395,13 +423,11 @@ class TestRaggedEngine:
             devprof._reset()
 
     def test_deadline_returns_partial_without_first_token(self, model):
-        """Ragged twin of the legacy deadline test: admission produces no
-        token, so an instant deadline may return a prompt-only partial —
-        but the request must still retire cleanly with its slot freed."""
+        """Admission produces no token, so under the async pipeline an
+        instant deadline may return a prompt-only partial — but the
+        request must still retire cleanly with its slot freed."""
         rng = np.random.RandomState(37)
-        eng = ContinuousBatchingEngine(model, max_seqs=1, page_size=16,
-                                       max_len=64, decode_block=1,
-                                       ragged=True)
+        eng = _engine(model, max_seqs=1, max_len=64, decode_block=1)
         p = _prompts(rng, (5,))[0]
         outs = eng.serve([p], max_new_tokens=30, request_timeout_s=0.0)
         assert eng.stats["timed_out_requests"] == 1

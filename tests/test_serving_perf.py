@@ -1,18 +1,19 @@
 """Serving data-plane pipeline (ISSUE 6): bit-exactness of chunked prefill
-and double-buffered async decode against the synchronous monolithic path,
-the hashed prefix-page index vs a content-exact oracle, warmup AOT
-coverage, and the bench_serving.py smoke.
+and double-buffered async decode against the plain reference (the model's
+no-cache forward, token by token: tests/_serving_reference.py), the hashed
+prefix-page index vs a content-exact oracle, and warmup AOT coverage.
 
 The bit-exactness contract is the tentpole's hard constraint: every
 pipeline optimization (chunked prefill, dispatch-time length accounting,
-per-row caps, device-chained feeds) must produce token streams IDENTICAL
-to the legacy engine for the same seeds — on the batch serve() path, the
+per-row caps, device-chained feeds) must produce the token streams the
+reference produces for the same seeds — on the batch serve() path, the
 online frontend path, and across a mid-stream replica-kill reroute.
 
 Engines compile their jitted program sets per instance, so the module
-shares two warm fixtures (one legacy, one pipelined PAIR) across tests —
-serve() leaves an engine idle and reusable, and re-paying the compile per
-test was measured to push the tier-1 suite past its wall-clock budget.
+shares warm fixtures (a pipelined PAIR, one engine with the prefix cache)
+across tests — serve() leaves an engine idle and reusable, and re-paying
+the compile per test was measured to push the tier-1 suite past its
+wall-clock budget.
 The chaos replica-kill test runs LAST: it abandons a killed engine
 mid-flight, which is exactly the one state the fixtures can't share.
 """
@@ -24,8 +25,11 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+from paddle_tpu.observability import tracing
 from paddle_tpu.serving import DEAD, RequestFailed, ServingFrontend
 from paddle_tpu.testing import chaos
+
+from _serving_reference import reference_streams
 
 
 @pytest.fixture(scope="module")
@@ -50,17 +54,9 @@ def _mk(model, **kw):
     return ContinuousBatchingEngine(model, **kw)
 
 
-# LEGACY is the PR 6 monolithic contrast/reference engine — pinned off the
-# ragged plane (ISSUE 20) so it keeps the pre-ragged emission order these
-# tests encode; the PIPELINED engines ride the ragged default, so every
-# legacy-vs-pipelined comparison below doubles as a ragged bit-exactness check.
-LEGACY = dict(async_decode=False, prefill_chunk=None, ragged=False)
+# a chunk budget well under the long prompts below: they stream in over
+# several mixed dispatches, beside the decode rows of the short ones
 PIPELINED = dict(async_decode=True, prefill_chunk=24)
-
-
-@pytest.fixture(scope="module")
-def legacy_eng(model):
-    return _mk(model, **LEGACY)
 
 
 @pytest.fixture(scope="module")
@@ -69,32 +65,29 @@ def pipe_pair(model):
 
 
 @pytest.fixture(scope="module")
-def prefix_pair(model):
-    return (_mk(model, **LEGACY, enable_prefix_cache=True),
-            _mk(model, **PIPELINED, enable_prefix_cache=True))
+def prefix_eng(model):
+    return _mk(model, **PIPELINED, enable_prefix_cache=True)
 
 
 class TestBitExactness:
-    """Chunked prefill + async decode vs the synchronous monolithic path."""
+    """Chunked prefill + async decode vs the no-cache reference."""
 
-    def test_batch_serve_greedy_and_sampled(self, model, legacy_eng,
-                                            pipe_pair):
+    def test_batch_serve_greedy_and_sampled(self, model, pipe_pair):
         rng = np.random.RandomState(3)
         vocab = model.config.vocab_size
-        # mix: a prompt shorter than one chunk (monolithic fast path),
-        # multi-chunk prompts, and MIXED token budgets so the per-row
-        # length caps and max-remaining block sizing both engage
+        # mix: a prompt shorter than one chunk, multi-chunk prompts, and
+        # MIXED token budgets so the per-row length caps engage
         prompts = _prompts(rng, vocab, [5, 60, 100, 31])
         new = [7, 10, 5, 9]
         for kw in (dict(), dict(do_sample=True, temperature=0.9, top_k=20,
                                seed=123)):
-            ref = legacy_eng.serve(prompts, max_new_tokens=new, **kw)
+            ref = reference_streams(model, prompts, new, **kw)
             outs = pipe_pair[0].serve(prompts, max_new_tokens=new, **kw)
             for i, (a, b) in enumerate(zip(ref, outs)):
                 np.testing.assert_array_equal(a, b,
                                               err_msg=f"rid={i} kw={kw}")
 
-    def test_batch_serve_with_prefix_cache(self, model, prefix_pair):
+    def test_batch_serve_with_prefix_cache(self, model, prefix_eng):
         """Chunked prefill composes with the prefix cache: the cached-hit
         pages shrink the chunked suffix, outputs stay identical."""
         rng = np.random.RandomState(4)
@@ -104,33 +97,33 @@ class TestBitExactness:
                                    rng.randint(1, vocab, (int(l),))
                                    .astype(np.int32)])
                    for l in (60, 9, 40)]
-        ref = prefix_pair[0].serve(prompts, max_new_tokens=6)
-        outs = prefix_pair[1].serve(prompts, max_new_tokens=6)
+        ref = reference_streams(model, prompts, 6)
+        outs = prefix_eng.serve(prompts, max_new_tokens=6)
         for a, b in zip(ref, outs):
             np.testing.assert_array_equal(a, b)
-        assert prefix_pair[1].stats["prefix_hit_pages"] > 0
+        assert prefix_eng.stats["prefix_hit_pages"] > 0
 
-    def test_eos_mid_block(self, model, legacy_eng, pipe_pair):
+    def test_eos_mid_block(self, model, pipe_pair):
         """Overshoot discipline: a row retiring mid-block (EOS) under the
         async pipeline discards its overshoot tokens and matches the
-        legacy stream exactly."""
+        reference stream exactly."""
         rng = np.random.RandomState(5)
         vocab = model.config.vocab_size
         prompts = _prompts(rng, vocab, [9, 50, 14])
         # greedy streams are deterministic, so pick an eos that actually
         # appears: run once, then use the 2nd generated token of request 0
-        probe = legacy_eng.serve(prompts, max_new_tokens=8)
+        probe = reference_streams(model, prompts, 8)
         eos = int(probe[0][len(prompts[0]) + 1])
-        ref = legacy_eng.serve(prompts, max_new_tokens=8, eos_token_id=eos)
+        ref = reference_streams(model, prompts, 8, eos_token_id=eos)
+        assert len(ref[0]) == len(prompts[0]) + 2  # it fires mid-block
         outs = pipe_pair[0].serve(prompts, max_new_tokens=8,
                                   eos_token_id=eos)
         for a, b in zip(ref, outs):
             np.testing.assert_array_equal(a, b)
 
-    def test_online_frontend_matches_batch(self, model, legacy_eng,
-                                           pipe_pair):
+    def test_online_frontend_matches_batch(self, model, pipe_pair):
         """submit() order fixes the rids, so the frontend-served streams
-        must equal a batch serve() of the same prompts/seed — sampled, so
+        must equal the reference's for the same prompts/seed — sampled, so
         co-scheduling or replica placement differences would show."""
         rng = np.random.RandomState(6)
         vocab = model.config.vocab_size
@@ -139,7 +132,7 @@ class TestBitExactness:
         # same sampling tuple as the batch test: the sampler is a
         # compile-time constant, so this reuses the fixtures' programs
         kw = dict(do_sample=True, temperature=0.9, top_k=20, seed=7)
-        ref = legacy_eng.serve(prompts, max_new_tokens=new, **kw)
+        ref = reference_streams(model, prompts, new, **kw)
         with ServingFrontend(pipe_pair, heartbeat_deadline_s=120.0) as fe:
             handles = [fe.submit(p, new, slo_class="interactive", **kw)
                        for p in prompts]
@@ -151,11 +144,11 @@ class TestPrefixIndex:
     """Satellite: hashed (chained-digest) prefix-page index == the old
     content-exact probe, at O(prompt bytes) instead of O(pages^2)."""
 
-    def test_probe_matches_content_oracle(self, model, prefix_pair):
+    def test_probe_matches_content_oracle(self, model, prefix_eng):
         rng = np.random.RandomState(9)
         vocab = model.config.vocab_size
         page = 8
-        eng = prefix_pair[1]
+        eng = prefix_eng
 
         def oracle(prompt):
             # the pre-ISSUE-6 probe, reconstructed content-exactly from the
@@ -189,8 +182,8 @@ class TestPrefixIndex:
                             rng.randint(1, vocab, (6,)).astype(np.int32)])
         ) >= 40 // page - 1
 
-    def test_digest_chain_is_prefix_sensitive(self, model, prefix_pair):
-        eng = prefix_pair[1]
+    def test_digest_chain_is_prefix_sensitive(self, model, prefix_eng):
+        eng = prefix_eng
         a = np.arange(32, dtype=np.int32)
         b = a.copy()
         b[0] = 999  # first page differs -> EVERY chained digest differs
@@ -202,60 +195,43 @@ class TestPrefixIndex:
 
 
 class TestPipelineMechanics:
-    def test_chunked_prefill_unblocks_cotenant_ttft(self, model, legacy_eng,
-                                                    pipe_pair):
-        """The tentpole's latency claim, functionally: with chunked
-        prefill, a short request admitted behind a long prompt emits its
-        first token BEFORE the long prompt finishes prefilling; the
-        monolithic engine emits the long prompt's token first."""
+    def test_chunked_prefill_unblocks_cotenant_ttft(self, model, pipe_pair):
+        """The tentpole's latency claim, functionally: under a chunk
+        budget smaller than a long prompt, a short request admitted behind
+        it graduates (its first token is sampled) in an EARLIER dispatch
+        than the long prompt does; under a budget that holds both prompts
+        whole they graduate in the same dispatch."""
         rng = np.random.RandomState(10)
         vocab = model.config.vocab_size
         long_p = rng.randint(1, vocab, (120,)).astype(np.int32)
         short_p = rng.randint(1, vocab, (6,)).astype(np.int32)
 
-        def first_emitter(eng):
-            seen = []
-            eng.serve([long_p, short_p], max_new_tokens=4,
-                      on_token=lambda rid, tok: seen.append(rid))
-            return seen[0]
+        def graduations(eng):
+            """rid -> seq of the dispatch whose row for it has role `g`."""
+            mark = tracing.new_step(engine=-1)["seq"]
+            eng.serve([long_p, short_p], max_new_tokens=4)
+            return {row[0]: r["seq"] for r in tracing.step_records()
+                    if r["engine"] == eng._engine_seq and r["seq"] > mark
+                    for row in r["rows"] if row[1] == "g"}
 
-        assert first_emitter(legacy_eng) == 0   # monolithic prefill wins
-        assert first_emitter(pipe_pair[0]) == 1  # short slips between chunks
+        whole = graduations(_mk(model, prefill_chunk=128))
+        assert whole[0] == whole[1]   # one dispatch carries both prompts
+        chunked = graduations(pipe_pair[0])
+        assert chunked[1] < chunked[0]  # short slips ahead of the chunks
         # and the chunk metric actually moved
         from paddle_tpu.observability.metrics import registry
 
         assert registry.get("serve.prefill_chunks").value > 0
 
     def test_pages_in_use_invariant_after_chunked_serve(self, model,
-                                                        prefix_pair):
-        eng = prefix_pair[1]
+                                                        prefix_eng):
+        eng = prefix_eng
         rng = np.random.RandomState(11)
         vocab = model.config.vocab_size
         eng.serve(_prompts(rng, vocab, [70, 9, 100, 33]), max_new_tokens=5)
         scan = eng.num_pages - 1 - len(eng.free_pages) - len(eng._evictable)
         assert eng.pages_in_use() == scan == 0
         assert not eng._prefilling and eng._inflight is None
-
-    def test_warmup_buckets_sampling_covers_chunk_ladder(self, model):
-        """warmup(buckets=..., sampling=[...]) must compile every program
-        a chunked serve of those lengths hits — for EVERY sampling config
-        — so the serve itself adds no program keys (no mid-serve compile
-        stall on a fresh replica). Needs a FRESH engine: the assertion is
-        about what warmup alone compiled."""
-        eng = _mk(model, **PIPELINED)
-        samplings = [(False, 1.0, 0, 1.0), (True, 0.9, 12, 1.0)]
-        eng.warmup(buckets=[9, 33], sampling=samplings)
-        warm_before = set(eng._warm)
-        rng = np.random.RandomState(12)
-        vocab = model.config.vocab_size
-        prompts = _prompts(rng, vocab, [9, 33])
-        eng.serve(prompts, max_new_tokens=5)
-        eng.serve(prompts, max_new_tokens=5, do_sample=True,
-                  temperature=0.9, top_k=12, seed=3)
-        assert set(eng._warm) == warm_before
-        from paddle_tpu.observability.metrics import registry
-
-        assert registry.get("serve.compile_warmup_s").count > 0
 
     def test_frontend_warmup_kwarg_runs_on_dispatchers(self, model):
         engines = [_mk(model, **PIPELINED)]
@@ -277,7 +253,7 @@ class TestPipelineMechanics:
         """Lock decomposition: engines own DISTINCT dispatch locks (the
         old process-wide lock serialized every replica's jitted sections),
         warm concurrent serves on two engines complete from two threads,
-        and an injected shared lock (the bench baseline's pre-ISSUE-6
+        and an injected shared lock (the pre-ISSUE-6 process-wide lock's
         emulation) is honored verbatim."""
         e0, e1 = pipe_pair
         assert e0.dispatch_lock is not e1.dispatch_lock
@@ -303,41 +279,20 @@ class TestPipelineMechanics:
         assert not t.is_alive()
         np.testing.assert_array_equal(outs["fg"], outs["bg"])
         assert e0.idle() and e1.idle()
-        # the bench baseline's shared-lock injection really is shared
+        # an injected lock really is shared
         from paddle_tpu.inference.continuous import _StampedRLock
 
         shared = _StampedRLock()
-        b0 = _mk(model, **LEGACY, dispatch_lock=shared)
-        b1 = _mk(model, **LEGACY, dispatch_lock=shared)
+        b0 = _mk(model, dispatch_lock=shared)
+        b1 = _mk(model, dispatch_lock=shared)
         assert b0.dispatch_lock is b1.dispatch_lock is shared
-
-
-class TestBenchServingSmoke:
-    def test_quick_bench_emits_contract_json(self):
-        import bench_serving
-
-        res = bench_serving.run_bench(quick=True)
-        assert res["metric"] == "serving_tokens_per_sec_per_chip"
-        assert res["unit"] == "tokens/s/chip"
-        assert res["value"] > 0
-        assert res["vs_baseline"] > 0
-        extra = res["extra"]
-        for side in ("pipelined", "baseline"):
-            for key in ("tokens_per_sec", "ttft_p50_s", "ttft_p99_s",
-                        "tpot_p50_s", "wall_s"):
-                assert extra[side][key] is not None, (side, key)
-            assert extra[side]["errors"] == 0
-        assert extra["pipelined"]["prefill_chunks"] > 0
-        assert extra["baseline"]["prefill_chunks"] == 0
-        assert extra["ttft_interactive_under_prefill"]["speedup"] is not None
 
 
 class TestReplicaKillLast:
     """LAST on purpose: kills a dispatcher mid-flight, abandoning one
     engine with admitted state — unshareable with the module fixtures."""
 
-    def test_replica_kill_mid_stream_reroutes_bit_identically(self, model,
-                                                              legacy_eng):
+    def test_replica_kill_mid_stream_reroutes_bit_identically(self, model):
         """A chaos-killed replica's unconsumed in-flight requests reroute
         and still produce the reference streams (key streams depend only
         on seed/rid/index — replica- and pipeline-independent)."""
@@ -346,7 +301,7 @@ class TestReplicaKillLast:
         prompts = _prompts(rng, vocab, [60, 30, 45, 15])
         new = 6
         kw = dict(do_sample=True, temperature=0.9, top_k=20, seed=11)
-        ref = legacy_eng.serve(prompts, max_new_tokens=new, **kw)
+        ref = reference_streams(model, prompts, new, **kw)
         engines = [_mk(model, **PIPELINED) for _ in range(2)]
         fe = ServingFrontend(engines, heartbeat_deadline_s=120.0)
         try:

@@ -40,7 +40,7 @@ def _engine(model, async_decode, **kw):
 def _drive(eng, reqs):
     """The frontend's loop without the frontend: admit what fits, step."""
     queue, dispatches = deque(reqs), 0
-    inner = eng._dispatch_ragged if eng._ragged else eng._dispatch_decode
+    inner = eng._dispatch_ragged
 
     def counting(chain=None):
         nonlocal dispatches
@@ -48,14 +48,13 @@ def _drive(eng, reqs):
         dispatches += out is not None
         return out
 
-    name = "_dispatch_ragged" if eng._ragged else "_dispatch_decode"
-    setattr(eng, name, counting)
+    eng._dispatch_ragged = counting
     try:
         while queue or not eng.idle():
             eng._admit_from(queue)
             eng.step()
     finally:
-        delattr(eng, name)
+        del eng._dispatch_ragged
     return dispatches
 
 
@@ -256,24 +255,6 @@ def test_the_ring_is_bounded():
 def test_seq_is_process_wide():
     a, b = tracing.new_step(engine=0), tracing.new_step(engine=1)
     assert b["seq"] == a["seq"] + 1 and a["engine"] == 0
-
-
-def test_ladder_plane_decode_blocks_are_logged(model):
-    """ragged=False: admission prefills and emits the first token itself,
-    so the log holds decode blocks only and the rest of the tokens."""
-    eng = _engine(model, True, ragged=False)
-    reqs = _requests(2)
-    dispatches = _drive(eng, reqs)
-    recs = [r for r in tracing.step_records()
-            if r["engine"] == eng._engine_seq]
-    assert len(recs) == dispatches > 0
-    assert {r["kind"] for r in recs} == {"decode"}
-    assert all(row[1] == "d" for r in recs for row in r["rows"])
-    emitted = sum(n for r in recs for _, n in r["emits"])
-    assert emitted == sum(MAX_NEW) - len(reqs)
-    assert [a[0] for r in recs for a in r["admits"]] == [q.rid for q in reqs]
-    for r in recs:
-        assert [r[k] for k in STAMPS] == sorted(r[k] for k in STAMPS)
 
 
 def test_annotation_is_the_profilers_own():
