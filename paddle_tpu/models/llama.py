@@ -220,12 +220,15 @@ class LlamaAttention(Layer):
                                      k._data[:, 0])
             v_pages = write_token_kv(pc.v_pages, pc.page_indices, pc.lengths,
                                      v._data[:, 0])
+            # a dead row's token went to the scratch page: nothing to attend
             out = paged_decode_attention(
-                q._data[:, 0], k_pages, v_pages, pc.lengths + 1, pc.page_indices
+                q._data[:, 0], k_pages, v_pages,
+                jnp.where(pc.live, pc.lengths + 1, 0), pc.page_indices
             )
             out = Tensor(out.reshape(B, 1, self.num_heads * self.head_dim),
                          stop_gradient=True)
-            present = PagedLayerCache(k_pages, v_pages, pc.page_indices, pc.lengths)
+            present = PagedLayerCache(k_pages, v_pages, pc.page_indices,
+                                      pc.lengths, pc.live)
             return self.o_proj(out), present
         if ragged:
             from ..ops.ragged_paged_attention import (

@@ -3,17 +3,18 @@ of paddle/fluid/inference AnalysisPredictor + PaddleNLP's block-attention
 serving; PAPERS.md ragged-paged-attention is the kernel blueprint).
 
 TPU-native design: the KV cache is a POOL of fixed-size pages shared by all
-sequences — [num_kv_heads, num_pages, page_size, head_dim], the exact layout
-of jax's Pallas TPU `paged_attention` kernel — plus a per-sequence page table
+sequences — [num_kv_heads, num_pages, page_size, head_dim], the layout jax's
+Pallas TPU `paged_attention` kernel fixed — plus a per-sequence page table
 (page_indices [B, pages_per_seq]) and lengths [B]. Memory is bounded by pool
 occupancy (sum of actual context lengths, page-granular), not by
 B × max_len as the dense fixed-shape cache is.
 
 The pool's layout contract (stated here, for both writers and both kernels):
 the pool is [Hkv, P, bs, D] in the default row-major layout, from a
-program's parameter to its result, because both Mosaic kernels (jax's
-`paged_attention`, `_ragged_pallas`) read it so. A write must NOT be an XLA
-scatter whose window covers Hkv (`pages.at[:, page, off, :].set(...)`): the
+program's parameter to its result, because the Mosaic kernels
+(`_paged_pallas`, `_ragged_pallas`, jax's `paged_attention`) read it so. A
+write must NOT be an XLA scatter whose window covers Hkv
+(`pages.at[:, page, off, :].set(...)`): the
 TPU compiler lays a scatter's operand out with the window dims minor
 ([P, bs, Hkv, D] physically), carries the pool through the decode scan in
 that layout, and copies the whole pool back before every kernel call — 55%
@@ -23,13 +24,32 @@ writers make the head a scattered index: `write_token_kv` scatters rows
 tests/test_chip_compile.py compiles both program shapes for a described v5e
 and fails if a pool-shaped copy comes back.
 
-Two decode tiers, chosen at trace time like ops/flash_attention.py:
-- kernel: `jax.experimental.pallas.ops.tpu.paged_attention` on TPU;
-- math: one vectorized page-table gather plus a masked dense softmax
-  (the old per-page sequential scan paid npages chained gather+dot
-  round-trips — it remains the bit-exactness reference only in spirit;
-  the gathered slab is B × max_len, the same footprint a dense cache
-  would hold).
+Decode tiers, chosen at trace time like ops/flash_attention.py (`LAST_IMPL`;
+a tier that cannot run raises, it never becomes another):
+- `paged-kernel`, float pool: `_paged_pallas`, this module's kernel, on the
+  pattern of ops/mla_decode_attention.py. Grid (live row, block of pages)
+  with both bounds as OPERANDS (the rows that have anything to attend, the
+  blocks of the longest of them); the row list, lengths and page table are
+  scalar prefetch; every page of a block is one page-indirect operand
+  holding ALL KV heads ([Hkv, bs, D], one strided DMA a page), so a step
+  folds a block into every head's online softmax with two batched dots
+  (q grouped [Hkv, G, D]: MHA and GQA take the one path). Scores, softmax
+  and accumulator are f32. Work is in proportion to the live rows' extents:
+  a dead row (length 0) is never visited and returns zeros; past a row's
+  own pages an operand stays on the page it held, which is not fetched
+  again. On the v5e at 16 rows x 32/32 heads it reads 76-92% of the HBM
+  roofline (28 us a call at 3 live rows of 350 tokens), where jax's kernel
+  took 200-404 us (PERF.md PR 32: a 512-step grid, a 4 KB DMA a page and
+  head, f32 products, and 13 dead rows handed a length of 1).
+- `paged-kernel`, int8 pool (`is_quantized`): jax's
+  `jax.experimental.pallas.ops.tpu.paged_attention`, whose DMAs dequantise
+  (this module's kernel has no scales operand). It skips rows of length 0
+  too, but broadcasts the pool's scales to head_dim on every call (1.6 ms
+  at the cell's pool, PERF.md PR 32): a kernel for that pool is ROADMAP's.
+- `paged-math`: one vectorized page-table gather plus a masked dense
+  softmax in f32 (the gathered slab is B × max_len, the footprint a dense
+  cache would hold). The off-TPU default and the kernels' reference;
+  `impl="pallas"` runs `_paged_pallas` in interpret mode for the CPU tests.
 
 `PagedLayerCache` is the duck-typed per-layer cache entry the model's
 attention recognizes in `past_key_values` (models/llama.py) — the third
@@ -41,7 +61,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-LAST_IMPL = None  # "paged-kernel" | "paged-math" — set at trace time
+LAST_IMPL = None  # "paged-kernel[-interpret]" | "paged-math" — at trace time
 
 
 @jax.tree_util.register_pytree_node_class
@@ -52,15 +72,21 @@ class PagedLayerCache:
     k_pages/v_pages: [num_kv_heads, num_pages, page_size, head_dim]
     page_indices:    [B, pages_per_seq] int32 rows into the pool
     lengths:         [B] int32 — valid tokens per sequence BEFORE this step
+    live:            [B] bool — the rows a request holds. A dead row (an
+                     empty slot of the engine's fixed batch, a row still in
+                     prefill during a mixed step's scan) writes its token to
+                     the scratch page and attends nothing.
     """
 
     k_pages: jax.Array
     v_pages: jax.Array
     page_indices: jax.Array
     lengths: jax.Array
+    live: jax.Array
 
     def tree_flatten(self):
-        return (self.k_pages, self.v_pages, self.page_indices, self.lengths), None
+        return (self.k_pages, self.v_pages, self.page_indices, self.lengths,
+                self.live), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -108,9 +134,7 @@ class KVCacheSpec:
 
     @staticmethod
     def paged(pool, page_table, lengths, live):
-        # `live` (the rows a request holds) is the latent twin's: here a
-        # dead row reads its one scratch token
-        return PagedLayerCache(*pool, page_table, lengths)
+        return PagedLayerCache(*pool, page_table, lengths, live)
 
     @staticmethod
     def ragged(pool, page_table, kv_lens, cu, row_of, token_pos, valid):
@@ -222,38 +246,202 @@ def _paged_math(q, k_pages, v_pages, lengths, page_indices, scale):
     qs = (q * scale).astype(jnp.float32).reshape(B, Hkv, group, D)
     s = jnp.einsum("bhgd,bhkd->bhgk", qs, ks)  # [B, Hkv, group, M]
     pos = jnp.arange(M)
-    s = jnp.where(pos[None, None, None, :] < lengths[:, None, None, None],
-                  s, -1e30)
-    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    seen = pos[None, None, None, :] < lengths[:, None, None, None]
+    s = jnp.where(seen, s, -1e30)
+    # a row of length 0 sees nothing: zeros, not the mean of its table
+    p = jnp.where(seen, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
     out = jnp.einsum("bhgk,bhkd->bhgd", p, vs)
     out = out / jnp.maximum(p.sum(axis=-1), 1e-30)[..., None]
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 
+_LANES = 128  # m/l scratch keep a lane-aligned last dim
+_SUBLANES = 8  # q's group rows are padded to whole f32 sublane tiles
+
+
+def _decode_kernel(ppb, row_ref, len_ref, pt_ref, q_ref, *refs):
+    """Grid (i-th live row, block j of ppb pages): fold the block's K and V
+    rows, every KV head at once, into row `row_ref[i]`'s online softmax.
+    q_ref [Hkv, Gp, D] (the group's rows padded to Gp); refs: ppb K pages
+    and ppb V pages [Hkv, bs, D], then o_ref [Hkv, G, D] and the scratch acc
+    [Hkv, Gp, D], m and l [Hkv, Gp, 128]."""
+    import jax.experimental.pallas as pl
+
+    k_pg, v_pg = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, acc, m, l = refs[2 * ppb:]
+    j = pl.program_id(1)
+    kb = ppb * k_pg[0].shape[1]
+    length = len_ref[row_ref[pl.program_id(0)]]
+
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m[...] = jnp.full_like(m, -1e30)
+        l[...] = jnp.zeros_like(l)
+
+    @pl.when(j * kb < length)
+    def _fold():
+        k = jnp.concatenate([pg[...] for pg in k_pg], axis=1)  # [Hkv, kb, D]
+        v = jnp.concatenate([pg[...] for pg in v_pg], axis=1)
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)                # [Hkv, Gp, kb]
+        pos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, kb), 2)
+        s = jnp.where(pos < length, s, -1e30)
+        m_prev, l_prev = m[:, :, :1], l[:, :, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(pos < length, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m[...] = jnp.broadcast_to(m_new, m.shape)
+        l[...] = jnp.broadcast_to(
+            l_prev * corr + p.sum(axis=-1, keepdims=True), l.shape)
+        # the weights ride the MXU in the pool's dtype, as the no-cache
+        # forward's softmax rounds them; the sums stay f32
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)                # [Hkv, Gp, D]
+        acc[...] = acc[...] * corr + pv
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        out = acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
+        o_ref[...] = out[:, :o_ref.shape[1]]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
+def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
+                  interpret, ppb=None):
+    """The float pool's kernel (module docstring). `ppb`, pages a block, is
+    `_pages_per_block`'s unless a test or a sweep says otherwise. The
+    `pallas_call` is named `paged_attention`: once a layer and scan step,
+    under the name the benchmark's reader looks for."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hq, D = q.shape
+    Hkv, _, bs, _ = k_pages.shape
+    npages = page_indices.shape[1]
+    group = Hq // Hkv
+    gp = -(-group // _SUBLANES) * _SUBLANES
+    ppb = ppb or _pages_per_block(k_pages, npages)
+
+    def page_map(pg):
+        def index(i, j, rows, lens, pt):
+            # past the row's pages the operand stays on the last page it
+            # held in this row (the scratch page if it held none): a block
+            # index that repeats between steps is not fetched again
+            b, held = rows[i], (lens[rows[i]] + bs - 1) // bs
+            last = pg + jnp.maximum(held - 1 - pg, 0) // ppb * ppb
+            page = pt[b, jnp.minimum(j * ppb + pg, last)]
+            return (0, jnp.where(pg < held, page, 0), 0, 0)
+        return index
+
+    def row_map(i, j, rows, lens, pt):
+        return (rows[i], 0, 0, 0)
+
+    lengths = lengths.astype(jnp.int32)
+    live = lengths > 0
+    # the live rows' numbers, in order, then zeros: no sort (one fusion)
+    idx = jnp.arange(B, dtype=jnp.int32)
+    place = jnp.sum(live[None, :] & (idx[None, :] <= idx[:, None]), axis=1) - 1
+    rows = jnp.sum(jnp.where(live[None, :] & (place[None, :] == idx[:, None]),
+                             idx[None, :], 0), axis=1).astype(jnp.int32)
+    n_blocks = jnp.minimum((jnp.max(lengths) + ppb * bs - 1) // (ppb * bs),
+                           -(-npages // ppb))
+    qs = (q * scale).astype(k_pages.dtype).reshape(B, Hkv, group, D)
+    qs = jnp.pad(qs, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    page_bytes = Hkv * bs * D * k_pages.dtype.itemsize
+    fn = pl.pallas_call(
+        functools.partial(_decode_kernel, ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(live.sum().astype(jnp.int32), n_blocks),
+            in_specs=[pl.BlockSpec((None, Hkv, gp, D), row_map)]
+            + [pl.BlockSpec((Hkv, None, bs, D), page_map(pg))
+               for pg in range(ppb)] * 2,
+            out_specs=pl.BlockSpec((None, Hkv, group, D), row_map),
+            scratch_shapes=[pltpu.VMEM((Hkv, gp, D), jnp.float32),
+                            pltpu.VMEM((Hkv, gp, _LANES), jnp.float32),
+                            pltpu.VMEM((Hkv, gp, _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # every page operand twice (the pipeline's two buffers), the
+            # block's K and V gathered and widened once more, and room
+            vmem_limit_bytes=int(12 * ppb * page_bytes) + (16 << 20)),
+        interpret=interpret,
+        name="paged_attention",
+    )
+    out = fn(rows, lengths, page_indices.astype(jnp.int32), qs,
+             *([k_pages] * ppb), *([v_pages] * ppb))
+    # the grid never visits a row of length 0: its block is whatever was there
+    return jnp.where(live[:, None, None], out.reshape(B, Hq, D),
+                     0.0).astype(q.dtype)
+
+
+#: K (or V) bytes a grid step folds, every KV head of its pages: 8 pages at
+#: 32 heads x 16 x 128 bf16, 32 at 8 heads, where each was the sweep's best at
+#: long contexts (half loses 9% there, double 15% at short ones; PERF.md PR 32)
+_BLOCK_BYTES = 1 << 20
+#: page operands a pool and step: 2 x 32 + q compile and ran at 7B widths
+_MAX_PAGES = 32
+
+
+def _pages_per_block(k_pages, npages):
+    """Pages a grid step folds, from the shape alone (page size, KV heads,
+    head dim, dtype): as many as fill `_BLOCK_BYTES`, at most `_MAX_PAGES`
+    and the table's width. VMEM follows: the step holds each page operand
+    twice and the gathered block once, ~6 `_BLOCK_BYTES` for K and V."""
+    hkv, _, bs, d = k_pages.shape
+    page_bytes = hkv * bs * d * k_pages.dtype.itemsize
+    return max(1, min(_BLOCK_BYTES // page_bytes, _MAX_PAGES, npages))
+
+
+def _jax_kernel(q, k_pages, v_pages, lengths, page_indices, scale):
+    """jax's paged-attention kernel, for the int8 pool. 32 pages a compute
+    block (the default 8 was 1.1-1.5x slower on either pool from 350 tokens
+    a row up, PERF.md PR 32), fewer where the table's width asks."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention as _kernel,
+    )
+
+    blk = min(32, page_indices.shape[1])
+    while page_indices.shape[1] % blk:
+        blk -= 1
+    out = _kernel((q * scale).astype(jnp.bfloat16), k_pages, v_pages,
+                  lengths.astype(jnp.int32), page_indices,
+                  pages_per_compute_block=blk)
+    # it skips a row of length 0 and leaves its output block unwritten
+    return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
-                           scale=None, pages_per_compute_block=None):
+                           scale=None, impl=None):
     """One-token decode attention over the paged pool.
 
     q: [B, Hq, D]; returns [B, Hq, D]. lengths must already INCLUDE the
-    just-written token (the query attends to itself)."""
+    just-written token (the query attends to itself); a row of length 0
+    attends nothing, costs nothing and returns zeros. impl: None/"auto"
+    (a kernel on TPU, where its failure raises; the math tier elsewhere),
+    "math", "pallas" (the float pool's kernel in interpret mode off TPU)."""
     global LAST_IMPL
     from .flash_attention import _FORCE_XLA, _on_tpu
 
     D = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    if _on_tpu() and not _FORCE_XLA:
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as _kernel,
-        )
-
-        blk = pages_per_compute_block or min(8, page_indices.shape[1])
-        while page_indices.shape[1] % blk:
-            blk -= 1
-        qdt = jnp.bfloat16 if is_quantized(k_pages) else k_pages.dtype
-        out = _kernel((q * scale).astype(qdt), k_pages, v_pages,
-                      lengths, page_indices,
-                      pages_per_compute_block=max(blk, 1))
-        LAST_IMPL = "paged-kernel"
-        return out.astype(q.dtype)
+    impl = impl or "auto"
+    on_tpu = _on_tpu() and not _FORCE_XLA
+    if impl == "pallas" or (impl == "auto" and on_tpu):
+        if is_quantized(k_pages):
+            if not on_tpu:
+                raise ValueError("the int8 pool's kernel is jax's, which "
+                                 "has no interpret mode off a TPU")
+            out = _jax_kernel(q, k_pages, v_pages, lengths, page_indices,
+                              scale)
+        else:
+            out = _paged_pallas(q, k_pages, v_pages, lengths, page_indices,
+                                scale, interpret=not on_tpu)
+        LAST_IMPL = "paged-kernel" if on_tpu else "paged-kernel-interpret"
+        return out
     LAST_IMPL = "paged-math"
     return _paged_math(q, k_pages, v_pages, lengths, page_indices, scale)

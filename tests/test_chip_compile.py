@@ -9,17 +9,17 @@ kernel exactly as the ops module calls it, at LLaMA-7B widths (h4096 = 32
 heads x 128, page 16, T = prefill_chunk + max_seqs), and asserts the
 compiled program holds a Mosaic kernel (`tpu_custom_call`).
 
-The last two cases guard a layout, not a kernel: the KV-pool writers and the
+The pool cases guard a layout, not a kernel: the KV-pool writers and the
 two paged kernels in the serving engine's two program shapes (a decode block:
 writer -> paged kernel in an 8-step `lax.scan`; a mixed step: ragged writer ->
 ragged kernel, then the 7-step scan), two layers at the benchmark cell's
-widths, pools donated. A write expressed as an XLA scatter whose window covers
-the head axis makes the TPU compiler keep the pool in another layout than the
-kernels read, and re-lay out the whole pool around every kernel call (55% of
-device time when it was found, PERF.md PR 27). A CPU compile shows nothing of
-it, so these two are the only guard a CPU suite can have against the layout
-coming back: no pool-shaped `copy` in the compiled text, temporaries under
-one pool.
+widths (32 / 32 heads) and at 32 / 8, pools donated. A write expressed as an
+XLA scatter whose window covers the head axis makes the TPU compiler keep
+the pool in another layout than the kernels read, and re-lay out the whole
+pool around every kernel call (55% of device time when it was found, PERF.md
+PR 27). A CPU compile shows nothing of it, so these are the only guard a CPU
+suite can have against the layout coming back: no pool-shaped `copy` in the
+compiled text, temporaries under one pool.
 
 The latent-attention configuration (benchmarks/configs/kimi-k2.7-code-ep32)
 adds its kernels to the first group (the absorbed decode kernel, and jax's
@@ -135,19 +135,31 @@ def _splash_gqa():
     return fn, args
 
 
-def _paged_decode():
-    """jax's paged-attention kernel through ops/paged_attention.py's own
-    call (the test steers its platform predicate to the described chip)."""
+def _paged_decode(hkv, quantized=False):
+    """The paged decode tier the pool's type takes (the repo's kernel for a
+    float pool, jax's for the int8 pool) through ops/paged_attention.py's own
+    call, at the serving cell's shape: 16 rows of 128 pages (the test steers
+    the platform predicate to the described chip)."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        quantization_utils as qu,
+    )
+
     from paddle_tpu.ops.paged_attention import paged_decode_attention
 
     def fn(q, k, v, lengths, page_indices):
         return paged_decode_attention(q, k, v, lengths, page_indices)
 
     def args(sds):
-        pool = sds((H, POOL, PAGE, D), jnp.bfloat16)
-        return (sds((MAX_SEQS, H, D), jnp.bfloat16), pool, pool,
-                sds((MAX_SEQS,), jnp.int32),
-                sds((MAX_SEQS, NPAGES), jnp.int32))
+        shape = (hkv,) + CELL_POOL[1:]
+        if quantized:
+            pool = qu.QuantizedTensor(
+                weight=sds(shape, jnp.int8),
+                scales=sds(shape[:3] + (1,), jnp.float32))
+        else:
+            pool = sds(shape, jnp.bfloat16)
+        return (sds((CELL_ROWS, H, D), jnp.bfloat16), pool, pool,
+                sds((CELL_ROWS,), jnp.int32),
+                sds((CELL_ROWS, NPAGES), jnp.int32))
 
     return fn, args
 
@@ -205,7 +217,9 @@ CASES = {
     "flash-fwd-s2048": lambda: _flash(grad=False),
     "flash-bwd-s2048": lambda: _flash(grad=True),
     "splash-gqa-kv8": _splash_gqa,
-    "paged-decode": _paged_decode,
+    "paged-decode-mha": lambda: _paged_decode(H),
+    "paged-decode-gqa-kv8": lambda: _paged_decode(HKV_GQA),
+    "paged-decode-int8-pool": lambda: _paged_decode(H, quantized=True),
 }
 
 
@@ -215,9 +229,10 @@ CELL_T = PREFILL_CHUNK + CELL_ROWS
 CELL_POOL = (CELL_HKV, 1 + CELL_ROWS * NPAGES, PAGE, D)
 
 
-def _decode_scan(pools, q, new, table, lengths, steps):
+def _decode_scan(pools, q, new, table, lengths, live, steps):
     """`steps` decode steps as the engine's scan body runs them: each layer
-    writes one token a row into K and V, then reads both pools."""
+    writes one token a row into K and V, then reads both pools; a dead row
+    (`live` false) attends nothing."""
     from paddle_tpu.ops.paged_attention import (
         paged_decode_attention, write_token_kv,
     )
@@ -226,9 +241,10 @@ def _decode_scan(pools, q, new, table, lengths, steps):
         pools_c, lens = carry
         acc, written = jnp.zeros_like(q), []
         for kp, vp in pools_c:
-            kp = write_token_kv(kp, table, lens, new)
-            vp = write_token_kv(vp, table, lens, new)
-            acc += paged_decode_attention(q, kp, vp, lens + 1, table)
+            kp = write_token_kv(kp, table, lens, new[:, :kp.shape[0]])
+            vp = write_token_kv(vp, table, lens, new[:, :vp.shape[0]])
+            acc += paged_decode_attention(
+                q, kp, vp, jnp.where(live, lens + 1, 0), table)
             written.append((kp, vp))
         return (tuple(written), lens + 1), acc
 
@@ -237,46 +253,48 @@ def _decode_scan(pools, q, new, table, lengths, steps):
     return outs, pools
 
 
-def _decode_block_shape():
-    def fn(pools, q, new, table, lengths):
-        return _decode_scan(pools, q, new, table, lengths, CELL_K)
+def _decode_block_shape(hkv):
+    def fn(pools, q, new, table, lengths, live):
+        return _decode_scan(pools, q, new, table, lengths, live, CELL_K)
 
     def args(sds):
-        pool = sds(CELL_POOL, jnp.bfloat16)
-        q = sds((CELL_ROWS, CELL_HKV, D), jnp.bfloat16)
+        pool = sds((hkv,) + CELL_POOL[1:], jnp.bfloat16)
+        q = sds((CELL_ROWS, H, D), jnp.bfloat16)
         return (((pool, pool),) * CELL_LAYERS, q, q,
                 sds((CELL_ROWS, NPAGES), jnp.int32),
-                sds((CELL_ROWS,), jnp.int32))
+                sds((CELL_ROWS,), jnp.int32), sds((CELL_ROWS,), jnp.bool_))
 
     return fn, args
 
 
-def _mixed_step_shape():
+def _mixed_step_shape(hkv):
     from paddle_tpu.ops.ragged_paged_attention import (
         _ragged_pallas, write_ragged_kv,
     )
 
-    def fn(pools, q_t, new_t, q, new, table, lengths, cu, row_of, token_pos,
-           valid):
+    def fn(pools, q_t, new_t, q, new, table, lengths, live, cu, row_of,
+           token_pos, valid):
         kv_lens = lengths + cu[1:] - cu[:-1]
         acc, written = jnp.zeros_like(q_t), []
         for kp, vp in pools:
-            kp = write_ragged_kv(kp, table, row_of, token_pos, valid, new_t)
-            vp = write_ragged_kv(vp, table, row_of, token_pos, valid, new_t)
+            kp = write_ragged_kv(kp, table, row_of, token_pos, valid,
+                                 new_t[:, :hkv])
+            vp = write_ragged_kv(vp, table, row_of, token_pos, valid,
+                                 new_t[:, :hkv])
             acc += _ragged_pallas(q_t, kp, vp, kv_lens, table, cu,
                                   D ** -0.5, interpret=False)
             written.append((kp, vp))
         return acc, _decode_scan(tuple(written), q, new, table, kv_lens,
-                                 CELL_K - 1)
+                                 live, CELL_K - 1)
 
     def args(sds):
-        pool = sds(CELL_POOL, jnp.bfloat16)
-        q_t = sds((CELL_T, CELL_HKV, D), jnp.bfloat16)
-        q = sds((CELL_ROWS, CELL_HKV, D), jnp.bfloat16)
+        pool = sds((hkv,) + CELL_POOL[1:], jnp.bfloat16)
+        q_t = sds((CELL_T, H, D), jnp.bfloat16)
+        q = sds((CELL_ROWS, H, D), jnp.bfloat16)
         per_token = sds((CELL_T,), jnp.int32)
         return (((pool, pool),) * CELL_LAYERS, q_t, q_t, q, q,
                 sds((CELL_ROWS, NPAGES), jnp.int32),
-                sds((CELL_ROWS,), jnp.int32),
+                sds((CELL_ROWS,), jnp.int32), sds((CELL_ROWS,), jnp.bool_),
                 sds((CELL_ROWS + 1,), jnp.int32), per_token, per_token,
                 sds((CELL_T,), jnp.bool_))
 
@@ -284,8 +302,10 @@ def _mixed_step_shape():
 
 
 POOL_CASES = {
-    "decode-block": _decode_block_shape,
-    "mixed-step": _mixed_step_shape,
+    "decode-block-mha": (_decode_block_shape, CELL_HKV),
+    "decode-block-gqa-kv8": (_decode_block_shape, HKV_GQA),
+    "mixed-step-mha": (_mixed_step_shape, CELL_HKV),
+    "mixed-step-gqa-kv8": (_mixed_step_shape, HKV_GQA),
 }
 
 
@@ -364,12 +384,15 @@ def test_splash_grad_runs_on_the_rules_tiles(case, one_chip, monkeypatch):
 
 @pytest.mark.parametrize("case", sorted(POOL_CASES))
 def test_pool_stays_in_the_kernels_layout(case, one_chip, monkeypatch):
-    compiled = _compile_for_chip(POOL_CASES[case], one_chip, monkeypatch,
+    shape, hkv = POOL_CASES[case]
+    compiled = _compile_for_chip(lambda: shape(hkv), one_chip, monkeypatch,
                                  donate_argnums=(0,))
-    pool = ",".join(map(str, CELL_POOL))
-    copies = re.findall(rf"= bf16\[{pool}\][^ ]* copy\(", compiled.as_text())
+    text = compiled.as_text()
+    assert "%paged_attention" in text  # the name the benchmark's reader finds
+    pool = ",".join(map(str, (hkv,) + CELL_POOL[1:]))
+    copies = re.findall(rf"= bf16\[{pool}\][^ ]* copy\(", text)
     assert not copies, f"{len(copies)} whole-pool re-layout copies"
-    pool_bytes = 2 * CELL_HKV * CELL_POOL[1] * PAGE * D
+    pool_bytes = 2 * hkv * CELL_POOL[1] * PAGE * D
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 

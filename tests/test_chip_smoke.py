@@ -154,6 +154,14 @@ def _call_paged():
         jnp.zeros((2, 2), jnp.int32))
 
 
+def _call_paged_int8():
+    q = jnp.zeros((2, 2, 64), jnp.float32)
+    pool = pa.quantize_pages(jnp.zeros((2, 5, 8, 64), jnp.float32))
+    return pa.paged_decode_attention(
+        q, pool, pool, jnp.ones((2,), jnp.int32),
+        jnp.zeros((2, 2), jnp.int32))
+
+
 def _call_ragged():
     q = jnp.zeros((8, 2, 64), jnp.float32)
     pool = jnp.zeros((2, 5, 8, 64), jnp.float32)
@@ -177,12 +185,14 @@ class TestKernelFailureIsFatalOnTpu:
         (_call_splash, "paddle_tpu.ops.flash_attention._splash_impl"),
         (_call_packed, "paddle_tpu.ops.flash_attention._splash_kernel"),
         (_call_varlen, "paddle_tpu.ops.flash_attention._splash_varlen"),
-        (_call_paged, "jax.experimental.pallas.ops.tpu.paged_attention"
-                      ".paged_attention"),
+        (_call_paged, "paddle_tpu.ops.paged_attention._paged_pallas"),
+        (_call_paged_int8, "jax.experimental.pallas.ops.tpu.paged_attention"
+                           ".paged_attention"),
         (_call_ragged,
          "paddle_tpu.ops.ragged_paged_attention._ragged_pallas"),
         (_call_ring, "paddle_tpu.ops.ring_attention._ring_kernel"),
-    ], ids=["flash", "splash", "packed", "varlen", "paged", "ragged", "ring"])
+    ], ids=["flash", "splash", "packed", "varlen", "paged", "paged-int8", "ragged",
+            "ring"])
     def test_kernel_exception_propagates(self, call, kernel, monkeypatch):
         monkeypatch.setattr(fa, "_on_tpu", lambda: True)
         monkeypatch.setattr(kernel, _refuse)

@@ -367,6 +367,37 @@ def test_raising_on_token_does_not_leak_warm_engine():
         np.testing.assert_array_equal(o, ref)
 
 
+def test_interpret_kernel_serves_the_reference_with_half_the_rows_dead(
+        monkeypatch):
+    """2 requests in an engine of 4 rows, GQA, through the float pool's
+    decode kernel in interpret mode (steered here, not by an option of the
+    program): the two empty slots reach the kernel at length 0 in every scan
+    step, and the served tokens are the no-cache reference's."""
+    import functools
+
+    from _serving_reference import reference_streams
+
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+    from paddle_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "paged_decode_attention", functools.partial(
+        pa.paged_decode_attention, impl="pallas"))
+    paddle.seed(31)
+    m = LlamaForCausalLM(llama_tiny(num_key_value_heads=2))
+    m.eval()
+    rng = np.random.RandomState(17)
+    prompts = [rng.randint(1, m.config.vocab_size, (l,)).astype(np.int32)
+               for l in [19, 5]]
+    eng = ContinuousBatchingEngine(m, max_seqs=4, page_size=16, max_len=64,
+                                   decode_block=4)
+    pa.LAST_IMPL = None
+    outs = eng.serve(prompts, max_new_tokens=7)
+    assert pa.LAST_IMPL == "paged-kernel-interpret"
+    assert eng.stats["decode_steps"] > 0
+    for o, r in zip(outs, reference_streams(m, prompts, 7)):
+        np.testing.assert_array_equal(o, r)
+
+
 def test_block_decode_matches_per_token():
     """decode_block=8 (k steps per dispatch) must produce exactly the same
     streams as decode_block=1 (per-token dispatch), across mixed lengths,
