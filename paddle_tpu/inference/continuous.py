@@ -439,17 +439,23 @@ class ContinuousBatchingEngine:
         dtype = next(iter(model.parameters())).dtype
         self.kv_cache_dtype = kv_cache_dtype
         # the model says what its layers cache (the model protocol:
-        # serving_cache_spec / serving_trunk / serving_head): K and V pools
+        # serving_cache_spec / serving_trunk / serving_head; the members
+        # read here are stated in ops/cache_specs.py): K and V pools
         # (ops.paged_attention.KVCacheSpec, also the default for a model
-        # that says nothing) or one pool of latent rows
-        # (ops.latent_pool.LatentCacheSpec). Pools, cache entries and bytes
-        # a token are the spec's; the allocator counts pages either way.
+        # that says nothing), one pool of latent rows
+        # (ops.latent_pool.LatentCacheSpec), or a spec a LAYER
+        # (ops.cache_specs.LayerCacheSpecs: selected K and V pages beside
+        # slots of recurrent state). Pools, cache entries, bytes and what a
+        # plane may do are the layer's; the allocator counts pages, which
+        # every paged layer shares through the row's page table, and a
+        # state slot is the row itself.
         spec = getattr(model, "serving_cache_spec", None)
         self._cache_spec = spec() if spec is not None else KVCacheSpec(
             cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim)
-        self._latent = self._cache_spec.latent
+        self._layer_specs = self._cache_spec.layers
         self.pools = self._cache_spec.make_pools(
-            self.num_pages, page_size, dtype, kv_cache_dtype)
+            self.num_pages, page_size, dtype, kv_cache_dtype,
+            max_seqs=max_seqs)
         self.free_pages = list(range(1, self.num_pages))  # page 0 = scratch
         self.free_slots = list(range(max_seqs))
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
@@ -578,10 +584,11 @@ class ContinuousBatchingEngine:
         # decode row per step (ops/ragged_paged_attention.py): ONE mixed
         # program plus the fixed-k decode block per (sampling, kv-dtype,
         # lora-rank), whatever the prompt lengths.
-        if self._latent and self.enable_prefix_cache:
-            # written for K and V pools; on a pool of latent rows it
-            # refuses by name
-            self._refuse_latent("the prefix cache (enable_prefix_cache)")
+        if self.enable_prefix_cache:
+            # written for K and V pools: a cache that cannot share a row's
+            # pages refuses by name
+            self._refuse("prefix_cache",
+                         "the prefix cache (enable_prefix_cache)")
         # token budget for prompt chunks per mixed dispatch
         self._ragged_chunk = max(self.prefill_chunk or min(256, max_len), 1)
         # packed token-stream width: chunk budget + one feed token per slot
@@ -909,18 +916,14 @@ class ContinuousBatchingEngine:
         def decode(state, toks, pools, page_table, lengths, caps, keys):
             overrides = {k: Tensor(v, stop_gradient=True) for k, v in state.items()}
             lengths_e = jnp.minimum(lengths, caps)
-            pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
-                                           caps > 0)
-                    for pool in pools]
+            pkvs = self._paged_views(pools, page_table, lengths_e, caps > 0)
             logits, presents = model.functional_call(
                 overrides, Tensor(toks),
                 position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                 past_key_values=pkvs, use_cache=True, training=False,
             )
             nxt = sampler(logits._data[:, -1], keys).astype(jnp.int32)
-            return nxt, tuple(
-                self._cache_spec.pool_of(p) for p in presents
-            )
+            return nxt, self._pools_of(presents)
 
         # donate the pools: a single-token decode must UPDATE the pool in
         # place, not copy it — without donation every step pays a full-pool
@@ -962,16 +965,15 @@ class ContinuousBatchingEngine:
                 # freeze an over-budget row at its last reserved position
                 # (identity for rows within budget — see caps note above)
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
-                                               caps > 0)
-                        for pool in pools_c]
+                pkvs = self._paged_views(pools_c, page_table, lengths_e,
+                                         caps > 0)
                 logits, presents = model.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 nxt = sampler(logits._data[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents)
+                new_pools = self._pools_of(presents)
                 out = (nxt[:, None], new_pools, lengths_e + 1)
                 if count is not None:
                     out += (carry[3] + count(),)
@@ -1036,9 +1038,7 @@ class ContinuousBatchingEngine:
                    a_stack, b_stack, scales, lora_idx):
             overrides = self._trunk_overrides(state, prefix)
             lengths_e = jnp.minimum(lengths, caps)
-            pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
-                                           caps > 0)
-                    for pool in pools]
+            pkvs = self._paged_views(pools, page_table, lengths_e, caps > 0)
             h, presents = inner.functional_call(
                 overrides, Tensor(toks),
                 position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
@@ -1053,7 +1053,7 @@ class ContinuousBatchingEngine:
             delta = jnp.einsum("bsr,brv->bsv", delta, b_rows)
             logits = base + delta * scales[lora_idx][:, None, None]
             nxt = sampler(logits[:, -1], keys).astype(jnp.int32)
-            return nxt, tuple(self._cache_spec.pool_of(p) for p in presents)
+            return nxt, self._pools_of(presents)
 
         fn = self._lora_decode_fns[key2] = _compilemem.ledgered_jit(
             decode, key=f"serve.lora_decode[r{rank},s{sampling}]",
@@ -1084,9 +1084,8 @@ class ContinuousBatchingEngine:
             def body(carry, step_keys):
                 toks_c, pools_c, lengths_c = carry
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [self._cache_spec.paged(pool, page_table, lengths_e,
-                                               caps > 0)
-                        for pool in pools_c]
+                pkvs = self._paged_views(pools_c, page_table, lengths_e,
+                                         caps > 0)
                 h, presents = inner.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(
@@ -1100,7 +1099,7 @@ class ContinuousBatchingEngine:
                 delta = jnp.einsum("bsr,brv->bsv", delta, b_rows)
                 logits = base + delta * s_rows
                 nxt = sampler(logits[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents)
+                new_pools = self._pools_of(presents)
                 return (nxt[:, None], new_pools, lengths_e + 1), nxt
 
             (_, pools_out, _), toks_block = jax.lax.scan(
@@ -1152,9 +1151,8 @@ class ContinuousBatchingEngine:
             upd = jnp.where(use_last[:, 0], last[:, 0], tok_block[first_idx])
             toks_in = tok_block.at[first_idx].set(upd)
             kv_lens = lengths + q_lens  # POST-write totals (ragged contract)
-            rcaches = [self._cache_spec.ragged(pool, page_table, kv_lens, cu,
-                                              row_of, token_pos, valid)
-                       for pool in pools]
+            rcaches = self._ragged_views(pools, page_table, kv_lens, cu,
+                                         row_of, token_pos, valid)
             h, presents = inner.functional_call(
                 inner_ov, Tensor(toks_in[None]),
                 position_ids=Tensor(token_pos[None].astype(jnp.int32)),
@@ -1165,7 +1163,7 @@ class ContinuousBatchingEngine:
             h_b = h._data[0, b_idx]                         # [max_seqs, H]
             base = model.serving_head(h_b, state)   # [max_seqs, V]
             tok0 = sampler(base, keys[0]).astype(jnp.int32)
-            pools1 = tuple(self._cache_spec.pool_of(p) for p in presents)
+            pools1 = self._pools_of(presents)
             init = (tok0[:, None], pools1, kv_lens)
             if count is not None:
                 init += (count(),)  # the packed pass's; the scan adds its own
@@ -1173,16 +1171,15 @@ class ContinuousBatchingEngine:
             def body(carry, step_keys):
                 toks_c, pools_c, lengths_c = carry[:3]
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [self._cache_spec.paged(pool, scan_table, lengths_e,
-                                               caps > 0)
-                        for pool in pools_c]
+                pkvs = self._paged_views(pools_c, scan_table, lengths_e,
+                                         caps > 0)
                 logits, presents2 = model.functional_call(
                     overrides, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
                     past_key_values=pkvs, use_cache=True, training=False,
                 )
                 nxt = sampler(logits._data[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents2)
+                new_pools = self._pools_of(presents2)
                 out = (nxt[:, None], new_pools, lengths_e + 1)
                 if count is not None:
                     out += (carry[3] + count(),)
@@ -1228,9 +1225,8 @@ class ContinuousBatchingEngine:
             upd = jnp.where(use_last[:, 0], last[:, 0], tok_block[first_idx])
             toks_in = tok_block.at[first_idx].set(upd)
             kv_lens = lengths + q_lens
-            rcaches = [self._cache_spec.ragged(pool, page_table, kv_lens, cu,
-                                              row_of, token_pos, valid)
-                       for pool in pools]
+            rcaches = self._ragged_views(pools, page_table, kv_lens, cu,
+                                         row_of, token_pos, valid)
             h, presents = inner.functional_call(
                 inner_ov, Tensor(toks_in[None]),
                 position_ids=Tensor(token_pos[None].astype(jnp.int32)),
@@ -1243,15 +1239,14 @@ class ContinuousBatchingEngine:
             delta = jnp.einsum("br,brv->bv", delta, b_rows)
             tok0 = sampler(base + delta * s_rows[:, None],
                            keys[0]).astype(jnp.int32)
-            pools1 = tuple(self._cache_spec.pool_of(p) for p in presents)
+            pools1 = self._pools_of(presents)
             s3 = s_rows[:, None, None]
 
             def body(carry, step_keys):
                 toks_c, pools_c, lengths_c = carry
                 lengths_e = jnp.minimum(lengths_c, caps)
-                pkvs = [self._cache_spec.paged(pool, scan_table, lengths_e,
-                                               caps > 0)
-                        for pool in pools_c]
+                pkvs = self._paged_views(pools_c, scan_table, lengths_e,
+                                         caps > 0)
                 h2, presents2 = inner.functional_call(
                     inner_ov, Tensor(toks_c),
                     position_ids=Tensor(lengths_e[:, None].astype(jnp.int32)),
@@ -1264,7 +1259,7 @@ class ContinuousBatchingEngine:
                 d2 = jnp.einsum("bsr,brv->bsv", d2, b_rows)
                 logits = base2 + d2 * s3
                 nxt = sampler(logits[:, -1], step_keys).astype(jnp.int32)
-                new_pools = tuple(self._cache_spec.pool_of(p) for p in presents2)
+                new_pools = self._pools_of(presents2)
                 return (nxt[:, None], new_pools, lengths_e + 1), nxt
 
             (_, pools_out, _), toks_tail = jax.lax.scan(
@@ -1329,11 +1324,9 @@ class ContinuousBatchingEngine:
         """Why this adapter can never run on this engine (None = it can):
         admission fails the request alone instead of deferring forever."""
         hidden, vocab = self._lora_dims
-        if self._latent:
-            return ValueError(
-                "the LoRA planes run copies of the K-and-V programs; this "
-                "model caches latent rows "
-                f"({type(self._cache_spec).__name__})")
+        why = self._cache_spec.refuses("lora")
+        if why:
+            return ValueError(f"the LoRA planes {why}")
         if not hasattr(self.model, "serving_trunk") or hidden is None \
                 or vocab is None:
             return ValueError(
@@ -1409,8 +1402,11 @@ class ContinuousBatchingEngine:
             return
         for key in list(_compilemem.memory.programs()):
             if key.startswith(("serve.ragged[", "serve.decode_block[")):
-                _trace.note_program_scopes(
-                    key, _compilemem.memory.compiled(key).as_text(), scopes)
+                try:
+                    text = _compilemem.memory.compiled(key).as_text()
+                except KeyError:
+                    continue  # an earlier engine's program, since collected
+                _trace.note_program_scopes(key, text, scopes)
 
     def _warmup_serve(self, do_sample, temperature, top_k, top_p,
                       lora_rank=None):
@@ -1498,13 +1494,29 @@ class ContinuousBatchingEngine:
     # and continues bit-identically. All three hooks run on the owning
     # dispatcher thread (the engine's single-threaded contract).
 
-    def _refuse_latent(self, plane):
-        """A plane written for K and V pages, asked of a pool of latent
-        rows, says so by name: none may silently fall back."""
-        if self._latent:
-            raise ValueError(
-                f"{plane} moves K and V pages; this model caches latent "
-                f"rows ({type(self._cache_spec).__name__})")
+    def _refuse(self, plane, what):
+        """A plane written for K and V pages, asked of a cache whose spec
+        refuses it (latent rows, state slots), says so by name: none may
+        silently fall back."""
+        why = self._cache_spec.refuses(plane)
+        if why:
+            raise ValueError(f"{what} {why}")
+
+    def _paged_views(self, pools, page_table, lengths, live):
+        """Each layer's pool as its own spec's decode view."""
+        return [s.paged(pool, page_table, lengths, live)
+                for s, pool in zip(self._layer_specs, pools)]
+
+    def _ragged_views(self, pools, page_table, kv_lens, cu, row_of,
+                      token_pos, valid):
+        """Each layer's pool as its own spec's packed-stream view."""
+        return [s.ragged(pool, page_table, kv_lens, cu, row_of, token_pos,
+                         valid) for s, pool in zip(self._layer_specs, pools)]
+
+    def _pools_of(self, presents):
+        """The pools back out of the entries a forward returned."""
+        return tuple(s.pool_of(p)
+                     for s, p in zip(self._layer_specs, presents))
 
     def _settle_inflight(self):
         """Read back the in-flight decode block NOW (instead of at the next
@@ -1525,7 +1537,7 @@ class ContinuousBatchingEngine:
         when the request finished while the in-flight block settled —
         nothing left to hand off. Prefill-side only: the host sync here is
         deliberate and NOT part of any decode critical section."""
-        self._refuse_latent("export_pages (the KV handoff plane)")
+        self._refuse("handoff", "export_pages (the KV handoff plane)")
         self._settle_inflight()
         req = self._active.get(slot)
         if req is None or req.finished:
@@ -1567,7 +1579,7 @@ class ContinuousBatchingEngine:
         stream, its tokens — are bit-identical to never having moved.
         Adopted pages are private (never prefix-indexed): their digests
         were validated against the bundle, not against this pool's index."""
-        self._refuse_latent("adopt_request (the KV handoff plane)")
+        self._refuse("handoff", "adopt_request (the KV handoff plane)")
         if not self.free_slots:
             return "deferred"
         if (self._active or self._prefilling) \
@@ -2369,8 +2381,11 @@ class ContinuousBatchingEngine:
         and the admissions since the previous record go on its record."""
         step["cold"] = cold
         step["rows"] = rows
-        if self._latent:  # latent pages in use, of those allocatable
+        if self._cache_spec.log_pages:  # pages in use, of those allocatable
             step["pages"] = (self._pages_in_use, self.num_pages - 1)
+        if self._cache_spec.has_state:  # rows holding a state slot, of all
+            step["slots"] = (len(self._active) + len(self._prefilling),
+                             self.max_seqs)
         step["admits"] = list(self._admits)
         self._admits.clear()
 

@@ -132,14 +132,30 @@ class LatentCacheSpec:
     """What the serving engine asks of a model whose layers cache latent
     rows: how to make the pools, how to view one as a cache entry, and how to
     take the pool back out of the entry a forward returns. The K-and-V twin
-    is ops.paged_attention.KVCacheSpec."""
+    is ops.paged_attention.KVCacheSpec; ops/cache_specs.py states the
+    members the engine reads."""
 
-    latent = True
+    kind = "latent pages"
+    log_pages, has_state = True, False
 
     def __init__(self, num_layers, width):
         self.num_layers, self.width = num_layers, width
 
-    def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None):
+    @property
+    def layers(self):
+        return [self] * self.num_layers
+
+    def refuses(self, plane):
+        """The prefix cache, the handoff plane and the LoRA programs were
+        written for K and V pages."""
+        what = {"prefix_cache": "moves K and V pages",
+                "handoff": "moves K and V pages",
+                "lora": "run copies of the K-and-V programs"}.get(plane)
+        return what and (f"{what}; this model caches latent rows "
+                         f"({type(self).__name__})")
+
+    def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None,
+                   max_seqs=None):
         if kv_cache_dtype not in (None, "model"):
             raise ValueError(
                 f"kv_cache_dtype={kv_cache_dtype!r}: the quantised pool "

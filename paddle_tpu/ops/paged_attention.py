@@ -103,15 +103,26 @@ class KVCacheSpec:
     how to make the pools, how to view one as a cache entry, and how to take
     the pool back out of the entry a forward returns. A pool is the pair
     (k_pages, v_pages). The latent twin is ops.latent_pool.LatentCacheSpec;
-    a model names its own through `serving_cache_spec()`."""
+    a model names its own through `serving_cache_spec()`; one whose layers
+    differ answers with a spec a layer (ops/cache_specs.py, which states
+    the members the engine reads)."""
 
-    latent = False
+    kind = "K/V pages"
+    log_pages = has_state = False
 
     def __init__(self, num_layers, num_kv_heads, head_dim):
         self.num_layers = num_layers
         self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
 
-    def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None):
+    @property
+    def layers(self):
+        return [self] * self.num_layers
+
+    def refuses(self, plane):
+        return None  # every plane of the engine was written for these pools
+
+    def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None,
+                   max_seqs=None):
         shape = (self.num_kv_heads, num_pages, page_size, self.head_dim)
         if kv_cache_dtype == "int8":
             # int8 KV pool (jax paged_attention QuantizedTensor layout):
@@ -314,16 +325,27 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
     """The float pool's kernel (module docstring). `ppb`, pages a block, is
     `_pages_per_block`'s unless a test or a sweep says otherwise. The
     `pallas_call` is named `paged_attention`: once a layer and scan step,
-    under the name the benchmark's reader looks for."""
+    under the name the benchmark's reader looks for.
+
+    A table a row AND K/V head (`page_indices [B, Hkv, n]`, `lengths
+    [B, Hkv]` keys in table order: ops/sparse_paged_attention.py, whose
+    kept blocks differ by K/V head) walks the same grid with one (row, head)
+    pair where a row stood: a page operand then holds its own head alone."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, Hq, D = q.shape
     Hkv, _, bs, _ = k_pages.shape
-    npages = page_indices.shape[1]
     group = Hq // Hkv
     gp = -(-group // _SUBLANES) * _SUBLANES
-    ppb = ppb or _pages_per_block(k_pages, npages)
+    by_head = page_indices.ndim == 3
+    hb = 1 if by_head else Hkv            # K/V heads a page operand holds
+    if by_head:
+        B, lengths = B * Hkv, lengths.reshape(-1)
+        page_indices = page_indices.reshape(B, -1)
+    npages = page_indices.shape[1]
+    ppb = ppb or _pages_per_block(jax.ShapeDtypeStruct(
+        (hb,) + k_pages.shape[1:], k_pages.dtype), npages)
 
     def page_map(pg):
         def index(i, j, rows, lens, pt):
@@ -333,7 +355,8 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
             b, held = rows[i], (lens[rows[i]] + bs - 1) // bs
             last = pg + jnp.maximum(held - 1 - pg, 0) // ppb * ppb
             page = pt[b, jnp.minimum(j * ppb + pg, last)]
-            return (0, jnp.where(pg < held, page, 0), 0, 0)
+            return (b % Hkv if by_head else 0,
+                    jnp.where(pg < held, page, 0), 0, 0)
         return index
 
     def row_map(i, j, rows, lens, pt):
@@ -348,22 +371,22 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
                              idx[None, :], 0), axis=1).astype(jnp.int32)
     n_blocks = jnp.minimum((jnp.max(lengths) + ppb * bs - 1) // (ppb * bs),
                            -(-npages // ppb))
-    qs = (q * scale).astype(k_pages.dtype).reshape(B, Hkv, group, D)
+    qs = (q * scale).astype(k_pages.dtype).reshape(B, hb, group, D)
     qs = jnp.pad(qs, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
-    page_bytes = Hkv * bs * D * k_pages.dtype.itemsize
+    page_bytes = hb * bs * D * k_pages.dtype.itemsize
     fn = pl.pallas_call(
         functools.partial(_decode_kernel, ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(live.sum().astype(jnp.int32), n_blocks),
-            in_specs=[pl.BlockSpec((None, Hkv, gp, D), row_map)]
-            + [pl.BlockSpec((Hkv, None, bs, D), page_map(pg))
+            in_specs=[pl.BlockSpec((None, hb, gp, D), row_map)]
+            + [pl.BlockSpec((hb, None, bs, D), page_map(pg))
                for pg in range(ppb)] * 2,
-            out_specs=pl.BlockSpec((None, Hkv, group, D), row_map),
-            scratch_shapes=[pltpu.VMEM((Hkv, gp, D), jnp.float32),
-                            pltpu.VMEM((Hkv, gp, _LANES), jnp.float32),
-                            pltpu.VMEM((Hkv, gp, _LANES), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), jnp.float32),
+            out_specs=pl.BlockSpec((None, hb, group, D), row_map),
+            scratch_shapes=[pltpu.VMEM((hb, gp, D), jnp.float32),
+                            pltpu.VMEM((hb, gp, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, gp, _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, hb, group, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             # every page operand twice (the pipeline's two buffers), the
@@ -375,8 +398,8 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
     out = fn(rows, lengths, page_indices.astype(jnp.int32), qs,
              *([k_pages] * ppb), *([v_pages] * ppb))
     # the grid never visits a row of length 0: its block is whatever was there
-    return jnp.where(live[:, None, None], out.reshape(B, Hq, D),
-                     0.0).astype(q.dtype)
+    out = jnp.where(live[:, None, None], out.reshape(B, hb * group, D), 0.0)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 #: K (or V) bytes a grid step folds, every KV head of its pages: 8 pages at

@@ -1,8 +1,9 @@
 """The benchmark's manifest and its newest cell, guarded by tier-1:
 `benchmarks/run.py --check` (BENCHMARK.json against the contract's limits
-and against every file it names) and a CPU rehearsal of the
-latent-attention cell through the harness's own entry point (tiny widths,
-3 s window; it prints no result line and measures nothing). The harness's
+and against every file it names) and a CPU rehearsal of each cell of a
+family other than Llama's (latent attention and routed experts; sparse and
+linear attention) through the harness's own entry point (tiny widths, 3 s
+window; it prints no result line and measures nothing). The harness's
 own unit tests stay in benchmarks/tests (run by hand). The cell's runner
 pins one arrival schedule for every seed: that is guarded here too."""
 import os
@@ -10,6 +11,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -24,19 +26,30 @@ def _run(*args, timeout):
 def test_manifest_checks_clean():
     done = _run("--check", timeout=120)
     assert done.returncode == 0, done.stderr[-2000:]
-    assert "0 fault(s), 3 cell(s)" in done.stdout
+    assert "0 fault(s), 4 cell(s)" in done.stdout
 
 
-def test_latent_attention_cell_rehearses(tmp_path):
-    done = _run("--workload", "kimi-k2.7-code-agent-steady", "--seconds", "3",
-                "--trace", "0", "--rehearse", "--seed", "3000000019",
-                "--out", str(tmp_path), timeout=600)
+# cell -> what has to reach its metrics line: the family's counters and the
+# numbers of the comparison that decides `correct`
+REHEARSED = {
+    "kimi-k2.7-code-agent-steady": (
+        "moe.experts_hit_pct", "moe.max_load_ratio", "latent_pool.used_pct",
+        "serve.mfu_pct", "full_forward_rel_rms", "far_share"),
+    "minicpm-sala-longdoc-steady": (
+        "sparse.kept_pct", "state_slots.used_pct", "sala.serve_mfu_pct",
+        "full_forward_rel_rms", "far_share", "blocks_selected_alike",
+        "lightning-xla", "sparse-prefill-xla", "sparse-decode-xla"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(REHEARSED))
+def test_cell_rehearses(cell, tmp_path):
+    done = _run("--workload", cell, "--seconds", "3", "--trace", "0",
+                "--rehearse", "--seed", "3000000019", "--out", str(tmp_path),
+                timeout=600)
     assert done.returncode == 0, (done.stdout[-3000:], done.stderr[-3000:])
     assert "rehearsal passed" in done.stdout
-    # the counters and the comparison reached the metrics line
-    for name in ("moe.experts_hit_pct", "moe.max_load_ratio",
-                 "latent_pool.used_pct", "serve.mfu_pct",
-                 "full_forward_rel_rms", "far_share"):
+    for name in REHEARSED[cell]:
         assert name in done.stdout, name
 
 

@@ -208,7 +208,37 @@ def _moe_gmm(tokens):
     return fn, args
 
 
+# the sparse + linear attention cell's widths
+# (benchmarks/workloads/minicpm-sala-longdoc-steady.json)
+SALA_HKV, SALA_PAGE, SALA_ROWS, SALA_K = 2, 64, 16, 8
+SALA_MAX_LEN, SALA_CHUNK = 50176, 4096
+SALA_NPAGES = SALA_MAX_LEN // SALA_PAGE
+SALA_POOL = (SALA_HKV, 1 + SALA_ROWS * SALA_NPAGES, SALA_PAGE, D)
+
+
+def _sparse_decode():
+    """Decode over the kept pages through ops/sparse_decode_attention.py's
+    own call: the selector over the compressed-key plane, then the paged
+    kernel with a table a row and K/V head (32 query heads over 2)."""
+    from paddle_tpu.ops.sparse_decode_attention import sparse_decode_attention
+    from paddle_tpu.ops.sparse_paged_attention import SparseConfig
+
+    def fn(q, k, v, c, lengths, page_indices):
+        return sparse_decode_attention(q, k, v, c, page_indices, lengths,
+                                       SparseConfig())
+
+    def args(sds):
+        pool = sds(SALA_POOL, jnp.bfloat16)
+        return (sds((SALA_ROWS, H, D), jnp.bfloat16), pool, pool,
+                sds((SALA_HKV, SALA_POOL[1] * 4, D), jnp.bfloat16),
+                sds((SALA_ROWS,), jnp.int32),
+                sds((SALA_ROWS, SALA_NPAGES), jnp.int32))
+
+    return fn, args
+
+
 CASES = {
+    "sparse-decode-by-head": _sparse_decode,
     "mla-decode": _mla_decode,
     "moe-gmm-decode-rows": lambda: _moe_gmm(MLA_ROWS),
     "moe-gmm-mixed-stream": lambda: _moe_gmm(MLA_CHUNK + MLA_ROWS),
@@ -470,3 +500,85 @@ def test_latent_programs_at_the_cells_sizes(one_chip, monkeypatch):
         gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
         stated = raw["compile_memory_gib"][name]
         assert gib < 15.0 and abs(gib - stated) < 0.05, (name, gib, stated)
+
+
+# ---- the sparse + linear attention cell's two programs, at the cell's sizes -
+
+def _abstract_sala():
+    """(model, state shapes, configuration) of the benchmark's
+    minicpm-sala-9b at its held depth, no parameter materialised."""
+    import json
+
+    from benchmarks import sala_model
+    from paddle_tpu.framework import random as prandom
+    from paddle_tpu.models.minicpm_sala import MinicpmSalaForCausalLM
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "minicpm-sala-9b.json")) as f:
+        raw = json.load(f)
+    cfg = sala_model.load_config(raw)
+    made = {}
+
+    def make():
+        with prandom.rng_guard(jax.random.PRNGKey(0)):
+            made["model"] = MinicpmSalaForCausalLM(
+                sala_model.model_config(cfg, SALA_MAX_LEN, "bfloat16"))
+        return made["model"].raw_state_dict()
+
+    return made, jax.eval_shape(make), raw
+
+
+def test_sala_programs_at_the_cells_sizes(one_chip, monkeypatch):
+    """`serve.decode_block` and `serve.ragged` of the real engine over
+    selected K/V pages and state slots, lowered for the described v5e: the
+    paged kernel is in (decode reads a table a K/V head), no copy is shaped
+    like a K/V pool or a layer's state slots, and arguments + temporaries
+    are what the configuration file's `compile_memory_gib` says (under
+    15.0 GiB)."""
+    from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+    from paddle_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    made, state, raw = _abstract_sala()
+    eng = ContinuousBatchingEngine(
+        made["model"], max_seqs=SALA_ROWS, page_size=SALA_PAGE,
+        max_len=SALA_MAX_LEN, prefill_chunk=SALA_CHUNK, decode_block=SALA_K,
+        num_pages=2)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    st = {n: sds(v.shape, v.dtype) for n, v in state.items()}
+    slots = (SALA_ROWS, 32, D, D)
+    kv = (sds(SALA_POOL, jnp.bfloat16), sds(SALA_POOL, jnp.bfloat16),
+          sds((SALA_HKV, SALA_POOL[1] * 4, D), jnp.bfloat16))
+    pools = tuple(kv if kind == "minicpm4" else (sds(slots, jnp.float32),)
+                  for kind in raw["mixer_types"])
+    S, T = SALA_ROWS, SALA_CHUNK + SALA_ROWS
+    i32, greedy = jnp.int32, (False, 1.0, 0, 1.0)
+    table, per_row, keys = (sds((S, SALA_NPAGES), i32), sds((S,), i32),
+                            sds((SALA_K, S, 2), jnp.uint32))
+    lowered = {
+        "decode_block": eng._decode_block_fn(greedy, SALA_K)._jitted.lower(
+            st, sds((S, 1), i32), pools, table, per_row, per_row, keys),
+        "ragged": eng._ragged_fn(greedy)._jitted.lower(
+            st, sds((T,), i32), sds((S + 1,), i32), sds((T,), i32),
+            sds((T,), i32), sds((T,), jnp.bool_), sds((S, 1), jnp.bool_),
+            sds((S, 1), i32), pools, table, table, per_row, per_row, keys),
+    }
+    shapes = [f"bf16[{','.join(map(str, SALA_POOL))}]",
+              f"f32[{','.join(map(str, slots))}]"]
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "%paged_attention" in text, name
+        for shape in shapes:
+            copies = re.findall(rf"= {re.escape(shape)}[^ ]* copy\(", text)
+            assert not copies, f"{name}: {len(copies)} copies of {shape}"
+        ma = compiled.memory_analysis()
+        gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
+        stated = raw["compile_memory_gib"][name]
+        assert abs(gib - stated) < 0.05 and gib < 15.0, (
+            name, gib, stated, ma.argument_size_in_bytes / 2 ** 30,
+            ma.temp_size_in_bytes / 2 ** 30)

@@ -327,12 +327,12 @@ def test_llama_serves_through_the_same_protocol():
     trunk, prefix = model.serving_trunk()
     assert trunk is model.llama and prefix == "llama."
     spec = model.serving_cache_spec()
-    assert not spec.latent
+    assert spec.kind == "K/V pages" and spec.refuses("handoff") is None
     (k, v), = spec.make_pools(3, 16, jnp.float32)[:1]
     assert k.shape == v.shape == (4, 3, 16, 16)
     eng = ContinuousBatchingEngine(model, max_seqs=2, page_size=16,
                                    max_len=64, prefill_chunk=16)
-    assert not eng._latent
+    assert [s.kind for s in eng._layer_specs] == ["K/V pages"] * 2
     ids = np.arange(1, 20, dtype=np.int32)
     out = eng.serve([ids], max_new_tokens=4)[0]
     logits = np.asarray(model(Tensor(jnp.asarray(out[None])))._data[0])
