@@ -451,7 +451,8 @@ class ContinuousBatchingEngine:
         # state slot is the row itself.
         spec = getattr(model, "serving_cache_spec", None)
         self._cache_spec = spec() if spec is not None else KVCacheSpec(
-            cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim)
+            cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim,
+            cfg.num_attention_heads)
         self._layer_specs = self._cache_spec.layers
         self.pools = self._cache_spec.make_pools(
             self.num_pages, page_size, dtype, kv_cache_dtype,
@@ -548,7 +549,11 @@ class ContinuousBatchingEngine:
         self.stats = {"peak_pages": 0, "deferred_admissions": 0,
                       "decode_steps": 0, "prefix_hit_pages": 0,
                       "prefix_evictions": 0, "failed_requests": 0,
-                      "timed_out_requests": 0}
+                      "timed_out_requests": 0,
+                      # grid steps the mixed steps' attention calls walked a
+                      # layer, and the steps of the dense walk (step log's
+                      # `ragged_walk`, summed)
+                      "ragged_walk": (0, 0)}
         # per-serve map rid -> exception for requests that failed in
         # isolation (their results entry is None); the EngineRequest carries
         # the same exception + its rendered string for the online path.
@@ -2076,6 +2081,13 @@ class ContinuousBatchingEngine:
                     grads.append((slot, st))
         cu = np.zeros(S + 1, np.int32)
         cu[1:] = np.cumsum(q_lens)
+        walk = getattr(self._cache_spec, "ragged_walk", None)
+        if walk is not None:  # K/V pages: the ragged kernel's grid, a layer
+            step["ragged_walk"] = walk(self.pools[0], cu, lengths_op + q_lens,
+                                       T, self.pages_per_seq)
+            self.stats["ragged_walk"] = tuple(
+                a + b for a, b in zip(self.stats["ragged_walk"],
+                                      step["ragged_walk"]))
         # non-participant rows (empty slots + still-mid-prefill prompts)
         # route their scan-step writes to the scratch page
         scan_pt = np.where((caps > 0)[:, None], self.page_table, 0)

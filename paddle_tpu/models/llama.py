@@ -801,7 +801,7 @@ class LlamaForCausalLM(GenerationMixin, Layer):
 
         cfg = self.config
         return KVCacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                           cfg.head_dim)
+                           cfg.head_dim, cfg.num_attention_heads)
 
     def num_parameters(self):
         import numpy as np

@@ -22,6 +22,10 @@ The engine reads every spec through the same four members:
     spec.log_pages / spec.has_state   what the step log records beside a
                                       dispatch (pages held; rows with state)
 
+and, where a mixed step's attention walks a grid sized from the spans
+(KVCacheSpec alone), `spec.ragged_walk(pool, cu, kv_lens, n_tokens, npages)`:
+the step log's `ragged_walk`; a spec without it logs none.
+
 Pages stay the allocator's one unit: every paged layer of a model shares the
 row's page table, and a state slot is the row itself.
 """

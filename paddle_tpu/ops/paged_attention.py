@@ -110,9 +110,10 @@ class KVCacheSpec:
     kind = "K/V pages"
     log_pages = has_state = False
 
-    def __init__(self, num_layers, num_kv_heads, head_dim):
+    def __init__(self, num_layers, num_kv_heads, head_dim, num_heads=None):
         self.num_layers = num_layers
         self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+        self.num_heads = num_heads or num_kv_heads
 
     @property
     def layers(self):
@@ -157,6 +158,14 @@ class KVCacheSpec:
     @staticmethod
     def pool_of(present):
         return (present.k_pages, present.v_pages)
+
+    def ragged_walk(self, pool, cu, kv_lens, n_tokens, npages):
+        """(walked, dense) grid steps a layer of a mixed step's attention
+        call over these spans: the step log's `ragged_walk`."""
+        from .ragged_paged_attention import ragged_walk
+
+        return ragged_walk(cu, kv_lens, n_tokens, self.num_heads, pool[0],
+                           npages)
 
 
 def is_quantized(pages):

@@ -19,13 +19,25 @@ step's writes (the query attends to itself), mirroring the `lengths + 1`
 convention of `paged_decode_attention`.
 
 Two tiers, same contract as the decode kernel:
-- `_ragged_pallas`: Pallas grid over (head block, q block, batch row, kv
-  block of several pages); per-row scalar prefetch (`cu_q_lens` /
-  `kv_lens` / page table) drives the masked block walk and the
-  page-indirect BlockSpec index_maps. A step holds one [heads, q block, D]
-  tile and its online-softmax scratch, so the resident set does not grow
-  with T — blocked to what the chip's compiler accepts at 7B widths, not
-  yet tuned. On TPU a failure of this tier raises; `interpret=True`
+- `_ragged_pallas`: a Pallas grid over the call's live work and nothing
+  else, on the pattern of `_paged_pallas`. The work list (`ragged_work`,
+  one small XLA fusion before the call) names the (query block, row) PAIRS
+  whose row has tokens in the block, in stream order, so the pairs of one
+  output block are neighbours; it rides in scalar prefetch beside
+  `cu_q_lens` / `kv_lens` / the page table, and the index maps and the
+  kernel read the block and the row from it. Both grid bounds are
+  OPERANDS: the live pairs, and the kv blocks the longest causal limit
+  among them needs. A pair whose own limit ends earlier skips, its page
+  operands staying on the last page they held (not fetched again). A step
+  folds one kv block of several pages, every KV head at once where VMEM
+  allows, into the online softmax of the block's tokens (q grouped
+  [Hkv, G x tq, D]: MHA and GQA take the one path); the scratch starts on
+  a block's first pair and the output is written on its last. The tiles
+  follow from the shape (`_ragged_tiles`). A query block no live row meets
+  is never visited and reads zero, as every pad token does. On the v5e a
+  mixed call of the serving cell takes 0.08-0.53 ms a layer (0.15 over the
+  cell's prompts) where the dense walk over (head block, q block, row, kv
+  block) took 5.3 whatever it held (PERF.md PR 34). On TPU a failure of this tier raises; `interpret=True`
   off-TPU so CPU tier-1 exercises the real kernel body.
 - `_ragged_math`: lax.scan over page columns with a vectorized per-token
   page gather and online-softmax accumulation — the XLA reference and the
@@ -43,6 +55,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils.envs import env_str as _env_str
 from .paged_attention import _dequantize, is_quantized, store_kv
@@ -219,110 +232,214 @@ def _ragged_math(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
     return out.reshape(T, Hq, D).astype(q.dtype)
 
 
-# Kernel blocking. A grid step holds one q block of _Q_BLOCK tokens for
-# _HEADS_PER_STEP query heads against one kv block of ~_KV_BLOCK positions
-# (several pages), so the resident set is a few MB whatever T, Hq and the
-# page-table width are — the chip's compiler refuses (or never finishes)
-# a kernel that keeps the whole packed [T, Hq, D] stream resident.
-_Q_BLOCK = 128
-_KV_BLOCK = 128
-_HEADS_PER_STEP = 8
 _LANES = 128  # m/l scratch keep a lane-aligned last dim
+
+#: query tokens a grid step holds: a row that straddles n tiles reads its K
+#: and V n times, a one-token row costs a whole tile's products. On the v5e,
+#: over the chat cell's prompts as mixed calls, 64 took 152 us a call, 128
+#: 159 and 32 196; 16 decode rows alone 213, 341 and 162 (PERF.md PR 34)
+_Q_TILE = 64
+#: K (or V) bytes a grid step folds, every KV head of the step, and the
+#: page operands that may take: ops/paged_attention.py's two, for the
+#: reasons given there (8 pages at 32 heads x 16 x 128 bf16, 32 at 8 heads)
+_BLOCK_BYTES = 1 << 20
+_MAX_PAGES = 32
+#: what a step may keep resident; the v5e's VMEM holds 128 MiB
+_VMEM_BUDGET = 48 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class RaggedTiles:
+    """A call's blocking: `tq` query tokens a block, `hb` KV heads a grid
+    step, `ppb` pages a kv block; `vmem` bytes the step keeps resident."""
+
+    tq: int
+    hb: int
+    ppb: int
+    vmem: int
+
+    def grid(self, n_tokens, page_size, npages):
+        """(query blocks of the stream, kv positions a block, kv blocks of
+        a row's table)."""
+        return (-(-n_tokens // self.tq), self.ppb * page_size,
+                -(-npages // self.ppb))
+
+
+def _ragged_tiles(T, Hq, k_pages, npages):
+    """The kernel's tiles from the shape alone (query heads, KV heads, page
+    size, head dim, pool dtype, stream and table width), in the manner of
+    `paged_attention._pages_per_block`: a query tile of `_Q_TILE` tokens,
+    as many pages a kv block as fill `_BLOCK_BYTES` over all KV heads, and
+    every KV head a step unless the step's resident set (blocks twice, the
+    pipeline's two buffers; the gathered K and V; f32 scores, weights and
+    scratch) passes `_VMEM_BUDGET`, then the largest divisor that fits."""
+    kq = is_quantized(k_pages)
+    kw = k_pages.weight if kq else k_pages
+    Hkv, _, bs, D = kw.shape
+    group = Hq // Hkv
+    itemsize = jnp.dtype(kw.dtype).itemsize
+    cbytes = 4 if kq else itemsize  # int8 pages dequantize to f32
+    tq = min(_Q_TILE, -(-T // 8) * 8)
+    ppb = max(1, min(_BLOCK_BYTES // (Hkv * bs * D * itemsize), _MAX_PAGES,
+                     npages))
+    kv_blk = ppb * bs
+
+    def resident(hb):
+        rows = hb * group * tq
+        # an int8 page rides with its scales, a lane tile a row in VMEM
+        page = hb * bs * (D * itemsize + (4 * _LANES if kq else 0))
+        return (4 * rows * D * cbytes + rows * (D + 2 * _LANES) * 4
+                + 4 * ppb * page + 2 * hb * kv_blk * D * cbytes
+                + 3 * rows * kv_blk * 4)
+
+    hb = next(h for h in range(Hkv, 0, -1)
+              if Hkv % h == 0 and (resident(h) <= _VMEM_BUDGET or h == 1))
+    return RaggedTiles(tq, hb, ppb, resident(hb))
+
+
+def ragged_work(cu_q_lens, kv_lens, tq, n_qblocks, kv_blk, xp=jnp):
+    """The kernel's work list from the packed spans, for `jnp` (before the
+    call, one small fusion, no sort) and for numpy (the engine's counter).
+
+    Returns (work [4, n_qblocks + S - 1] int32, n_pairs, n_kv). Pair p, in
+    stream order: work[0, p] its query block, work[1, p] its row, work[2, p]
+    the kv positions its block's last in-row token may see (the pair's
+    causal limit), work[3, p] bit 0 set on a block's first pair and bit 1
+    on its last. A pair is a
+    (query block, row) whose row has tokens in the block: rows are
+    contiguous spans, so row b owns the blocks `cu[b] // tq ..
+    (cu[b+1] - 1) // tq` and there are at most n_qblocks + S - 1 pairs.
+    n_kv is the kv blocks the longest live row needs. Entries past n_pairs
+    repeat the last pair's block and row and are never visited."""
+    cu = cu_q_lens.astype(xp.int32)
+    kvl = kv_lens.astype(xp.int32)
+    S = kvl.shape[0]
+    cu0, cu1 = cu[:-1], cu[1:]
+    live = cu1 > cu0
+    first = cu0 // tq
+    count = xp.where(live, (cu1 - 1) // tq - first + 1, 0)       # [S]
+    ends = xp.cumsum(count)                                      # [S]
+    n_pairs = ends[-1]
+    p = xp.arange(n_qblocks + S - 1, dtype=xp.int32)
+    p_in = xp.minimum(p, xp.maximum(n_pairs - 1, 0))
+    row = xp.minimum(
+        xp.sum(ends[None, :] <= p_in[:, None], axis=1), S - 1
+    ).astype(xp.int32)
+    blk = xp.minimum(first[row] + p_in - (ends[row] - count[row]),
+                     n_qblocks - 1)
+    # the block's last in-row token bounds what any of its tokens may see
+    lim = kvl[row] - xp.maximum(cu1[row] - (blk + 1) * tq, 0)
+    edge = xp.ones((1,), bool)
+    turn = blk[1:] != blk[:-1]
+    is_first = xp.concatenate([edge, turn])
+    is_last = xp.concatenate([turn, edge]) | (p == n_pairs - 1)
+    work = xp.stack([blk, row, lim,
+                     is_first.astype(xp.int32) + 2 * is_last])
+    n_kv = -(-xp.max(xp.where(live, kvl, 0)) // kv_blk)
+    return work.astype(xp.int32), n_pairs, n_kv
+
+
+def ragged_walk(cu_q_lens, kv_lens, n_tokens, n_heads, k_pages, npages):
+    """(walked, dense): the grid steps `_ragged_pallas` walks a layer for
+    these spans, and the steps of the dense (query block x row x kv block)
+    walk at the same tiles. Host arithmetic on the engine's own `cu_q_lens`
+    (numpy); `k_pages` lends its shape and dtype."""
+    tiles = _ragged_tiles(n_tokens, n_heads, k_pages, npages)
+    kw = k_pages.weight if is_quantized(k_pages) else k_pages
+    Hkv, _, bs, _ = kw.shape
+    n_qblocks, kv_blk, nkv = tiles.grid(n_tokens, bs, npages)
+    _, n_pairs, n_kv = ragged_work(
+        np.asarray(cu_q_lens), np.asarray(kv_lens), tiles.tq, n_qblocks,
+        kv_blk, xp=np)
+    n_hb = Hkv // tiles.hb
+    return (int(n_hb * n_pairs * n_kv),
+            n_hb * n_qblocks * len(kv_lens) * nkv)
 
 
 def _ragged_kernel(bs, group, ppb, quantized,
                    # scalar prefetch (order fixed by PrefetchScalarGridSpec)
-                   cu_ref, kvl_ref, pt_ref,
+                   work_ref, cu_ref, kvl_ref, pt_ref,
                    # blocked operands
                    *refs):
-    """Grid (head block h, q block i, batch row b, kv block j); b and j
-    are the reduction axes. A step folds `ppb` pages of row b's KV into
-    the online-softmax scratch of the q block's tokens — tokens outside
-    row b or past their causal limit are masked, and steps whose row
-    misses the q block (or whose pages lie past the row's KV extent) are
-    skipped. Heads lead every operand so both contractions are 3-D
-    batched dots; the accumulators normalize into the output block on the
-    q block's final reduction step."""
+    """Grid (head block, live pair p, kv block j); p and j are the reduction
+    axes. A step folds `ppb` pages of the pair's row into the online-softmax
+    scratch of the pair's query block — tokens outside the row or past their
+    causal limit are masked, and a step past the pair's own limit is
+    skipped. q_ref [hb, G x tq, D]: a KV head's query heads lie one after
+    another along the rows, so both contractions are 3-D batched dots with
+    no K or V repeated. refs: the q block, `ppb` K pages and `ppb` V pages
+    [hb, bs, D] (each followed by its scales [hb, bs, 1] for the int8 pool),
+    then o_ref and the scratch acc [hb, G x tq, D], m and l [.., 128]."""
     import jax.experimental.pallas as pl
 
     q_ref = refs[0]
-    n_in = 1 + ppb * (4 if quantized else 2)
+    per = 2 if quantized else 1
+    n_in = 1 + 2 * ppb * per
     pages = refs[1:n_in]
     o_ref, acc, m, l = refs[n_in:]
-    i, b, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    n, tq, _ = q_ref.shape
+    p, j = pl.program_id(1), pl.program_id(2)
+    tq = q_ref.shape[1] // group
     kv_blk = ppb * bs
+    i, b, lim_max, edge = (work_ref[0, p], work_ref[1, p], work_ref[2, p],
+                           work_ref[3, p])
 
-    @pl.when((b == 0) & (j == 0))
+    @pl.when(((edge & 1) == 1) & (j == 0))
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, -1e30)
         l[...] = jnp.zeros_like(l)
 
-    cu0 = cu_ref[b]
-    cu1 = cu_ref[b + 1]
-    kvl = kvl_ref[b]
-    q_len = cu1 - cu0
-    q_lo = i * tq
-    # the block's last in-row token bounds what any of its tokens may see
-    lim_max = kvl - q_len + (jnp.minimum(cu1, q_lo + tq) - cu0)
-
-    @pl.when((q_len > 0) & (cu0 < q_lo + tq) & (cu1 > q_lo)
-             & (j * kv_blk < lim_max))
+    @pl.when(j * kv_blk < lim_max)
     def _accumulate():
         def load(idx):
-            # ppb pages [hb, bs, D] -> one [n, kv_blk, D] kv block, each kv
-            # head repeated over its query-head group
-            per = 2 if quantized else 1
+            # ppb pages [hb, bs, D] -> one [hb, kv_blk, D] kv block
             blks = []
             for pg in range(ppb):
-                w = pages[(idx * ppb + pg) * per][:, 0]
+                w = pages[(idx * ppb + pg) * per][...]
                 if quantized:
                     # from_int8: w * scales / 127.5 (per-row absmax)
-                    sc = pages[(idx * ppb + pg) * per + 1][:, 0]
+                    sc = pages[(idx * ppb + pg) * per + 1][...]
                     w = (w.astype(jnp.float32)
                          * (sc.astype(jnp.float32) / 127.5))
                 blks.append(w)
-            blk = blks[0] if ppb == 1 else jnp.concatenate(blks, axis=1)
-            if group > 1:
-                hb = blk.shape[0]
-                blk = jnp.broadcast_to(
-                    blk[:, None], (hb, group) + blk.shape[1:]
-                ).reshape((n,) + blk.shape[1:])
-            return blk
+            return blks[0] if ppb == 1 else jnp.concatenate(blks, axis=1)
 
         k_blk, v_blk = load(0), load(1)
-        qs = q_ref[...].astype(k_blk.dtype)
+        cu0, cu1, kvl = cu_ref[b], cu_ref[b + 1], kvl_ref[b]
         s = jax.lax.dot_general(
-            qs, k_blk, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [n, tq, kv_blk]
-        t_ids = q_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-        in_row = (t_ids >= cu0) & (t_ids < cu1)            # [tq, 1]
-        lim = kvl - q_len + (t_ids - cu0) + 1              # [tq, 1]
+            q_ref[...], k_blk, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)        # [hb, G*tq, kv_blk]
+        t_ids = i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        if group > 1:
+            t_ids = jnp.concatenate([t_ids] * group, axis=0)
+        in_row = (t_ids >= cu0) & (t_ids < cu1)            # [G*tq, 1]
+        lim = kvl - cu1 + t_ids + 1                        # [G*tq, 1]
         kv_pos = j * kv_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, kv_blk), 1)
-        mask = (in_row & (kv_pos < lim))[None]             # [1, tq, kv_blk]
+        mask = (in_row & (kv_pos < lim))[None]         # [1, G*tq, kv_blk]
         s = jnp.where(mask, s, -1e30)
-        m_prev = m[:, :, :1]                               # [n, tq, 1]
+        m_prev = m[:, :, :1]                               # [hb, G*tq, 1]
         l_prev = l[:, :, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        w = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m[...] = jnp.broadcast_to(m_new, m.shape)
         l[...] = jnp.broadcast_to(
-            l_prev * corr + p.sum(axis=-1, keepdims=True), l.shape)
+            l_prev * corr + w.sum(axis=-1, keepdims=True), l.shape)
         acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [n, tq, D]
+            w.astype(v_blk.dtype), v_blk, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)            # [hb, G*tq, D]
 
-    @pl.when((b == pl.num_programs(2) - 1) & (j == pl.num_programs(3) - 1))
+    @pl.when((edge >= 2) & (j == pl.num_programs(2) - 1))
     def _finalize():
         o_ref[...] = (acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
                       ).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
-                   scale, interpret):
+                   scale, interpret, tiles=None):
+    """The kernel tier (module docstring). `tiles` is `_ragged_tiles`'s
+    unless a test or a sweep says otherwise."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -332,72 +449,78 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
     Hkv, _, bs, _ = kw.shape
     S, npages = page_indices.shape
     group = Hq // Hkv
+    tiles = tiles or _ragged_tiles(T, Hq, k_pages, npages)
+    tq, hb, ppb = tiles.tq, tiles.hb, tiles.ppb
+    n_qblocks, kv_blk, nkv = tiles.grid(T, bs, npages)
+    t_pad = n_qblocks * tq
 
-    tq = min(_Q_BLOCK, -(-T // 8) * 8)
-    t_pad = -(-T // tq) * tq
-    hb = max(1, min(Hkv, _HEADS_PER_STEP // group))  # kv heads per step
-    while Hkv % hb:
-        hb -= 1
-    n = hb * group
-    ppb = max(1, min(npages, _KV_BLOCK // bs))       # pages per kv block
-    nkv = -(-npages // ppb)
+    cu = cu_q_lens.astype(jnp.int32)
+    work, n_pairs, n_kv = ragged_work(cu, kv_lens, tq, n_qblocks, kv_blk)
+    n_kv = jnp.minimum(n_kv, nkv)  # a length past the table reads no page
 
     def page_map(pg):
-        def index(h, i, b, j, cu, kvl, pt):
-            # a step that will be skipped maps to scratch page 0: a block
-            # index that repeats between steps is not fetched again
-            page = j * ppb + pg
-            live = ((cu[b + 1] > cu[b]) & (cu[b] < (i + 1) * tq)
-                    & (cu[b + 1] > i * tq) & (page * bs < kvl[b]))
-            return (h, jnp.where(
-                live, pt[b, jnp.minimum(page, npages - 1)], 0), 0, 0)
+        def index(h, p, j, work, cu, kvl, pt):
+            # past the pair's limit the operand stays on the last page it
+            # held for this pair: a block index that repeats between steps
+            # is not fetched again. (`lax.div`, not `//`: seven layers of
+            # 2 x ppb such maps lower in a second less without the floor's
+            # sign fix-up, and nothing here is negative)
+            held = jax.lax.div(work[2, p] + (bs - 1), bs)
+            last = pg + jax.lax.div(jnp.maximum(held - 1 - pg, 0), ppb) * ppb
+            page = pt[work[1, p], jnp.minimum(j * ppb + pg, last)]
+            return (h, jnp.where(pg < held, page, 0), 0, 0)
         return index
 
-    def q_map(h, i, b, j, cu, kvl, pt):
-        return (h, i, 0)
+    def q_map(h, p, j, work, cu, kvl, pt):
+        return (h, work[0, p], 0, 0)
 
-    q_spec = pl.BlockSpec((n, tq, D), q_map)
-    # heads lead: [Hq, t_pad, D], in the pool's dtype (f32 for int8 pools,
+    q_spec = pl.BlockSpec((hb, None, group * tq, D), q_map)
+    # [Hkv, q block, G x tq, D], in the pool's dtype (f32 for int8 pools,
     # whose pages dequantize to f32 like the math tier's)
-    qs = jnp.swapaxes(q * scale, 0, 1).astype(
-        jnp.float32 if kq else kw.dtype)
-    qs = jnp.pad(qs, ((0, 0), (0, t_pad - T), (0, 0)))
+    qs = (q * scale).astype(jnp.float32 if kq else kw.dtype)
+    qs = jnp.pad(qs, ((0, t_pad - T), (0, 0), (0, 0)))
+    qs = qs.reshape(n_qblocks, tq, Hkv, group, D).transpose(
+        2, 0, 3, 1, 4).reshape(Hkv, n_qblocks, group * tq, D)
 
     in_specs, operands = [q_spec], [qs]
     for pages in (k_pages, v_pages):
         for pg in range(ppb):
-            in_specs.append(pl.BlockSpec((hb, 1, bs, D), page_map(pg)))
+            in_specs.append(pl.BlockSpec((hb, None, bs, D), page_map(pg)))
             if kq:
-                in_specs.append(pl.BlockSpec((hb, 1, bs, 1), page_map(pg)))
+                in_specs.append(pl.BlockSpec((hb, None, bs, 1), page_map(pg)))
                 operands += [pages.weight, pages.scales]
             else:
                 operands.append(pages)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(Hkv // hb, t_pad // tq, S, nkv),
+        num_scalar_prefetch=4,
+        grid=(Hkv // hb, n_pairs, n_kv),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n, tq, D), jnp.float32),       # acc
-            pltpu.VMEM((n, tq, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((n, tq, _LANES), jnp.float32),  # running sum
+            pltpu.VMEM((hb, group * tq, D), jnp.float32),       # acc
+            pltpu.VMEM((hb, group * tq, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((hb, group * tq, _LANES), jnp.float32),  # running sum
         ],
     )
     kernel = functools.partial(_ragged_kernel, bs, group, ppb, kq)
     fn = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hq, t_pad, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=tiles.vmem + (16 << 20)),
         interpret=interpret,
         name="ragged_paged_attention",
     )
-    out = fn(cu_q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
+    out = fn(work, cu, kv_lens.astype(jnp.int32),
              page_indices.astype(jnp.int32), *operands)
-    return jnp.swapaxes(out, 0, 1)[:T]
+    out = out.reshape(Hkv, n_qblocks, group, tq, D).transpose(
+        1, 3, 0, 2, 4).reshape(t_pad, Hq, D)[:T]
+    # the grid never visits a query block no live row meets: its tokens are
+    # whatever the buffer held
+    return jnp.where((jnp.arange(T) < cu[-1])[:, None, None], out, 0)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,

@@ -80,7 +80,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _ragged(quantized):
+def _ragged(quantized, hkv=H):
+    """The ragged kernel through its own call at the serving cell's heads
+    (32 / 32), or at `hkv` KV heads (Mistral's 32 / 8: the rule takes other
+    tiles there)."""
     from jax.experimental.pallas.ops.tpu.paged_attention import (
         quantization_utils as qu,
     )
@@ -94,10 +97,10 @@ def _ragged(quantized):
     def args(sds):
         if quantized:
             pool = qu.QuantizedTensor(
-                weight=sds((H, POOL, PAGE, D), jnp.int8),
-                scales=sds((H, POOL, PAGE, 1), jnp.float32))
+                weight=sds((hkv, POOL, PAGE, D), jnp.int8),
+                scales=sds((hkv, POOL, PAGE, 1), jnp.float32))
         else:
-            pool = sds((H, POOL, PAGE, D), jnp.bfloat16)
+            pool = sds((hkv, POOL, PAGE, D), jnp.bfloat16)
         return (sds((T, H, D), jnp.bfloat16), pool, pool,
                 sds((MAX_SEQS,), jnp.int32),
                 sds((MAX_SEQS, NPAGES), jnp.int32),
@@ -244,6 +247,8 @@ CASES = {
     "moe-gmm-mixed-stream": lambda: _moe_gmm(MLA_CHUNK + MLA_ROWS),
     "ragged-bf16-pool": lambda: _ragged(quantized=False),
     "ragged-int8-pool": lambda: _ragged(quantized=True),
+    "ragged-gqa-kv8-pool": lambda: _ragged(quantized=False, hkv=HKV_GQA),
+    "ragged-gqa-kv8-int8-pool": lambda: _ragged(quantized=True, hkv=HKV_GQA),
     "flash-fwd-s2048": lambda: _flash(grad=False),
     "flash-bwd-s2048": lambda: _flash(grad=True),
     "splash-gqa-kv8": _splash_gqa,

@@ -124,7 +124,7 @@ class TestRaggedKernel:
     ])
     def test_blocked_kernel_matches_math_across_blocks(self, T, S, npages,
                                                        bs):
-        """The kernel's q blocks (128 tokens), head blocks and multi-page
+        """The kernel's q blocks (64 tokens), head blocks and multi-page
         kv blocks must tile a batch larger than any one of them: rows that
         straddle q blocks, GQA groups, a page table that is not a multiple
         of the kv block. Tolerance: two summation orders in f32."""
@@ -153,6 +153,155 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
                                    np.asarray(ref)[:cu[-1]],
                                    rtol=1e-5, atol=1e-5)
+
+    # (T, page size, pages a row, Hq, Hkv, quantized, tiles (tq, hb, ppb),
+    #  rows as (q_len, kv_len) by slot); tiles None = the rule's
+    WALK_CASES = {
+        # one row over two query blocks, and over three (a neighbour each side)
+        "straddles-two-blocks": (64, 8, 12, 4, 4, False, (32, 4, 2),
+                                 [(3, 9), (40, 40), (2, 70)]),
+        "straddles-three-blocks": (96, 8, 12, 4, 4, False, (32, 2, 2),
+                                   [(20, 20), (70, 90), (1, 5)]),
+        "sixteen-one-token-rows": (32, 8, 6, 4, 2, False, (32, 2, 2),
+                                   [(1, 3 * i + 1) for i in range(16)]),
+        "dead-row-between-live": (48, 8, 6, 4, 4, False, (16, 4, 2),
+                                  [(10, 10), (0, 0), (21, 30), (0, 17)]),
+        "trailing-pad-blocks": (128, 8, 6, 4, 4, False, (32, 4, 2),
+                                [(5, 5), (1, 33)]),
+        # kv_len on a page's boundary, on a kv block's, and one past each
+        "kv-on-boundaries": (64, 8, 8, 4, 4, False, (32, 4, 2),
+                             [(1, 8), (1, 9), (1, 16), (1, 17), (4, 32),
+                              (4, 33), (1, 64)]),
+        "prefix-cache-row": (64, 8, 16, 4, 4, False, (32, 4, 4),
+                             [(3, 120), (1, 7), (17, 128)]),
+        "gqa-group-4": (80, 8, 10, 8, 2, False, (32, 2, 2),
+                        [(1, 44), (50, 61), (0, 0), (9, 9)]),
+        "gqa-group-8": (80, 8, 10, 16, 2, False, (32, 1, 4),
+                        [(1, 44), (50, 61), (0, 0), (9, 9)]),
+        "int8-pool": (80, 8, 10, 8, 4, True, (32, 2, 2),
+                      [(1, 44), (50, 61), (0, 0), (9, 9)]),
+        "rule-tiles-gqa": (300, 16, 20, 16, 4, False, None,
+                           [(1, 51), (280, 300), (0, 0), (2, 2)]),
+        "rule-tiles-int8": (140, 16, 9, 8, 8, True, None,
+                            [(130, 130), (1, 144)]),
+        "no-live-row": (32, 8, 4, 4, 4, False, (16, 4, 2),
+                        [(0, 0), (0, 9)]),
+    }
+
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_live_pair_walk_matches_math(self, case):
+        """The kernel's grid over its live (query block, row) pairs against
+        `_ragged_math`, in interpret mode: every token a row owns agrees
+        (two summation orders in f32) and every pad token, in a visited
+        block or not, reads exactly zero."""
+        T, bs, npages, Hq, Hkv, quantized, tiles, rows = self.WALK_CASES[case]
+        rng = np.random.RandomState(len(case) + T)
+        S, D = len(rows), 32
+        P = 1 + S * npages
+        kp = jnp.asarray(rng.randn(Hkv, P, bs, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(Hkv, P, bs, D).astype(np.float32))
+        if quantized:
+            from paddle_tpu.ops.paged_attention import quantize_pages
+
+            kp, vp = quantize_pages(kp), quantize_pages(vp)
+        table = jnp.asarray(rng.permutation(np.arange(1, P))
+                            .reshape(S, npages).astype(np.int32))
+        q_lens = np.array([r[0] for r in rows], np.int32)
+        kv_lens = np.array([r[1] for r in rows], np.int32)
+        assert kv_lens.max() <= npages * bs and q_lens.sum() <= T
+        cu = np.zeros(S + 1, np.int32)
+        cu[1:] = np.cumsum(q_lens)
+        q = jnp.asarray(rng.randn(T, Hq, D).astype(np.float32))
+        args = (q, kp, vp, jnp.asarray(kv_lens), table, jnp.asarray(cu))
+        if tiles is not None:
+            tiles = rpa.RaggedTiles(*tiles, vmem=32 << 20)
+        ref = np.asarray(rpa._ragged_math(*args, D ** -0.5))
+        out = np.asarray(rpa._ragged_pallas(*args, D ** -0.5, interpret=True,
+                                            tiles=tiles))
+        n = int(cu[-1])
+        np.testing.assert_allclose(out[:n], ref[:n], rtol=1e-5, atol=1e-5)
+        assert not out[n:].any()
+
+    @staticmethod
+    def _enumerate_work(cu, kv_lens, tq, n_qblocks, kv_blk):
+        """The work list by a plain enumeration of (query block, row)."""
+        pairs = []
+        for i in range(n_qblocks):
+            for b in range(len(kv_lens)):
+                lo, hi = max(cu[b], i * tq), min(cu[b + 1], (i + 1) * tq)
+                if hi > lo:  # the row has tokens in the block
+                    pairs.append((i, b, kv_lens[b] - (cu[b + 1] - hi)))
+        edges = [(p == 0 or pairs[p - 1][0] != blk)
+                 + 2 * (p == len(pairs) - 1 or pairs[p + 1][0] != blk)
+                 for p, (blk, _, _) in enumerate(pairs)]
+        longest = max([kv for q0, q1, kv in zip(cu, cu[1:], kv_lens)
+                       if q1 > q0], default=0)
+        return pairs, edges, -(-longest // kv_blk)
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_work_list_matches_enumeration(self, seed):
+        """`ragged_work` on random spans: the pairs, their limits and edge
+        bits and both grid bounds are a plain enumeration's, and the numpy
+        call (the engine's counter) agrees with the `jnp` call (the
+        kernel's) entry for entry."""
+        rng = np.random.RandomState(seed)
+        S = int(rng.choice([1, 4, 16]))
+        tq = int(rng.choice([8, 16, 128]))
+        n_qblocks = int(rng.randint(1, 6))
+        T, kv_blk = n_qblocks * tq, int(rng.choice([16, 128]))
+        for _ in range(20):
+            q_lens = np.where(rng.rand(S) < 0.4, 0,
+                              rng.randint(0, 2 * T // S + 2, S))
+            while q_lens.sum() > T:
+                q_lens[rng.randint(S)] //= 2
+            kv_lens = np.where(q_lens > 0, q_lens + rng.randint(0, 300, S),
+                               rng.randint(0, 50, S)).astype(np.int32)
+            cu = np.zeros(S + 1, np.int32)
+            cu[1:] = np.cumsum(q_lens)
+            pairs, edges, n_kv = self._enumerate_work(cu, kv_lens, tq,
+                                                      n_qblocks, kv_blk)
+            assert len(pairs) <= n_qblocks + S - 1
+            work, n_pairs, got_kv = rpa.ragged_work(cu, kv_lens, tq,
+                                                    n_qblocks, kv_blk, xp=np)
+            assert (int(n_pairs), int(got_kv)) == (len(pairs), n_kv)
+            assert work.shape == (4, n_qblocks + S - 1)
+            assert [tuple(w) for w in work[:3, :len(pairs)].T] == pairs
+            assert list(work[3, :len(pairs)]) == edges
+            # what the grid never visits still indexes inside its operands
+            assert (work[0] >= 0).all() and (work[0] < n_qblocks).all()
+            assert (work[1] >= 0).all() and (work[1] < S).all()
+            jw, jn, jk = rpa.ragged_work(jnp.asarray(cu),
+                                         jnp.asarray(kv_lens), tq,
+                                         n_qblocks, kv_blk)
+            np.testing.assert_array_equal(np.asarray(jw), work)
+            assert (int(jn), int(jk)) == (len(pairs), n_kv)
+
+    def test_tiles_follow_the_shape(self):
+        """`_ragged_tiles` at the serving cell's widths and at Mistral's:
+        every KV head a step, pages a block by the bytes a page holds, and
+        fewer heads where the int8 pool's f32 blocks would pass the budget."""
+        import jax
+
+        def pool(hkv, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct((hkv, 2049, 16, 128), dtype)
+
+        mha = rpa._ragged_tiles(528, 32, pool(32), 128)
+        assert (mha.tq, mha.hb, mha.ppb) == (64, 32, 8)
+        gqa = rpa._ragged_tiles(528, 32, pool(8), 128)
+        assert (gqa.tq, gqa.hb, gqa.ppb) == (64, 8, 32)
+        assert rpa._ragged_tiles(20, 32, pool(8), 5).tq == 24
+        assert rpa._ragged_tiles(20, 32, pool(8), 5).ppb == 5
+        for t in (mha, gqa):
+            assert t.vmem <= rpa._VMEM_BUDGET
+        # (walked, dense) at the cell's shape: one 256-token chunk at kv 256
+        # between two decode rows at 350 -> 7 pairs x 3 kv blocks, of 9 query
+        # blocks x 16 rows x 16 kv blocks
+        q_lens = np.zeros(16, np.int32)
+        kv_lens = np.zeros(16, np.int32)
+        q_lens[[2, 5, 9]], kv_lens[[2, 5, 9]] = (1, 256, 1), (350, 256, 350)
+        cu = np.concatenate([[0], np.cumsum(q_lens)])
+        assert rpa.ragged_walk(cu, kv_lens, 528, 32, pool(32), 128) == (
+            21, 9 * 16 * 16)
 
     def test_write_ragged_kv_places_tokens_and_scratches_pads(self):
         rng = np.random.RandomState(1)

@@ -79,6 +79,7 @@ def served(request, model):
     return {"async": request.param, "reqs": reqs, "dispatches": dispatches,
             "records": [r for r in tracing.step_records()
                         if r["engine"] == eng._engine_seq],
+            "stats": dict(eng.stats),
             "tokens_out": registry.get("serve.tokens_out").value - tokens0,
             "tpot": (tpot.count - tpot0[0], tpot.sum - tpot0[1]),
             "new_spans": len(tracing.last_spans(10_000)) - spans0}
@@ -148,6 +149,28 @@ def test_rows_carry_the_kernels_extents(served):
     admits = [a for r in recs for a in r["admits"]]
     assert [(a[0], a[3]) for a in admits] == [
         (q.rid, len(q.prompt)) for q in reqs]
+
+
+def test_mixed_dispatches_log_the_ragged_walk(served):
+    """A mixed dispatch's record carries `ragged_walk = (walked, dense)`,
+    the ragged kernel's grid steps a layer reckoned on the host from the
+    dispatch's own spans, and `engine.stats` sums them. At this size a row's
+    table is one kv block, so the kernel walks one step a (query block, row)
+    pair: a step a row with tokens and one more for each block edge a row
+    straddles, of (query blocks x `max_seqs`) dense."""
+    from paddle_tpu.ops.ragged_paged_attention import _Q_TILE
+
+    recs = served["records"]
+    mixed = [r for r in recs if r["kind"] == "mixed"]
+    assert mixed
+    assert not any("ragged_walk" in r for r in recs if r["kind"] == "decode")
+    n_qblocks = -(-(64 + 4) // _Q_TILE)  # prefill_chunk + max_seqs tokens
+    for r in mixed:
+        walked, dense = r["ragged_walk"]
+        assert dense == n_qblocks * 4, r
+        assert len(r["rows"]) <= walked <= len(r["rows"]) + n_qblocks - 1, r
+    assert served["stats"]["ragged_walk"] == tuple(
+        sum(r["ragged_walk"][i] for r in mixed) for i in (0, 1))
 
 
 def test_ttft_parts_sum_to_the_request_stamps(served):
@@ -226,7 +249,7 @@ def test_tracing_on_fans_the_record_out_as_spans(model):
     assert one["kind"] == "mixed" and one["rows"] and one["emits"]
     # the parent carries the record's counts and none of its stamps
     assert sorted(one) == ["admits", "chained", "cold", "emits", "engine",
-                           "k", "kind", "rows", "step"]
+                           "k", "kind", "ragged_walk", "rows", "step"]
     # a phase lies inside its step's life
     by_step = {s["attrs"]["step"]: s for s in steps}
     for s in spans:
