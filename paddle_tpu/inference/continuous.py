@@ -454,9 +454,12 @@ class ContinuousBatchingEngine:
             cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim,
             cfg.num_attention_heads)
         self._layer_specs = self._cache_spec.layers
+        # token budget for prompt chunks per mixed dispatch (it also sizes
+        # a window layer's ring of pages; every other spec ignores it)
+        self._ragged_chunk = max(int(prefill_chunk or 0) or min(256, max_len), 1)
         self.pools = self._cache_spec.make_pools(
             self.num_pages, page_size, dtype, kv_cache_dtype,
-            max_seqs=max_seqs)
+            max_seqs=max_seqs, prefill_chunk=self._ragged_chunk)
         self.free_pages = list(range(1, self.num_pages))  # page 0 = scratch
         self.free_slots = list(range(max_seqs))
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
@@ -594,8 +597,6 @@ class ContinuousBatchingEngine:
             # pages refuses by name
             self._refuse("prefix_cache",
                          "the prefix cache (enable_prefix_cache)")
-        # token budget for prompt chunks per mixed dispatch
-        self._ragged_chunk = max(self.prefill_chunk or min(256, max_len), 1)
         # packed token-stream width: chunk budget + one feed token per slot
         self._ragged_tokens = self._ragged_chunk + max_seqs
         self._ragged_fns = {}        # sampling -> mixed program
@@ -1138,6 +1139,10 @@ class ContinuousBatchingEngine:
         inner, prefix = model.serving_trunk()
         sampler = _row_sampler(*sampling)
         count = getattr(model, "serving_counters", None)
+        # a model whose upper layers cache nothing (they read a lower
+        # layer's pool) names them its TAIL: the trunk runs on the packed
+        # tokens, the tail on the span ends alone, then the head
+        tail = getattr(model, "serving_tail", None)
         T = self._ragged_tokens
         k = self.decode_block
 
@@ -1165,7 +1170,10 @@ class ContinuousBatchingEngine:
             )
             # each participant samples from its LAST packed token (span end)
             b_idx = jnp.clip(cu[1:] - 1, 0, T - 1)
-            h_b = h._data[0, b_idx]                         # [max_seqs, H]
+            if tail is None:
+                h_b = h._data[0, b_idx]                     # [max_seqs, H]
+            else:
+                h_b = tail(inner_ov, h, b_idx, presents)
             base = model.serving_head(h_b, state)   # [max_seqs, V]
             tok0 = sampler(base, keys[0]).astype(jnp.int32)
             pools1 = self._pools_of(presents)

@@ -155,7 +155,7 @@ class LatentCacheSpec:
                          f"({type(self).__name__})")
 
     def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None,
-                   max_seqs=None):
+                   max_seqs=None, prefill_chunk=None):
         if kv_cache_dtype not in (None, "model"):
             raise ValueError(
                 f"kv_cache_dtype={kv_cache_dtype!r}: the quantised pool "

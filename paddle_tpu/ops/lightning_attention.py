@@ -56,7 +56,8 @@ def decay_slopes(num_heads):
 class StateSlotCache:
     """One layer's state slots seen by a decode step.
 
-    state:   [S, heads, key dim, value dim] float32
+    state:   [S, heads, key dim, value dim] float32 (a layer whose slot is
+             several arrays: the tuple of them, each [S, ...])
     lengths: [S] int32 — the row's tokens BEFORE this step (0: a fresh slot,
              its state reads as zeros)
     live:    [S] bool — the rows a request holds; a dead row's slot is left
@@ -84,44 +85,54 @@ class StateSlotRaggedCache:
 
 
 class StateSlotSpec:
-    """The cache of ONE linear-attention layer, as the serving engine asks
-    for it (ops/cache_specs.py puts a model's layers together): a pool is
-    the one array of state slots. It has no pages, so the planes that share
-    or move pages refuse it by name."""
+    """The cache of ONE recurrent layer, as the serving engine asks for it
+    (ops/cache_specs.py puts a model's layers together): a slot a row, with
+    no length and no pages, so the planes that share or move pages refuse
+    it by name. A slot is ONE array (`StateSlotSpec(heads, key, value)`: a
+    linear-attention layer's state; the views' `state` is that array) or a
+    layer's TUPLE of arrays (`StateSlotSpec(shape, shape, ...,
+    dtypes=...)`: a state-space layer's state and its convolution's last
+    inputs, ops/selective_scan.py; the views' `state` is the tuple). An
+    array is float32 (a running sum) unless its entry of `dtypes` says
+    otherwise; None there is the model's dtype."""
 
     kind = "state slots"
     has_state = True
+    allocator_pages = False
 
-    def __init__(self, num_heads, key_dim, value_dim):
-        self.shape = (num_heads, key_dim, value_dim)
+    def __init__(self, *shapes, dtypes=None):
+        self.one = isinstance(shapes[0], int)
+        self.shapes = [tuple(shapes)] if self.one else [
+            tuple(s) for s in shapes]
+        self.dtypes = list(dtypes or [jnp.float32] * len(self.shapes))
 
     def make_pool(self, num_pages, page_size, dtype, kv_cache_dtype=None,
-                  max_seqs=None):
+                  max_seqs=None, prefill_chunk=None):
         if max_seqs is None:
             raise ValueError("state slots are a row each: make_pools needs "
                              "max_seqs")
-        # float32 whatever the model's dtype: the state is a running sum
-        return (jnp.zeros((max_seqs,) + self.shape, jnp.float32),)
+        return tuple(jnp.zeros((max_seqs,) + shape, d or dtype)
+                     for shape, d in zip(self.shapes, self.dtypes))
 
     def refuses(self, plane):
         if plane in ("prefix_cache", "handoff"):
-            return ("shares or moves a row's pages; a linear-attention "
-                    "layer keeps a state slot a row, which has none "
+            return ("shares or moves a row's pages; a recurrent layer "
+                    "keeps a state slot a row, which has none "
                     f"({type(self).__name__})")
         return None
 
-    @staticmethod
-    def paged(pool, page_table, lengths, live):
-        return StateSlotCache(pool[0], lengths, live)
+    def _state(self, pool):
+        return pool[0] if self.one else tuple(pool)
 
-    @staticmethod
-    def ragged(pool, page_table, kv_lens, cu, row_of, token_pos, valid):
-        return StateSlotRaggedCache(pool[0], kv_lens, cu, row_of, token_pos,
-                                    valid)
+    def paged(self, pool, page_table, lengths, live):
+        return StateSlotCache(self._state(pool), lengths, live)
 
-    @staticmethod
-    def pool_of(present):
-        return (present.state,)
+    def ragged(self, pool, page_table, kv_lens, cu, row_of, token_pos, valid):
+        return StateSlotRaggedCache(self._state(pool), kv_lens, cu, row_of,
+                                    token_pos, valid)
+
+    def pool_of(self, present):
+        return (present.state,) if self.one else tuple(present.state)
 
 
 def lightning_decode(q, k, v, state, lengths, live, slopes, scale=None):

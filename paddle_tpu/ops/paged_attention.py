@@ -109,6 +109,7 @@ class KVCacheSpec:
 
     kind = "K/V pages"
     log_pages = has_state = False
+    allocator_pages = True  # as ONE layer of a ops.cache_specs.LayerCacheSpecs
 
     def __init__(self, num_layers, num_kv_heads, head_dim, num_heads=None):
         self.num_layers = num_layers
@@ -123,7 +124,7 @@ class KVCacheSpec:
         return None  # every plane of the engine was written for these pools
 
     def make_pools(self, num_pages, page_size, dtype, kv_cache_dtype=None,
-                   max_seqs=None):
+                   max_seqs=None, prefill_chunk=None):
         shape = (self.num_kv_heads, num_pages, page_size, self.head_dim)
         if kv_cache_dtype == "int8":
             # int8 KV pool (jax paged_attention QuantizedTensor layout):
@@ -143,6 +144,10 @@ class KVCacheSpec:
             def zero_pool():
                 return jnp.zeros(shape, dtype)
         return [(zero_pool(), zero_pool()) for _ in range(self.num_layers)]
+
+    def make_pool(self, *args, **kw):
+        """This spec as ONE layer of a model whose layers differ."""
+        return self.make_pools(*args, **kw)[0]
 
     @staticmethod
     def paged(pool, page_table, lengths, live):
@@ -166,6 +171,95 @@ class KVCacheSpec:
 
         return ragged_walk(cu, kv_lens, n_tokens, self.num_heads, pool[0],
                            npages)
+
+
+class WindowRingSpec:
+    """The cache of ONE sliding-window attention layer: a fixed RING of
+    pages a row, whatever the row's length. A query sees its last `window`
+    keys, so a row never needs more than the pages that hold them and the
+    chunk being written: `ring_pages = ceil((window + prefill_chunk) /
+    page_size) + 1` (a chunk is written before it attends, so its first
+    query's `window - 1` predecessors and its last token are live together,
+    on at most that many pages). The pool is `1 + max_seqs * ring_pages`
+    pages whatever `num_pages` and `max_len` are; page 0 is the scratch
+    page dead rows write to.
+
+    The ring is a page TABLE of its own, computed from the row and the
+    position and never touched by the allocator: logical page `j` of row
+    `r` is physical page `1 + r * ring_pages + j mod ring_pages`. Writers
+    and kernels take it where they take the row's table, as wide as the
+    row's (the kernels are given `window` and read it at the window's pages
+    alone), so the views are `PagedLayerCache` / `RaggedLayerCache` and a
+    stale key a ring still holds lies either past the row's length or below
+    every live query's window."""
+
+    kind = "window ring"
+    has_state = allocator_pages = False
+
+    def __init__(self, num_kv_heads, head_dim, window):
+        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+        self.window = window
+
+    def ring_pages(self, page_size, prefill_chunk):
+        return -(-(self.window + prefill_chunk) // page_size) + 1
+
+    def make_pool(self, num_pages, page_size, dtype, kv_cache_dtype=None,
+                  max_seqs=None, prefill_chunk=None):
+        if max_seqs is None or prefill_chunk is None:
+            raise ValueError("a window ring is sized by the rows and the "
+                             "prefill chunk: make_pools needs max_seqs and "
+                             "prefill_chunk")
+        if kv_cache_dtype not in (None, "model"):
+            raise ValueError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: the windowed kernels "
+                f"read float pools ({type(self).__name__})")
+        shape = (self.num_kv_heads,
+                 1 + max_seqs * self.ring_pages(page_size, prefill_chunk),
+                 page_size, self.head_dim)
+        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+    def refuses(self, plane):
+        if plane in ("prefix_cache", "handoff"):
+            return ("shares or moves a row's pages; a window layer keeps a "
+                    "ring of pages a row, which are not the allocator's "
+                    f"({type(self).__name__})")
+        return None
+
+    @staticmethod
+    def table(pool, page_table):
+        """The rings as a table as wide as the row's own."""
+        rows, width = page_table.shape
+        ring = (pool[0].shape[1] - 1) // rows
+        return (1 + jnp.arange(rows, dtype=jnp.int32)[:, None] * ring
+                + jnp.arange(width, dtype=jnp.int32)[None, :] % ring)
+
+    @classmethod
+    def paged(cls, pool, page_table, lengths, live):
+        table = jnp.where(live[:, None], cls.table(pool, page_table), 0)
+        return PagedLayerCache(*pool, table, lengths, live)
+
+    @classmethod
+    def ragged(cls, pool, page_table, kv_lens, cu, row_of, token_pos, valid):
+        from .ragged_paged_attention import RaggedLayerCache
+
+        return RaggedLayerCache(*pool, cls.table(pool, page_table), kv_lens,
+                                cu, row_of, token_pos, valid)
+
+    @staticmethod
+    def pool_of(present):
+        return (present.k_pages, present.v_pages)
+
+
+def window_walk(kv_lens, q_lens, window, page_size):
+    """(walked, causal) keys of one windowed layer's attention call, summed
+    over the rows that have a query (`q_lens > 0`; `kv_lens` counts the
+    call's own tokens): the keys on the row's pages from the first one its
+    FIRST query's window touches to its end, and the row's whole length,
+    which a causal walk without a window would read."""
+    first = jnp.maximum(kv_lens - q_lens + 1 - window, 0) // page_size
+    has = q_lens > 0
+    return (jnp.sum(jnp.where(has, kv_lens - first * page_size, 0)),
+            jnp.sum(jnp.where(has, kv_lens, 0)))
 
 
 def is_quantized(pages):
@@ -234,7 +328,8 @@ def write_token_kv(pages, page_indices, lengths, new):
     return store_kv(pages, new, put)
 
 
-def _paged_math(q, k_pages, v_pages, lengths, page_indices, scale):
+def _paged_math(q, k_pages, v_pages, lengths, page_indices, scale,
+                window=None):
     """Masked decode attention over the paged pool; q: [B, Hq, D] (one
     decode token per row). ONE vectorized advanced-index gather pulls
     every row's pages ([B, Hkv, npages*bs, D] slab) and a masked dense
@@ -267,6 +362,9 @@ def _paged_math(q, k_pages, v_pages, lengths, page_indices, scale):
     s = jnp.einsum("bhgd,bhkd->bhgk", qs, ks)  # [B, Hkv, group, M]
     pos = jnp.arange(M)
     seen = pos[None, None, None, :] < lengths[:, None, None, None]
+    if window is not None:  # the last `window` keys, the query's own among them
+        seen &= pos[None, None, None, :] >= (lengths - window)[
+            :, None, None, None]
     s = jnp.where(seen, s, -1e30)
     # a row of length 0 sees nothing: zeros, not the mean of its table
     p = jnp.where(seen, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
@@ -279,21 +377,35 @@ _LANES = 128  # m/l scratch keep a lane-aligned last dim
 _SUBLANES = 8  # q's group rows are padded to whole f32 sublane tiles
 
 
-def _decode_kernel(ppb, row_ref, len_ref, pt_ref, q_ref, *refs):
+def _first_block(length, window, kb):
+    """The first block of `kb` keys that holds one of a row's last `window`
+    keys: a windowed walk starts there, whatever the row's length."""
+    return jax.lax.div(jnp.maximum(length - window, 0), kb)
+
+
+def _decode_kernel(ppb, window, row_ref, len_ref, pt_ref, q_ref, *refs):
     """Grid (i-th live row, block j of ppb pages): fold the block's K and V
     rows, every KV head at once, into row `row_ref[i]`'s online softmax.
     q_ref [Hkv, Gp, D] (the group's rows padded to Gp); refs: ppb K pages
     and ppb V pages [Hkv, bs, D], then o_ref [Hkv, G, D] and the scratch acc
-    [Hkv, Gp, D], m and l [Hkv, Gp, 128]."""
+    [Hkv, Gp, D], m and l [Hkv, Gp, 128]. With a `window` the row's walk
+    starts at the block of its first visible key (`_first_block`)."""
     import jax.experimental.pallas as pl
 
     k_pg, v_pg = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, acc, m, l = refs[2 * ppb:]
-    j = pl.program_id(1)
+    step = j = pl.program_id(1)
     kb = ppb * k_pg[0].shape[1]
     length = len_ref[row_ref[pl.program_id(0)]]
+    if window is not None:
+        j = step + _first_block(length, window, kb)
 
-    @pl.when(j == 0)
+    def seen(pos):
+        if window is None:
+            return pos < length
+        return (pos < length) & (pos >= length - window)
+
+    @pl.when(step == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, -1e30)
@@ -307,10 +419,10 @@ def _decode_kernel(ppb, row_ref, len_ref, pt_ref, q_ref, *refs):
             q_ref[...], k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)                # [Hkv, Gp, kb]
         pos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, kb), 2)
-        s = jnp.where(pos < length, s, -1e30)
+        s = jnp.where(seen(pos), s, -1e30)
         m_prev, l_prev = m[:, :, :1], l[:, :, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.where(pos < length, jnp.exp(s - m_new), 0.0)
+        p = jnp.where(seen(pos), jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m[...] = jnp.broadcast_to(m_new, m.shape)
         l[...] = jnp.broadcast_to(
@@ -322,15 +434,16 @@ def _decode_kernel(ppb, row_ref, len_ref, pt_ref, q_ref, *refs):
             preferred_element_type=jnp.float32)                # [Hkv, Gp, D]
         acc[...] = acc[...] * corr + pv
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(step == pl.num_programs(1) - 1)
     def _finish():
         out = acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
         o_ref[...] = out[:, :o_ref.shape[1]]
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb",
+                                             "window"))
 def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
-                  interpret, ppb=None):
+                  interpret, ppb=None, window=None):
     """The float pool's kernel (module docstring). `ppb`, pages a block, is
     `_pages_per_block`'s unless a test or a sweep says otherwise. The
     `pallas_call` is named `paged_attention`: once a layer and scan step,
@@ -339,7 +452,13 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
     A table a row AND K/V head (`page_indices [B, Hkv, n]`, `lengths
     [B, Hkv]` keys in table order: ops/sparse_paged_attention.py, whose
     kept blocks differ by K/V head) walks the same grid with one (row, head)
-    pair where a row stood: a page operand then holds its own head alone."""
+    pair where a row stood: a page operand then holds its own head alone.
+
+    `window` (a row sees its last `window` keys only): the row's walk starts
+    at the block of its first visible key and the grid's second bound is the
+    most blocks any live row's window spans, so the cost stops growing with
+    the row. The table is read at the row's LOGICAL pages: a ring of pages
+    (`WindowRingSpec`) is a table whose entries repeat."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -362,6 +481,8 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
             # held in this row (the scratch page if it held none): a block
             # index that repeats between steps is not fetched again
             b, held = rows[i], (lens[rows[i]] + bs - 1) // bs
+            if window is not None:
+                j = j + _first_block(lens[rows[i]], window, ppb * bs)
             last = pg + jnp.maximum(held - 1 - pg, 0) // ppb * ppb
             page = pt[b, jnp.minimum(j * ppb + pg, last)]
             return (b % Hkv if by_head else 0,
@@ -380,11 +501,14 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
                              idx[None, :], 0), axis=1).astype(jnp.int32)
     n_blocks = jnp.minimum((jnp.max(lengths) + ppb * bs - 1) // (ppb * bs),
                            -(-npages // ppb))
+    if window is not None:
+        n_blocks = jnp.max((lengths + ppb * bs - 1) // (ppb * bs)
+                           - _first_block(lengths, window, ppb * bs))
     qs = (q * scale).astype(k_pages.dtype).reshape(B, hb, group, D)
     qs = jnp.pad(qs, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     page_bytes = hb * bs * D * k_pages.dtype.itemsize
     fn = pl.pallas_call(
-        functools.partial(_decode_kernel, ppb),
+        functools.partial(_decode_kernel, ppb, window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(live.sum().astype(jnp.int32), n_blocks),
@@ -448,12 +572,16 @@ def _jax_kernel(q, k_pages, v_pages, lengths, page_indices, scale):
 
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
-                           scale=None, impl=None):
+                           scale=None, impl=None, window=None):
     """One-token decode attention over the paged pool.
 
     q: [B, Hq, D]; returns [B, Hq, D]. lengths must already INCLUDE the
     just-written token (the query attends to itself); a row of length 0
-    attends nothing, costs nothing and returns zeros. impl: None/"auto"
+    attends nothing, costs nothing and returns zeros. `scale` is
+    1/sqrt(D) of the STORED width unless given (a pool that stores two
+    heads as one gives its own). `window`: the query sees its last `window`
+    keys, itself among them, and the kernel never reads a block below them
+    (float pools). impl: None/"auto"
     (a kernel on TPU, where its failure raises; the math tier elsewhere),
     "math", "pallas" (the float pool's kernel in interpret mode off TPU)."""
     global LAST_IMPL
@@ -465,15 +593,17 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     on_tpu = _on_tpu() and not _FORCE_XLA
     if impl == "pallas" or (impl == "auto" and on_tpu):
         if is_quantized(k_pages):
-            if not on_tpu:
+            if not on_tpu or window is not None:
                 raise ValueError("the int8 pool's kernel is jax's, which "
-                                 "has no interpret mode off a TPU")
+                                 "has no interpret mode off a TPU and no "
+                                 "window")
             out = _jax_kernel(q, k_pages, v_pages, lengths, page_indices,
                               scale)
         else:
             out = _paged_pallas(q, k_pages, v_pages, lengths, page_indices,
-                                scale, interpret=not on_tpu)
+                                scale, interpret=not on_tpu, window=window)
         LAST_IMPL = "paged-kernel" if on_tpu else "paged-kernel-interpret"
         return out
     LAST_IMPL = "paged-math"
-    return _paged_math(q, k_pages, v_pages, lengths, page_indices, scale)
+    return _paged_math(q, k_pages, v_pages, lengths, page_indices, scale,
+                       window)

@@ -170,7 +170,7 @@ def _ragged_meta(cu_q_lens, row_of, kv_lens):
 
 
 def _ragged_math(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
-                 scale):
+                 scale, window=None):
     """Online-softmax over page columns for a packed ragged batch.
 
     q: [T, Hq, D]. Each scan step gathers ONE page per packed token (a
@@ -209,8 +209,11 @@ def _ragged_math(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
         vb = gather(v_pages, vq, pid)
         s = jnp.einsum("thgd,thkd->thgk", qs, kb)                # [T,Hkv,g,bs]
         pos = j * bs + jnp.arange(bs)
-        s = jnp.where(pos[None, None, None, :] < limit[:, None, None, None],
-                      s, -1e30)
+        seen = pos[None, None, None, :] < limit[:, None, None, None]
+        if window is not None:  # the token's last `window` keys, its own too
+            seen &= pos[None, None, None, :] >= (limit - window)[
+                :, None, None, None]
+        s = jnp.where(seen, s, -1e30)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
@@ -297,7 +300,8 @@ def _ragged_tiles(T, Hq, k_pages, npages):
     return RaggedTiles(tq, hb, ppb, resident(hb))
 
 
-def ragged_work(cu_q_lens, kv_lens, tq, n_qblocks, kv_blk, xp=jnp):
+def ragged_work(cu_q_lens, kv_lens, tq, n_qblocks, kv_blk, xp=jnp,
+                window=None):
     """The kernel's work list from the packed spans, for `jnp` (before the
     call, one small fusion, no sort) and for numpy (the engine's counter).
 
@@ -310,7 +314,12 @@ def ragged_work(cu_q_lens, kv_lens, tq, n_qblocks, kv_blk, xp=jnp):
     contiguous spans, so row b owns the blocks `cu[b] // tq ..
     (cu[b+1] - 1) // tq` and there are at most n_qblocks + S - 1 pairs.
     n_kv is the kv blocks the longest live row needs. Entries past n_pairs
-    repeat the last pair's block and row and are never visited."""
+    repeat the last pair's block and row and are never visited.
+
+    With a `window` (a token sees its last `window` keys, its own among
+    them) the list has a fifth row, work[4, p]: the kv block that holds the
+    lowest key the pair's FIRST in-row token may see, where the pair's walk
+    starts; n_kv is then the most blocks any pair walks from there."""
     cu = cu_q_lens.astype(xp.int32)
     kvl = kv_lens.astype(xp.int32)
     S = kvl.shape[0]
@@ -333,9 +342,15 @@ def ragged_work(cu_q_lens, kv_lens, tq, n_qblocks, kv_blk, xp=jnp):
     turn = blk[1:] != blk[:-1]
     is_first = xp.concatenate([edge, turn])
     is_last = xp.concatenate([turn, edge]) | (p == n_pairs - 1)
-    work = xp.stack([blk, row, lim,
-                     is_first.astype(xp.int32) + 2 * is_last])
+    work = [blk, row, lim, is_first.astype(xp.int32) + 2 * is_last]
+    if window is not None:
+        first_tok = xp.maximum(cu0[row], blk * tq)
+        low = xp.maximum(kvl[row] - cu1[row] + first_tok + 1 - window, 0)
+        work.append(low // kv_blk)
+    work = xp.stack(work)
     n_kv = -(-xp.max(xp.where(live, kvl, 0)) // kv_blk)
+    if window is not None:
+        n_kv = xp.max(xp.where(p < n_pairs, -(-lim // kv_blk) - work[4], 0))
     return work.astype(xp.int32), n_pairs, n_kv
 
 
@@ -356,7 +371,7 @@ def ragged_walk(cu_q_lens, kv_lens, n_tokens, n_heads, k_pages, npages):
             n_hb * n_qblocks * len(kv_lens) * nkv)
 
 
-def _ragged_kernel(bs, group, ppb, quantized,
+def _ragged_kernel(bs, group, ppb, quantized, window,
                    # scalar prefetch (order fixed by PrefetchScalarGridSpec)
                    work_ref, cu_ref, kvl_ref, pt_ref,
                    # blocked operands
@@ -365,7 +380,9 @@ def _ragged_kernel(bs, group, ppb, quantized,
     axes. A step folds `ppb` pages of the pair's row into the online-softmax
     scratch of the pair's query block — tokens outside the row or past their
     causal limit are masked, and a step past the pair's own limit is
-    skipped. q_ref [hb, G x tq, D]: a KV head's query heads lie one after
+    skipped; with a `window` the pair's walk starts at the kv block
+    work_ref[4, p] and keys below a token's window are masked.
+    q_ref [hb, G x tq, D]: a KV head's query heads lie one after
     another along the rows, so both contractions are 3-D batched dots with
     no K or V repeated. refs: the q block, `ppb` K pages and `ppb` V pages
     [hb, bs, D] (each followed by its scales [hb, bs, 1] for the int8 pool),
@@ -377,13 +394,14 @@ def _ragged_kernel(bs, group, ppb, quantized,
     n_in = 1 + 2 * ppb * per
     pages = refs[1:n_in]
     o_ref, acc, m, l = refs[n_in:]
-    p, j = pl.program_id(1), pl.program_id(2)
+    p, step = pl.program_id(1), pl.program_id(2)
+    j = step if window is None else step + work_ref[4, p]
     tq = q_ref.shape[1] // group
     kv_blk = ppb * bs
     i, b, lim_max, edge = (work_ref[0, p], work_ref[1, p], work_ref[2, p],
                            work_ref[3, p])
 
-    @pl.when(((edge & 1) == 1) & (j == 0))
+    @pl.when(((edge & 1) == 1) & (step == 0))
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, -1e30)
@@ -416,7 +434,10 @@ def _ragged_kernel(bs, group, ppb, quantized,
         lim = kvl - cu1 + t_ids + 1                        # [G*tq, 1]
         kv_pos = j * kv_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, kv_blk), 1)
-        mask = (in_row & (kv_pos < lim))[None]         # [1, G*tq, kv_blk]
+        mask = in_row & (kv_pos < lim)
+        if window is not None:
+            mask &= kv_pos >= lim - window
+        mask = mask[None]                              # [1, G*tq, kv_blk]
         s = jnp.where(mask, s, -1e30)
         m_prev = m[:, :, :1]                               # [hb, G*tq, 1]
         l_prev = l[:, :, :1]
@@ -430,14 +451,14 @@ def _ragged_kernel(bs, group, ppb, quantized,
             w.astype(v_blk.dtype), v_blk, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)            # [hb, G*tq, D]
 
-    @pl.when((edge >= 2) & (j == pl.num_programs(2) - 1))
+    @pl.when((edge >= 2) & (step == pl.num_programs(2) - 1))
     def _finalize():
         o_ref[...] = (acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
                       ).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
-                   scale, interpret, tiles=None):
+                   scale, interpret, tiles=None, window=None):
     """The kernel tier (module docstring). `tiles` is `_ragged_tiles`'s
     unless a test or a sweep says otherwise."""
     import jax.experimental.pallas as pl
@@ -455,7 +476,8 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
     t_pad = n_qblocks * tq
 
     cu = cu_q_lens.astype(jnp.int32)
-    work, n_pairs, n_kv = ragged_work(cu, kv_lens, tq, n_qblocks, kv_blk)
+    work, n_pairs, n_kv = ragged_work(cu, kv_lens, tq, n_qblocks, kv_blk,
+                                      window=window)
     n_kv = jnp.minimum(n_kv, nkv)  # a length past the table reads no page
 
     def page_map(pg):
@@ -466,6 +488,8 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
             # 2 x ppb such maps lower in a second less without the floor's
             # sign fix-up, and nothing here is negative)
             held = jax.lax.div(work[2, p] + (bs - 1), bs)
+            if window is not None:
+                j = j + work[4, p]
             last = pg + jax.lax.div(jnp.maximum(held - 1 - pg, 0), ppb) * ppb
             page = pt[work[1, p], jnp.minimum(j * ppb + pg, last)]
             return (h, jnp.where(pg < held, page, 0), 0, 0)
@@ -503,7 +527,7 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
             pltpu.VMEM((hb, group * tq, _LANES), jnp.float32),  # running sum
         ],
     )
-    kernel = functools.partial(_ragged_kernel, bs, group, ppb, kq)
+    kernel = functools.partial(_ragged_kernel, bs, group, ppb, kq, window)
     fn = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -524,13 +548,16 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices, cu_q_lens,
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,
-                           cu_q_lens, scale=None, impl=None):
+                           cu_q_lens, scale=None, impl=None, window=None):
     """Mixed prefill+decode attention over the paged pool.
 
     q: [T, Hq, D] packed token stream; returns [T, Hq, D]. kv_lens must
     already include this step's tokens (post-write totals). Pad tokens
     (beyond cu_q_lens[-1]) return zeros-ish garbage — callers discard
-    them. impl: None/"auto" (kernel on TPU — a kernel failure there
+    them. `scale` is 1/sqrt(D) of the STORED width unless given. `window`:
+    a token sees its last `window` keys, its own among them, and the kernel
+    never reads a kv block below the window of a (query block, row) pair's
+    first token: the cost of a span stops growing with the row. impl: None/"auto" (kernel on TPU — a kernel failure there
     raises, it never becomes the math tier — and math elsewhere), "math",
     "pallas" (interpret-mode off TPU — the CPU tier-1 path through the
     real kernel body)."""
@@ -543,9 +570,10 @@ def ragged_paged_attention(q, k_pages, v_pages, kv_lens, page_indices,
     on_tpu = _on_tpu() and not _FORCE_XLA
     if impl == "pallas" or (impl == "auto" and on_tpu):
         out = _ragged_pallas(q, k_pages, v_pages, kv_lens, page_indices,
-                             cu_q_lens, scale, interpret=not on_tpu)
+                             cu_q_lens, scale, interpret=not on_tpu,
+                             window=window)
         LAST_IMPL = "ragged-kernel" if on_tpu else "ragged-kernel-interpret"
         return out
     LAST_IMPL = "ragged-math"
     return _ragged_math(q, k_pages, v_pages, kv_lens, page_indices,
-                        cu_q_lens, scale)
+                        cu_q_lens, scale, window)

@@ -124,6 +124,7 @@ class SelectedKVSpec:
     K and V are KVCacheSpec's pools at `page_size == block_size`."""
 
     kind = "selected K/V pages"
+    allocator_pages = True
     has_state = False
 
     def __init__(self, num_kv_heads, head_dim, sparse):
@@ -131,7 +132,7 @@ class SelectedKVSpec:
         self.sparse = sparse
 
     def make_pool(self, num_pages, page_size, dtype, kv_cache_dtype=None,
-                  max_seqs=None):
+                  max_seqs=None, prefill_chunk=None):
         if page_size != self.sparse.block_size:
             raise ValueError(
                 f"page_size {page_size}: a block-selecting layer keeps "

@@ -2,7 +2,8 @@
 `benchmarks/run.py --check` (BENCHMARK.json against the contract's limits
 and against every file it names) and a CPU rehearsal of each cell of a
 family other than Llama's (latent attention and routed experts; sparse and
-linear attention) through the harness's own entry point (tiny widths, 3 s
+linear attention; state-space, window and cross-attention layers over one
+shared K/V pool) through the harness's own entry point (tiny widths, 3 s
 window; it prints no result line and measures nothing). The harness's
 own unit tests stay in benchmarks/tests (run by hand). The cell's runner
 pins one arrival schedule for every seed: that is guarded here too."""
@@ -26,7 +27,7 @@ def _run(*args, timeout):
 def test_manifest_checks_clean():
     done = _run("--check", timeout=120)
     assert done.returncode == 0, done.stderr[-2000:]
-    assert "0 fault(s), 4 cell(s)" in done.stdout
+    assert "0 fault(s), 5 cell(s)" in done.stdout
 
 
 # cell -> what has to reach its metrics line: the family's counters and the
@@ -39,6 +40,11 @@ REHEARSED = {
         "sparse.kept_pct", "state_slots.used_pct", "sala.serve_mfu_pct",
         "full_forward_rel_rms", "far_share", "blocks_selected_alike",
         "lightning-xla", "sparse-prefill-xla", "sparse-decode-xla"),
+    "phi4flash-reasoning-steady": (
+        "swa.keys_visited_pct", "yoco.tail_tok_pct",
+        "phi4flash.serve_mfu_pct", "full_forward_rel_rms", "far_share",
+        "mismatch_share", "first_state_rel_rms", "ragged-kernel-interpret",
+        "ssm-xla"),
 }
 
 

@@ -29,6 +29,12 @@ an ABSTRACT model (4.85 B parameters as shapes): no copy shaped like the
 latent pool in either, and arguments + temporaries as the configuration file
 states them.
 
+The SambaY configuration (benchmarks/configs/phi-4-mini-flash-reasoning) adds
+the two kernels with a window and a scale of the caller's, and the engine's
+two real programs at the cell's sizes from an abstract model (3.85 B
+parameters as shapes): no copy shaped like layer 17's pool, a ring or a slot
+array, and the mixed program's cross-decoder on max_seqs rows.
+
 This is the ONLY test file that describes a topology, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so the
 call must not run while any module is imported (pytest-xdist workers all
@@ -240,7 +246,35 @@ def _sparse_decode():
     return fn, args
 
 
+def _windowed(decode):
+    """Either kernel at the SambaY cell's window layers: 40 padded query
+    heads over 10 stored K/V pairs of 128, pages of 64, a ring table 256
+    wide, window 512 and the published head's scale (1/8)."""
+    from paddle_tpu.ops.paged_attention import paged_decode_attention
+    from paddle_tpu.ops.ragged_paged_attention import _ragged_pallas
+
+    rows, width, pool = 32, 256, (10, 1 + 32 * 25, 64, 128)
+
+    def fn(q, k, v, lens, table, cu):
+        if decode:
+            return paged_decode_attention(q, k, v, lens, table, scale=0.125,
+                                          window=512)
+        return _ragged_pallas(q, k, v, lens, table, cu, 0.125,
+                              interpret=False, window=512)
+
+    def args(sds):
+        return (sds((rows if decode else 1024 + rows, 40, 128),
+                    jnp.bfloat16),
+                sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
+                sds((rows,), jnp.int32), sds((rows, width), jnp.int32),
+                sds((rows + 1,), jnp.int32))
+
+    return fn, args
+
+
 CASES = {
+    "paged-decode-window-512": lambda: _windowed(decode=True),
+    "ragged-window-512": lambda: _windowed(decode=False),
     "sparse-decode-by-head": _sparse_decode,
     "mla-decode": _mla_decode,
     "moe-gmm-decode-rows": lambda: _moe_gmm(MLA_ROWS),
@@ -581,6 +615,113 @@ def test_sala_programs_at_the_cells_sizes(one_chip, monkeypatch):
         for shape in shapes:
             copies = re.findall(rf"= {re.escape(shape)}[^ ]* copy\(", text)
             assert not copies, f"{name}: {len(copies)} copies of {shape}"
+        ma = compiled.memory_analysis()
+        gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
+        stated = raw["compile_memory_gib"][name]
+        assert abs(gib - stated) < 0.05 and gib < 15.0, (
+            name, gib, stated, ma.argument_size_in_bytes / 2 ** 30,
+            ma.temp_size_in_bytes / 2 ** 30)
+
+
+# ---- the SambaY cell's two programs, at the cell's sizes -------------------
+
+def _abstract_phi4flash():
+    """(model, state shapes, configuration, the cell's engine knobs) of the
+    benchmark's phi-4-mini-flash-reasoning, no parameter materialised."""
+    import json
+
+    from benchmarks import phi4flash_model
+    from paddle_tpu.framework import random as prandom
+    from paddle_tpu.models.phi4flash import (
+        Phi4FlashConfig, Phi4FlashForCausalLM,
+    )
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def read(*path):
+        with open(os.path.join(here, "benchmarks", *path)) as f:
+            return json.load(f)
+
+    raw = read("configs", "phi-4-mini-flash-reasoning.json")
+    knobs = read("workloads", "phi4flash-reasoning-steady.json")["engine"]
+    cfg = phi4flash_model.load_config(raw)
+    made = {}
+
+    def make():
+        with prandom.rng_guard(jax.random.PRNGKey(0)):
+            made["model"] = Phi4FlashForCausalLM(Phi4FlashConfig(
+                **{k: cfg[k] for k in phi4flash_model.MODEL_KEYS},
+                max_position_embeddings=knobs["max_len"], dtype="bfloat16"))
+        return made["model"].raw_state_dict()
+
+    return made, jax.eval_shape(make), raw, knobs
+
+
+def test_phi4flash_programs_at_the_cells_sizes(one_chip, monkeypatch):
+    """`serve.decode_block` and `serve.ragged` of the real engine over state
+    slots, window rings, ONE K/V pool and 14 layers with no pool, lowered
+    for the described v5e, whole (32 layers, 3.85 B parameters): both
+    kernels are in, no copy is shaped like layer 17's pool, a ring or a slot
+    array, the mixed program's cross-decoder runs on max_seqs rows, and
+    arguments + temporaries are what the configuration file's
+    `compile_memory_gib` says (under 15.0 GiB)."""
+    from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+    from paddle_tpu.ops import flash_attention
+    from paddle_tpu.ops.cache_specs import LayerCacheSpecs
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    made, state, raw, kn = _abstract_phi4flash()
+    assert sum(v.size for v in state.values()) == 3_852_457_984
+    make_pools = LayerCacheSpecs.make_pools
+    with monkeypatch.context() as mp:   # no 4.9 GB of zeros on this CPU
+        mp.setattr(LayerCacheSpecs, "make_pools",
+                   lambda self, *a, **k: [() for _ in self.layers])
+        eng = ContinuousBatchingEngine(
+            made["model"], **{k: kn[k] for k in (
+                "max_seqs", "page_size", "max_len", "prefill_chunk",
+                "decode_block")})
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, K = kn["max_seqs"], kn["decode_block"]
+    T = kn["prefill_chunk"] + S
+    shapes = jax.eval_shape(lambda: make_pools(
+        eng._cache_spec, eng.num_pages, kn["page_size"], jnp.bfloat16, None,
+        max_seqs=S, prefill_chunk=kn["prefill_chunk"]))
+    ring = -(-(512 + kn["prefill_chunk"]) // kn["page_size"]) + 1
+    assert [tuple(a.shape for a in p) for p in shapes[:2]] == [
+        ((S, 16, 5120), (S, 3, 5120)),
+        ((10, 1 + S * ring, 64, 128),) * 2]
+    assert shapes[17][0].shape == (10, eng.num_pages, 64, 128)
+    assert all(p == () for p in shapes[18:])
+    pools = tuple(tuple(sds(a.shape, a.dtype) for a in p) for p in shapes)
+    st = {n: sds(v.shape, v.dtype) for n, v in state.items()}
+    i32, greedy = jnp.int32, (False, 1.0, 0, 1.0)
+    table, per_row, keys = (sds((S, kn["max_len"] // kn["page_size"]), i32),
+                            sds((S,), i32), sds((K, S, 2), jnp.uint32))
+    lowered = {
+        "decode_block": eng._decode_block_fn(greedy, K)._jitted.lower(
+            st, sds((S, 1), i32), pools, table, per_row, per_row, keys),
+        "ragged": eng._ragged_fn(greedy)._jitted.lower(
+            st, sds((T,), i32), sds((S + 1,), i32), sds((T,), i32),
+            sds((T,), i32), sds((T,), jnp.bool_), sds((S, 1), jnp.bool_),
+            sds((S, 1), i32), pools, table, table, per_row, per_row, keys),
+    }
+    pooled = sorted({
+        f"{'bf16' if a.dtype == jnp.bfloat16 else 'f32'}"
+        f"[{','.join(map(str, a.shape))}]" for p in shapes for a in p})
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "%paged_attention" in text, name
+        assert ("%ragged_paged_attention" in text) == (name == "ragged")
+        for shape in pooled:
+            copies = re.findall(rf"= {re.escape(shape)}[^ ]* copy\(", text)
+            assert not copies, f"{name}: {len(copies)} copies of {shape}"
+        gmu = [line for line in text.splitlines()
+               if "sambay.gmu" in line and " convolution(" in line]
+        assert gmu and all(f"bf16[{S},5120]" in line for line in gmu), name
         ma = compiled.memory_analysis()
         gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
         stated = raw["compile_memory_gib"][name]

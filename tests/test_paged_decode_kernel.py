@@ -134,3 +134,101 @@ def test_int8_pool_has_no_interpret_tier():
         pa.paged_decode_attention(
             q, pa.quantize_pages(kp), pa.quantize_pages(vp),
             jnp.asarray([5, 9], jnp.int32), table, impl="pallas")
+
+
+# ---- a window and a scale of the caller's (models/phi4flash.py) ------------
+
+def _dense_window(q, kp, vp, lens, table, window, scale):
+    """Each row's last `window` keys by a dense softmax, in numpy."""
+    q, kp, vp = (np.asarray(a, np.float32) for a in (q, kp, vp))
+    out = np.zeros_like(q)
+    g = q.shape[1] // kp.shape[0]
+    for b, n in enumerate(lens):
+        if n == 0:
+            continue
+        kd = np.concatenate([kp[:, p] for p in np.asarray(table[b])], axis=1)
+        vd = np.concatenate([vp[:, p] for p in np.asarray(table[b])], axis=1)
+        lo = max(n - window, 0)
+        for h in range(q.shape[1]):
+            s = (q[b, h] @ kd[h // g, lo:n].T) * scale
+            p = np.exp(s - s.max())
+            out[b, h] = (p / p.sum()) @ vd[h // g, lo:n]
+    return out
+
+
+@pytest.mark.parametrize("window, ppb", [(20, None), (20, 1), (33, 2),
+                                         (FULL + 5, None)])
+@pytest.mark.parametrize("heads", ["gqa-g4", "mha-g1"])
+def test_window_and_scale_match_a_dense_window(heads, window, ppb):
+    """Rows shorter than the window, just over it and far over it, one dead:
+    both tiers see each row's last `window` keys alone, at a scale that is
+    not 1/sqrt(D). Blocks of 1 and 2 pages make a row's walk start past
+    block 0."""
+    q, kp, vp, table = _case(heads, "f32", rows=5)
+    lens = [7, window + 1 if window < FULL else FULL, 0, FULL, 50]
+    scale = 0.21
+    want = _dense_window(q, kp, vp, lens, table, window, scale)
+    lens = jnp.asarray(lens, jnp.int32)
+    out = pa._paged_pallas(q, kp, vp, lens, table, scale, interpret=True,
+                           ppb=ppb, window=window)
+    ref = pa.paged_decode_attention(q, kp, vp, lens, table, scale=scale,
+                                    impl="math", window=window)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(ref), want, rtol=2e-5, atol=2e-6)
+
+
+def test_a_windowed_walk_never_reads_below_the_window():
+    """Pages wholly below every row's window hold NaN: a walk that read one
+    would carry it into the softmax (0 x NaN)."""
+    q, kp, vp, table = _case("gqa-g4", "f32", rows=2)
+    window, lens = 20, [FULL, 70]
+    kp, vp = np.array(kp), np.array(vp)
+    for b, n in enumerate(lens):
+        for j in range((n - window) // BS):   # pages wholly below
+            kp[:, table[b, j]] = np.nan
+            vp[:, table[b, j]] = np.nan
+    out = pa._paged_pallas(q, jnp.asarray(kp), jnp.asarray(vp),
+                           jnp.asarray(lens, jnp.int32), table, 0.125,
+                           interpret=True, ppb=1, window=window)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_a_ring_of_pages_is_a_table_whose_entries_repeat():
+    """`WindowRingSpec`: rows that wrote past their ring read the window
+    from the ring's pages; a dead row's table is the scratch page."""
+    spec = pa.WindowRingSpec(2, D, window=20)
+    pool = spec.make_pool(999, BS, jnp.float32, max_seqs=3, prefill_chunk=8)
+    ring = -(-(20 + 8) // BS) + 1
+    assert pool[0].shape == (2, 1 + 3 * ring, BS, D) and ring == 3
+    rng = np.random.RandomState(0)
+    keys = rng.randn(3, 100, 2, D).astype(np.float32)
+    vals = rng.randn(3, 100, 2, D).astype(np.float32)
+    lens = np.array([100, 0, 61], np.int32)
+    live = jnp.asarray(lens > 0)
+    width = jnp.zeros((3, 8), jnp.int32)             # the row's table: 8 wide
+    view = spec.paged(pool, width, jnp.zeros(3, jnp.int32), live)
+    kp, vp = view.k_pages, view.v_pages
+    assert (np.asarray(view.page_indices[1]) == 0).all()
+    for t in range(100):                             # a token a step
+        at = jnp.asarray(np.minimum(t, lens - 1).clip(0), jnp.int32)
+        view = spec.paged((kp, vp), width, at, live & (t < lens))
+        kp = pa.write_token_kv(kp, view.page_indices, at, keys[:, t])
+        vp = pa.write_token_kv(vp, view.page_indices, at, vals[:, t])
+    q = jnp.asarray(rng.randn(3, 8, D).astype(np.float32))
+    view = spec.paged((kp, vp), width, jnp.asarray(lens), live)
+    for impl in ("math", "pallas"):
+        out = pa.paged_decode_attention(
+            q, kp, vp, jnp.asarray(lens), view.page_indices, scale=0.3,
+            impl=impl, window=20)
+        for b, n in enumerate(lens):
+            want = np.zeros((8, D), np.float32)
+            for h in range(8 if n else 0):
+                s = (np.asarray(q)[b, h] @ keys[b, n - 20:n, h // 4].T) * 0.3
+                p = np.exp(s - s.max())
+                want[h] = (p / p.sum()) @ vals[b, n - 20:n, h // 4]
+            np.testing.assert_allclose(np.asarray(out[b]), want, rtol=2e-5,
+                                       atol=2e-6)
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        spec.make_pool(9, BS, jnp.float32, max_seqs=3)
+    with pytest.raises(ValueError, match="float pools"):
+        spec.make_pool(9, BS, jnp.float32, "int8", 3, 8)

@@ -118,6 +118,74 @@ class TestRaggedKernel:
                                    np.asarray(ref)[:cu[-1]],
                                    rtol=0, atol=1e-6)
 
+    @pytest.mark.parametrize("window", [3, 5, 64])
+    def test_window_and_scale_match_a_dense_window(self, window):
+        """A token sees its last `window` keys (its own among them) at the
+        caller's scale, on both tiers: a window under a page, over one, and
+        over every row."""
+        args, raw = _mixed_case(seed=2)
+        kp, vp, page_indices, kv_lens, q_lens, cu, q, bs, Hq, Hkv, D = raw
+        want = np.zeros_like(q)
+        for b in range(len(kv_lens)):
+            kd = np.concatenate([kp[:, p] for p in page_indices[b]], axis=1)
+            vd = np.concatenate([vp[:, p] for p in page_indices[b]], axis=1)
+            for j in range(q_lens[b]):
+                hi = kv_lens[b] - q_lens[b] + j + 1
+                lo = max(hi - window, 0)
+                for h in range(Hq):
+                    s = (q[cu[b] + j, h] @ kd[h // 2, lo:hi].T) * 0.4
+                    p = np.exp(s - s.max())
+                    want[cu[b] + j, h] = (p / p.sum()) @ vd[h // 2, lo:hi]
+        for impl in ("math", "pallas"):
+            out = rpa.ragged_paged_attention(*args, scale=0.4, impl=impl,
+                                             window=window)
+            np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
+                                       want[:cu[-1]], rtol=2e-5, atol=2e-6)
+
+    def test_a_windowed_walk_starts_at_the_pairs_first_block(self):
+        """A long row and a window far shorter: the work list's fifth row
+        names, for each (query block, row) pair, the kv block of its first
+        token's lowest visible key; the grid's kv bound is the most blocks a
+        pair walks from there; kv blocks wholly below hold NaN and are
+        never read."""
+        rng = np.random.RandomState(9)
+        Hq, Hkv, D, bs, npages, window = 4, 2, 16, 8, 40, 24
+        q_lens = np.array([1, 100, 0], np.int32)
+        kv_lens = np.array([300, 260, 0], np.int32)
+        cu = np.zeros(4, np.int32)
+        cu[1:] = np.cumsum(q_lens)
+        T = 104
+        P = 1 + 3 * npages
+        table = np.arange(1, P, dtype=np.int32).reshape(3, npages)
+        kp = rng.randn(Hkv, P, bs, D).astype(np.float32)
+        vp = rng.randn(Hkv, P, bs, D).astype(np.float32)
+        lowest = [300 - window, 160 + 1 - window, 0]   # a row's first query
+        for b in range(2):   # below the kv block (2 pages) of that key
+            for j in range(lowest[b] // 16 * 2):
+                kp[:, table[b, j]] = vp[:, table[b, j]] = np.nan
+        q = jnp.asarray(rng.randn(T, Hq, D).astype(np.float32))
+        tiles = rpa.RaggedTiles(tq=32, hb=Hkv, ppb=2, vmem=1 << 20)
+        work, n_pairs, n_kv = rpa.ragged_work(cu, kv_lens, 32, 4, 16, xp=np,
+                                              window=window)
+        assert work.shape[0] == 5 and n_pairs == 5
+        # pair 0: row 0's one token; row 1's span meets query blocks 0-3
+        assert work[4, 0] == lowest[0] // 16
+        assert work[4, 1] == lowest[1] // 16
+        assert n_kv == max(-(-work[2, p] // 16) - work[4, p] for p in range(5))
+        assert n_kv <= -(-(window + 32) // 16) + 1 < -(-300 // 16)
+        out = rpa._ragged_pallas(q, jnp.asarray(kp), jnp.asarray(vp),
+                                 jnp.asarray(kv_lens), jnp.asarray(table),
+                                 jnp.asarray(cu), 0.25, interpret=True,
+                                 tiles=tiles, window=window)
+        ref = rpa._ragged_math(q, jnp.asarray(np.nan_to_num(kp)),
+                               jnp.asarray(np.nan_to_num(vp)),
+                               jnp.asarray(kv_lens), jnp.asarray(table),
+                               jnp.asarray(cu), 0.25, window)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out)[:101],
+                                   np.asarray(ref)[:101], rtol=2e-5,
+                                   atol=2e-6)
+
     @pytest.mark.parametrize("T,S,npages,bs", [
         (300, 3, 20, 16),   # three q blocks; kv blocks of 8 + 8 + 4 pages
         (40, 2, 9, 8),      # one q block; kv block of 9 pages (odd width)
