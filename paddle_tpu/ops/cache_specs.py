@@ -44,6 +44,19 @@ the row itself, and `num_pages`, the allocator's count and the step log's
 import dataclasses
 
 import jax
+import jax.numpy as jnp
+
+
+def live_rows(live):
+    """The numbers of the rows `live` [B] marks, in order, then zeros, and
+    their count (both int32): what a decode form walks, so that its work
+    grows with the rows that live and not with the slots. No sort (one
+    fusion), as ops/paged_attention.py's kernel finds its grid's rows."""
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    place = jnp.sum(live[None, :] & (idx[None, :] <= idx[:, None]), axis=1) - 1
+    rows = jnp.sum(jnp.where(live[None, :] & (place[None, :] == idx[:, None]),
+                             idx[None, :], 0), axis=1, dtype=jnp.int32)
+    return rows, jnp.sum(live, dtype=jnp.int32)
 
 
 def cache_view(*names):

@@ -19,8 +19,9 @@ a reused slot costs the host nothing, and a dead row (an empty slot, a row
 still in prefill during a mixed step's scan) leaves its slot as it was.
 
 Two forms, as the engine's two cache views ask:
-- `lightning_decode`: one token a row, every row at once (memory-bound: a
-  row's state is read and written once, 4 MiB at 32 heads of 128 x 128).
+- `lightning_decode`: one token a row (memory-bound: a live row's state is
+  read and written once, 4 MiB at 32 heads of 128 x 128, in place; a dead
+  row's is not touched while at most `_WALK_UP_TO` rows live).
 - `lightning_prefill`: the packed stream of a mixed step. Each row's span of
   two or more tokens continues the row's state in chunks of `chunk` tokens:
   inside a chunk the decayed scores `q k^T` ride the MXU, between chunks the
@@ -38,7 +39,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .cache_specs import cache_view
+from .cache_specs import cache_view, live_rows
 
 LAST_IMPL = None  # "lightning-xla" — at trace time
 
@@ -135,6 +136,43 @@ class StateSlotSpec:
         return (present.state,) if self.one else tuple(present.state)
 
 
+#: `lightning_decode` walks its live rows one a trip up to this many of them
+#: and past that updates every slot at once. The number is the sweep's
+#: (`scripts/sala_chip_checks.py sweep` on the v5e, 16 slots of 32 x 128 x
+#: 128; PERF.md section 6, PR 36): the walk costs 17 us and 11.3 us a row
+#: more (95.5 at 8 rows, 107 at 9), every slot at once 105 whatever lives.
+_WALK_UP_TO = 8
+
+
+def _decode_slots(q, k, v, state, lengths, live, lam):
+    """Every slot at once: a dead row's slot is read and written back as it
+    was. q (scaled), k and v are float32."""
+    s0 = jnp.where((lengths > 0)[:, None, None, None], state, 0.0)
+    s1 = lam * s0 + jnp.einsum("bhd,bhe->bhde", k, v)
+    o = jnp.einsum("bhd,bhde->bhe", q, s1, precision=_HI)
+    return (jnp.where(live[:, None, None], o, 0.0),
+            jnp.where(live[:, None, None, None], s1, state))
+
+
+def _decode_live(q, k, v, state, lengths, rows, n, lam):
+    """The live rows alone (`rows[:n]`), one a trip: a row's slot is read,
+    updated and written back in place; a dead row's slot is neither read nor
+    written."""
+    def trip(i, carry):
+        o, st = carry
+        r = rows[i]
+        s0 = jnp.where(lengths[r] > 0,
+                       jax.lax.dynamic_index_in_dim(st, r, 0, False), 0.0)
+        s1 = lam[0] * s0 + jnp.einsum("hd,he->hde", k[r], v[r])
+        o_r = jnp.einsum("hd,hde->he", q[r], s1, precision=_HI)
+        return (jax.lax.dynamic_update_index_in_dim(o, o_r, r, 0),
+                jax.lax.dynamic_update_index_in_dim(st, s1, r, 0))
+
+    return jax.lax.fori_loop(
+        0, n, trip, (jnp.zeros(q.shape[:2] + v.shape[-1:], jnp.float32),
+                     state))
+
+
 def lightning_decode(q, k, v, state, lengths, live, slopes, scale=None):
     """One token a row. q, k, v: [B, H, D*]; state [B, H, Dk, Dv] float32;
     `lengths` the rows' tokens BEFORE this one. Returns (o [B, H, Dv] in
@@ -145,15 +183,14 @@ def lightning_decode(q, k, v, state, lengths, live, slopes, scale=None):
     LAST_IMPL = "lightning-xla"
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     lam = jnp.exp(-slopes)[None, :, None, None]
-    s0 = jnp.where((lengths > 0)[:, None, None, None], state, 0.0)
-    kv = jnp.einsum("bhd,bhe->bhde", k.astype(jnp.float32),
-                    v.astype(jnp.float32))
-    s1 = lam * s0 + kv
-    o = jnp.einsum("bhd,bhde->bhe", q.astype(jnp.float32) * scale, s1,
-                   precision=_HI)
-    alive = live[:, None, None, None]
-    return (jnp.where(live[:, None, None], o, 0.0).astype(q.dtype),
-            jnp.where(alive, s1, state))
+    qkv = (jnp.asarray(q, jnp.float32) * scale, jnp.asarray(k, jnp.float32),
+           jnp.asarray(v, jnp.float32))
+    rows, n = live_rows(live)
+    o, state = jax.lax.cond(
+        n <= _WALK_UP_TO,
+        lambda: _decode_live(*qkv, state, lengths, rows, n, lam),
+        lambda: _decode_slots(*qkv, state, lengths, live, lam))
+    return o.astype(q.dtype), state
 
 
 def lightning_prefill(q, k, v, state, kv_lens, cu_q_lens, slopes, scale=None,

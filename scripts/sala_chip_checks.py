@@ -32,6 +32,18 @@ left its state and keys in, and is compared by the cell's own
                configuration's bf16 weights. Has to fail `check_served`.
                Rounds the model in place: name it last.
 
+`sweep`:       (named alone; no model, no engine) device time of ONE sparse
+               layer's decode (`sparse_decode_attention`: selector and
+               kernel) and ONE lightning layer's decode (`lightning_decode`,
+               the slots donated) at the cell's widths and pool, contexts
+               9k / 17k / 49k, 1, 2, 4, 8 and 16 live rows of 16, scattered
+               over the slots: the median of `--calls` runs of each, read off
+               a profiler trace's `XLA Modules` line. `--tree DIR` imports
+               `paddle_tpu` from another checkout (the parent's, unpacked
+               beside: both ops keep their signatures), so one chip call
+               measures parent and change: the table of PERF.md section 6
+               (PR 36), which set the ops' `_WALK_UP_TO`.
+
 Prints one JSON line a check; exits 0 if every check came out as it has to."""
 import argparse
 import json
@@ -153,14 +165,133 @@ def pages_read(cfg, recs):
     return {"decode_pages_in_tables": kept, "decode_pages_of_rows": seen}
 
 
+SWEEP_CONTEXTS, SWEEP_LIVE = (9216, 17408, 49152), (1, 2, 4, 8, 16)
+
+
+def sweep(args):
+    """The `sweep` check: one JSON line a (op, context, live rows), then the
+    table; also left in chiprun_out/pr36/."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from benchmarks.profiler import WindowTracer
+    from benchmarks.readers.trace import Trace
+    from paddle_tpu.ops.lightning_attention import (
+        decay_slopes, lightning_decode,
+    )
+    from paddle_tpu.ops.sparse_decode_attention import sparse_decode_attention
+    from paddle_tpu.ops.sparse_paged_attention import SparseConfig
+
+    cfg, knobs, _ = _load(args.rehearse)
+    dev = jax.devices()[0]
+    if not args.rehearse and dev.platform != "tpu":
+        raise SystemExit(f"no TPU: {dev}")
+    sp = SparseConfig(**cfg["sparse_config"])
+    B, bs = knobs["max_seqs"], knobs["page_size"]
+    npages = knobs["max_len"] // bs
+    hq, hkv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                  cfg["head_dim"])
+    lh, ld = cfg["lightning_nh"], cfg["lightning_head_dim"]
+    contexts = [c // 256 if args.rehearse else c for c in SWEEP_CONTEXTS]
+    live_counts = [n for n in SWEEP_LIVE if n <= B]
+    key = jax.random.split(jax.random.PRNGKey(args.seed % 2 ** 31), 8)
+    bf = jnp.bfloat16
+    pool = (hkv, B * npages + 1, bs, d)
+    k_pages = jax.random.normal(key[0], pool, bf)
+    v_pages = jax.random.normal(key[1], pool, bf)
+    c_keys = jax.random.normal(key[2], (hkv, pool[1] * sp.per_page, d), bf)
+    q = jax.random.normal(key[3], (B, hq, d), bf)
+    table = 1 + jnp.arange(B * npages, dtype=jnp.int32).reshape(B, npages)
+    lq, lk, lv = (jax.random.normal(k, (B, lh, ld), bf) for k in key[4:7])
+    slopes = decay_slopes(lh)
+
+    def sweep_sparse(q, k_pages, v_pages, c_keys, table, lengths):
+        return sparse_decode_attention(q, k_pages, v_pages, c_keys, table,
+                                       lengths, sp)
+
+    def sweep_lightning(q, k, v, state, lengths, live):
+        return lightning_decode(q, k, v, state, lengths, live, slopes)
+
+    sparse = jax.jit(sweep_sparse)
+    lightning = jax.jit(sweep_lightning, donate_argnums=3)
+
+    def rows_of(n):   # scattered over the slots, as an engine's rows are
+        return (3 + np.arange(n) * B // n) % B
+
+    def lengths_of(n, ctx):
+        out = np.zeros(B, np.int32)
+        out[rows_of(n)] = ctx
+        return jnp.asarray(out)
+
+    state = jax.random.normal(key[7], (B, lh, ld, ld), jnp.float32)
+    cases = ([("sparse", c, n) for c in contexts for n in live_counts]
+             + [("lightning", contexts[0], n) for n in live_counts])
+    lens = lengths_of(B, contexts[0])               # compile both
+    got = sparse(q, k_pages, v_pages, c_keys, table, lens)
+    _, state = lightning(lq, lk, lv, state, lens - 1, lens > 0)
+    jax.block_until_ready((got, state))
+    out_dir = os.path.join(ROOT, "chiprun_out", "pr36")
+    say(check="sweep", tree=args.name, device=dev.device_kind,
+        paddle_tpu=os.path.dirname(paddle_tpu.__file__), cases=len(cases))
+    # the benchmark's own hold on the profiler: on at once, the traced
+    # interval under the annotation its reader clips to
+    tracer = WindowTracer(True, os.path.join(out_dir, "sweep_trace."
+                                             + args.name), 0.0, 0.0)
+    tracer.tick(0.0)
+    for op, c, n in cases:
+        lens = lengths_of(n, c)
+        for _ in range(args.calls):
+            if op == "sparse":
+                got = sparse(q, k_pages, v_pages, c_keys, table, lens)
+            else:
+                got, state = lightning(lq, lk, lv, state,
+                                       jnp.maximum(lens - 1, 0), lens > 0)
+        jax.block_until_ready((got, state))
+    tracer.stop()
+    trace = Trace(tracer.xplane_path())
+    if not trace.devices:   # a rehearsal: the CPU's trace has no device line
+        return say(check="sweep", tree=args.name, cases=len(cases),
+                   device_time="not measured")
+    runs = {"sparse": trace.module_runs("sweep_sparse"),
+            "lightning": trace.module_runs("sweep_lightning")}
+    lines = []
+    for op, c, n in cases:
+        mine, runs[op] = runs[op][:args.calls], runs[op][args.calls:]
+        if len(mine) != args.calls:
+            raise SystemExit(f"the trace holds {len(mine)} runs of {op} at "
+                             f"{c} x {n}, not {args.calls}")
+        lines.append({"check": "sweep", "tree": args.name, "op": op,
+                      "context": c, "live": n,
+                      "us_median": 1e6 * float(np.median(mine)),
+                      "us_min": 1e6 * min(mine), "us_max": 1e6 * max(mine)})
+        say(**lines[-1])
+    with open(os.path.join(out_dir, f"sweep.{args.name}.json"), "w") as f:
+        json.dump({"device": dev.device_kind, "calls": args.calls,
+                   "lines": lines}, f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("checks", nargs="+", choices=sorted(HAS_TO_PASS))
+    ap.add_argument("checks", nargs="+",
+                    choices=sorted(HAS_TO_PASS) + ["sweep"])
     ap.add_argument("--seed", type=int, default=3300000011)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--tree", help="sweep: import paddle_tpu from this "
+                    "checkout (the parent's) instead of the script's own")
+    ap.add_argument("--calls", type=int, default=20,
+                    help="sweep: runs of each case")
     args = ap.parse_args()
     if "fp8" in args.checks[:-1]:
         ap.error("fp8 rounds the model in place: name it last")
+    if "sweep" in args.checks:
+        if args.checks != ["sweep"]:
+            ap.error("sweep runs alone")
+        args.name = "change"
+        if args.tree:     # ".parent" -> "parent"
+            sys.path.insert(0, os.path.abspath(args.tree))
+            args.name = os.path.basename(sys.path[0]).lstrip(".")
+        return sweep(args)
 
     import jax
     import jax.numpy as jnp

@@ -572,9 +572,12 @@ def test_sala_programs_at_the_cells_sizes(one_chip, monkeypatch):
     """`serve.decode_block` and `serve.ragged` of the real engine over
     selected K/V pages and state slots, lowered for the described v5e: the
     paged kernel is in (decode reads a table a K/V head), no copy is shaped
-    like a K/V pool or a layer's state slots, and arguments + temporaries
-    are what the configuration file's `compile_memory_gib` says (under
-    15.0 GiB)."""
+    like a K/V pool or a layer's state slots, no instruction makes every
+    slot's compressed keys at once (the step's selector walks its live rows
+    since PR 36: the all-slots gather was two fusions `bf16[100352,128]`,
+    the second and third ops of the cell's trace), and arguments +
+    temporaries are what the configuration file's `compile_memory_gib` says
+    (under 15.0 GiB)."""
     from paddle_tpu.inference.continuous import ContinuousBatchingEngine
     from paddle_tpu.ops import flash_attention
 
@@ -608,6 +611,9 @@ def test_sala_programs_at_the_cells_sizes(one_chip, monkeypatch):
     }
     shapes = [f"bf16[{','.join(map(str, SALA_POOL))}]",
               f"f32[{','.join(map(str, slots))}]"]
+    keys = SALA_NPAGES * 4            # compressed keys a row's table spans
+    every_slot = [f"bf16[{SALA_ROWS * SALA_HKV * keys},{D}]",
+                  f"bf16[{SALA_ROWS},{SALA_HKV},{keys},{D}]"]
     for name, low in lowered.items():
         compiled = low.compile()
         text = compiled.as_text()
@@ -615,6 +621,9 @@ def test_sala_programs_at_the_cells_sizes(one_chip, monkeypatch):
         for shape in shapes:
             copies = re.findall(rf"= {re.escape(shape)}[^ ]* copy\(", text)
             assert not copies, f"{name}: {len(copies)} copies of {shape}"
+        for shape in every_slot:
+            made = re.findall(rf"= {re.escape(shape)}[^ ]* \w", text)
+            assert not made, f"{name}: {len(made)} results of {shape}"
         ma = compiled.memory_analysis()
         gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2 ** 30
         stated = raw["compile_memory_gib"][name]

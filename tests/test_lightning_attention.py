@@ -117,6 +117,43 @@ def test_decode_zeroes_a_fresh_slot_and_leaves_a_dead_row():
     assert not np.asarray(o[2]).any()
 
 
+LIVE_SETS = {"none": [], "one": [5], "three_scattered": [1, 4, 6],
+             "all": list(range(8))}
+
+
+@pytest.mark.parametrize("form", ["walk", "every_slot"])
+@pytest.mark.parametrize("live_set", sorted(LIVE_SETS))
+def test_decode_walks_live_rows_and_never_touches_a_dead_slot(
+        live_set, form, monkeypatch):
+    """Eight slots, some live (row 4, when live, at length 0 on a slot that
+    held another request's state): a live row equals the token recurrence;
+    a dead row's slot is bit for bit what went in, its output zero, and its
+    q, k and v (NaN here) reach nothing. Both forms of the step (the walk
+    over the live rows, every slot at once past `_WALK_UP_TO` of them)."""
+    monkeypatch.setattr(la, "_WALK_UP_TO", 8 if form == "walk" else -1)
+    rows = LIVE_SETS[live_set]
+    rng = np.random.RandomState(7)
+    mk = lambda *s: rng.randn(*s).astype(np.float32)
+    q, k, v, st = mk(8, H, D), mk(8, H, D), mk(8, H, D), mk(8, H, D, D)
+    live = np.zeros(8, bool)
+    live[rows] = True
+    for a in (q, k, v):
+        a[~live] = np.nan
+    lengths = np.array([3, 9, 0, 1, 0, 12, 40, 2], np.int32)
+    o, new = jax.jit(la.lightning_decode)(
+        *(jnp.asarray(a) for a in (q, k, v, st, lengths, live)), SLOPES)
+    o, new = np.asarray(o), np.asarray(new)
+    for r in range(8):
+        if not live[r]:
+            np.testing.assert_array_equal(new[r], st[r])
+            assert not o[r].any()
+            continue
+        s0 = st[r] if lengths[r] else np.zeros((H, D, D), np.float32)
+        want, s1 = recurrence(q[r:r + 1], k[r:r + 1], v[r:r + 1], s0)
+        np.testing.assert_allclose(o[r], want[0], atol=1e-5)
+        np.testing.assert_allclose(new[r], s1, atol=1e-5)
+
+
 def test_state_slots_have_no_pages_to_share_or_move():
     spec = la.StateSlotSpec(H, D, D)
     (state,) = spec.make_pool(9, 16, jnp.bfloat16, max_seqs=3)

@@ -120,26 +120,35 @@ def test_selector_keeps_the_references_blocks(n, what):
     assert not got[0, :, 1:].any()                # causal: query 0, block 0
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_decode_reads_a_table_a_head_and_equals_the_reference(impl):
-    """Three rows (200 tokens selecting, 50 dense, dead) against the
-    reference's masked attention; `pallas` runs the paged kernel's own body
-    (interpret mode) with a page table a row and K/V head."""
-    lens = [200, 50, 0]
-    seqs = [sequence(10 + i, max(n, 1)) for i, n in enumerate(lens)]
+def rows_in_one_pool(lens, seed):
+    """(pool, page tables [rows, npages], the rows' (q, k, v)): a sequence
+    of `lens[r]` tokens a row (none for 0), each written by `paged` and
+    laid into one pool at the row's own pages."""
+    rows = len(lens)
+    seqs = [sequence(seed + i, max(n, 1)) for i, n in enumerate(lens)]
     npages = -(-max(lens) // BS)
-    pool = spa.SelectedKVSpec(HKV, D, SP).make_pool(1 + 3 * npages, BS,
+    pool = spa.SelectedKVSpec(HKV, D, SP).make_pool(1 + rows * npages, BS,
                                                     jnp.float32)
-    tables = np.zeros((3, npages), np.int32)
+    tables = np.zeros((rows, npages), np.int32)
     for r, (n, (_, k, v)) in enumerate(zip(lens, seqs)):
         if n:
-            (kp, vp, cp), tb = paged(k, v, rows=3, row=r,
+            (kp, vp, cp), tb = paged(k, v, rows=rows, row=r,
                                      extra_pages=npages - -(-n // BS))
             at = np.asarray(tb[r])
             tables[r] = at
             pool = tuple(a.at[:, i].set(b[:, i]) for a, b, i in (
                 (pool[0], kp, at), (pool[1], vp, at),
                 (pool[2], cp, (at[:, None] * 4 + np.arange(4)).reshape(-1))))
+    return pool, tables, seqs
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_reads_a_table_a_head_and_equals_the_reference(impl):
+    """Three rows (200 tokens selecting, 50 dense, dead) against the
+    reference's masked attention; `pallas` runs the paged kernel's own body
+    (interpret mode) with a page table a row and K/V head."""
+    lens = [200, 50, 0]
+    pool, tables, seqs = rows_in_one_pool(lens, 10)
     q = jnp.asarray(np.stack([s[0][-1] for s in seqs]))
     o, kept, seen = sda.sparse_decode_attention(
         q, *pool, jnp.asarray(tables), jnp.asarray(lens, jnp.int32), SP,
@@ -166,6 +175,88 @@ def test_decode_reads_a_table_a_head_and_equals_the_reference(impl):
         np.testing.assert_allclose(np.asarray(o[r]), want.reshape(H, D),
                                    atol=2e-5)
     assert int(kept) == total and int(seen) == sum(lens) * HKV
+
+
+def all_rows_tables(q, c_keys, page_indices, lengths, sp, scale, bs):
+    """The step's selector as it stood before it walked its live rows
+    (PR 33's form, kept here as the reference): every row's compressed keys
+    gathered, scored and sorted at once, dead rows masked afterwards.
+    Returns (table [B, Hkv, width], vlen [B, Hkv])."""
+    B, Hq, D = q.shape
+    Hkv = c_keys.shape[0]
+    npages, per = page_indices.shape[1], sp.per_page
+    t = jnp.maximum(lengths - 1, 0)
+    at = (page_indices[:, :, None] * per
+          + jnp.arange(per)[None, None]).reshape(B, -1)
+    ck = c_keys[jnp.arange(Hkv)[None, :, None], at[:, None]]
+    logits = jnp.einsum("bhgd,bhjd->bhgj", q.reshape(B, Hkv, Hq // Hkv, D),
+                        ck, preferred_element_type=jnp.float32) * scale
+    mask = spa.block_mask(logits, t, sp, npages)
+    mask = mask & (lengths > 0)[:, None, None]
+    m = jnp.arange(npages)
+    order = jnp.argsort(jnp.where(mask, m, m + npages), axis=-1)
+    order = order[..., :sp.table_width(npages)]
+    n_kept = mask.sum(axis=-1).astype(jnp.int32)
+    table = jnp.take_along_axis(
+        jnp.broadcast_to(page_indices[:, None], mask.shape), order, axis=-1)
+    table = jnp.where(jnp.arange(order.shape[-1]) < n_kept[..., None],
+                      table, 0)
+    vlen = jnp.where(n_kept > 0,
+                     (n_kept - 1) * bs + (t % bs + 1)[:, None], 0)
+    return table, vlen
+
+
+#: eight rows: selecting (past dense_len 64), dense (under it), one at the
+#: edge on either side, one of a single token
+ROW_LENS = [200, 1, 50, 130, 64, 65, 97, 16]
+LIVE_SETS = {"none": [], "one": [3], "three_scattered": [0, 4, 6],
+             "all": list(range(8)), "one_token_and_dense": [1, 2, 7]}
+
+
+@pytest.fixture(scope="module")
+def eight_rows():
+    """(pool, page tables, the rows' last queries) of ROW_LENS, each row's
+    K, V and compressed keys written by the ragged writer."""
+    pool, tables, seqs = rows_in_one_pool(ROW_LENS, 40)
+    return pool, jnp.asarray(tables), np.stack([q[-1] for q, _, _ in seqs])
+
+
+@pytest.mark.parametrize("live_set", sorted(LIVE_SETS))
+def test_decode_selector_walks_live_rows_and_keeps_the_same_tables(
+        eight_rows, live_set, monkeypatch):
+    """The step's selector over the live rows alone against the all-rows
+    form: the kept tables and `vlen` identical, the outputs equal, dead rows
+    zero (their queries NaN: never scored), the two counters equal."""
+    pool, tables, qs = eight_rows
+    live = np.zeros(len(ROW_LENS), bool)
+    live[LIVE_SETS[live_set]] = True
+    lengths = jnp.asarray(np.where(live, ROW_LENS, 0), jnp.int32)
+    scale = 1.0 / math.sqrt(D)
+    want_table, want_vlen = all_rows_tables(
+        jnp.asarray(np.where(live[:, None, None], qs, 0.0)), pool[2], tables,
+        lengths, SP, scale, BS)
+    seen_by_kernel = {}
+
+    def spy(q, k_pages, v_pages, table, vlen, scale):
+        seen_by_kernel.update(table=np.asarray(table), vlen=np.asarray(vlen))
+        return decode_xla(q, k_pages, v_pages, table, vlen, scale)
+
+    decode_xla = sda._decode_xla
+    monkeypatch.setattr(sda, "_decode_xla", spy)
+    q = jnp.asarray(np.where(live[:, None, None], qs, np.nan))
+    o, kept, seen = sda.sparse_decode_attention(q, *pool, tables, lengths,
+                                                SP, impl="xla")
+    np.testing.assert_array_equal(seen_by_kernel["table"],
+                                  np.asarray(want_table))
+    np.testing.assert_array_equal(seen_by_kernel["vlen"],
+                                  np.asarray(want_vlen))
+    want_o = decode_xla(jnp.asarray(qs), pool[0], pool[1], want_table,
+                        want_vlen, scale)
+    np.testing.assert_array_equal(np.asarray(o)[live],
+                                  np.asarray(want_o)[live])
+    assert not np.asarray(o)[~live].any()
+    assert int(kept) == int(np.asarray(want_vlen).sum())
+    assert int(seen) == int(lengths.sum()) * HKV
 
 
 def test_packed_prefill_attends_exactly_the_kept_set():
