@@ -7,6 +7,10 @@ imperative user API (Tensor/Layer/Optimizer/AMP/DataLoader), the parallelism
 orchestration (mesh, fleet, TP/PP/ZeRO/SP/EP, auto-parallel), Pallas kernels
 for the hot paths, and the launcher/checkpoint/profiler shell.
 """
+import time as _time
+
+_T_IMPORT0 = _time.monotonic_ns()  # the set-up log's `setup.import` starts here
+
 from . import framework
 from .framework import dtype as _dtype_mod
 from .framework.core import Parameter, Tensor, no_grad, to_tensor
@@ -222,3 +226,7 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
 
 
 __version__ = "0.1.0"
+
+from .observability import tracing as _tracing
+
+_tracing.setup_record("setup.import", _T_IMPORT0, _time.monotonic_ns())

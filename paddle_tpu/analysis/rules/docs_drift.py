@@ -18,6 +18,9 @@ REG_ATTRS = {"counter", "gauge", "histogram", "bump",
              # the step log's spans (ISSUE 25): profiler annotations while
              # the record is built, fanned out as spans when tracing is on
              "annotation", "_phase",
+             # the set-up log's phases (ISSUE 37): always-on records,
+             # fanned out as spans of the same name when tracing is on
+             "setup_phase", "setup_record",
              "child", "event", "begin", "span_at",
              "_class_hist"}
 

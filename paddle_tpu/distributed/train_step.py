@@ -295,45 +295,51 @@ class DistributedTrainStep(TrainStep):
             sig = tuple((tuple(np.shape(b)), str(np.asarray(b).dtype) if not hasattr(b, "dtype") else str(b.dtype)) for b in batch_datas)
         jitted = self._jitted.get(sig)
         first = jitted is None
-        if first:
-            with _tracing.span("train.step.compile_build"):
-                shardings = self._sharding_trees(batch_datas)
-                params_sh, buffers_sh, frozen_sh, opt_sh, scaler_sh, batch_sh = shardings
-                nf_sh = self._nf_sharding()
-                dyn_sh = self._dyn_sharding()
-                jitted = _compilemem.ledgered_jit(
-                    self._step_fn, key="train.step",
-                    in_shardings=(params_sh, buffers_sh, frozen_sh, opt_sh, scaler_sh, nf_sh, dyn_sh, self._ns(P()), self._ns(P()), batch_sh),
-                    out_shardings=(self._ns(P()), params_sh, buffers_sh, opt_sh, scaler_sh, nf_sh, dyn_sh),
-                    donate_argnums=(0, 1, 3, 4, 5, 6),
-                )
-                self._jitted[sig] = jitted
-                _compilemem.ledger.note_cache_size(
-                    "train.step.signatures", len(self._jitted))
-        params = {k: p._data for k, p in self._trainable.items()}
-        buffers = {k: b._data for k, b in self._buffers.items()}
-        frozen = {k: p._data for k, p in self._frozen.items()}
-        lr = self.optimizer.get_lr()
-        # a signature-miss dispatch pays XLA compile: goodput counts it as
-        # init/compile, not step time (the MPMD-scaling paper's
-        # bubble-vs-compute split needs the same discipline)
-        with _tracing.span("train.step.dispatch"), \
-                _goodput.account("init" if first else "step"):
-            with self.mesh:
-                # OOM-forensics seam (ISSUE 8) — same contract as the
-                # single-host TrainStep dispatch
-                try:
-                    chaos.site("obs.oom")
-                    (loss, new_params, new_buffers, self.opt_state,
-                     self._scaler_state, self._nf_state,
-                     self._dyn_state) = jitted(
-                        params, buffers, frozen, self.opt_state,
-                        self._scaler_state, self._nf_state, self._dyn_state,
-                        lr, prandom.next_key(), batch_datas
+        # a signature-miss call builds and compiles: one `train.step.build`
+        # in the set-up log, the sharding trees and the jit wrapper its
+        # child `train.step.compile_build` (a span of that name when
+        # tracing is enabled), the compile the ledger's `compile` under it
+        with self._build_phase() if first else _tracing._NULL:
+            if first:
+                with _tracing.setup_phase("train.step.compile_build"):
+                    shardings = self._sharding_trees(batch_datas)
+                    params_sh, buffers_sh, frozen_sh, opt_sh, scaler_sh, batch_sh = shardings
+                    nf_sh = self._nf_sharding()
+                    dyn_sh = self._dyn_sharding()
+                    jitted = _compilemem.ledgered_jit(
+                        self._step_fn, key="train.step",
+                        in_shardings=(params_sh, buffers_sh, frozen_sh, opt_sh, scaler_sh, nf_sh, dyn_sh, self._ns(P()), self._ns(P()), batch_sh),
+                        out_shardings=(self._ns(P()), params_sh, buffers_sh, opt_sh, scaler_sh, nf_sh, dyn_sh),
+                        donate_argnums=(0, 1, 3, 4, 5, 6),
                     )
-                except Exception as e:
-                    _compilemem.maybe_oom_report(e, program="train.step")
-                    raise
+                    self._jitted[sig] = jitted
+                    _compilemem.ledger.note_cache_size(
+                        "train.step.signatures", len(self._jitted))
+            params = {k: p._data for k, p in self._trainable.items()}
+            buffers = {k: b._data for k, b in self._buffers.items()}
+            frozen = {k: p._data for k, p in self._frozen.items()}
+            lr = self.optimizer.get_lr()
+            # a signature-miss dispatch pays XLA compile: goodput counts it
+            # as init/compile, not step time (the MPMD-scaling paper's
+            # bubble-vs-compute split needs the same discipline)
+            with _tracing.span("train.step.dispatch"), \
+                    _goodput.account("init" if first else "step"):
+                with self.mesh:
+                    # OOM-forensics seam (ISSUE 8) — same contract as the
+                    # single-host TrainStep dispatch
+                    try:
+                        chaos.site("obs.oom")
+                        (loss, new_params, new_buffers, self.opt_state,
+                         self._scaler_state, self._nf_state,
+                         self._dyn_state) = jitted(
+                            params, buffers, frozen, self.opt_state,
+                            self._scaler_state, self._nf_state,
+                            self._dyn_state, lr, prandom.next_key(),
+                            batch_datas
+                        )
+                    except Exception as e:
+                        _compilemem.maybe_oom_report(e, program="train.step")
+                        raise
         for k, v in new_params.items():
             self._trainable[k]._data = v
         for k, v in new_buffers.items():

@@ -50,6 +50,7 @@ device compute instead of ping-ponging between them —
   Tensor state — executing already-compiled programs does not). N
   in-process replicas therefore genuinely run concurrently once warm.
 """
+import functools
 import hashlib
 import itertools
 import math
@@ -400,7 +401,22 @@ class _InflightBlock:
         self.cold = cold    # dispatched under a first-trace (compile) hold
 
 
+def _engine_init_phase(init):
+    """The set-up log's `engine.init` (observability/tracing.py) round the
+    engine's constructor. Not synced: the pools are still being filled when
+    it returns, while the host goes on to trace the step programs."""
+
+    @functools.wraps(init)
+    def phased(self, *args, **kwargs):
+        with _trace.setup_phase("engine.init", synced=False) as phase:
+            init(self, *args, **kwargs)
+            phase.counts["pool_bytes"] = self.pool_bytes()
+
+    return phased
+
+
 class ContinuousBatchingEngine:
+    @_engine_init_phase
     def __init__(self, model, max_seqs=4, page_size=16, num_pages=None,
                  max_len=512, kv_cache_dtype=None, decode_block=8,
                  enable_prefix_cache=False, prefill_chunk=None,
@@ -457,9 +473,10 @@ class ContinuousBatchingEngine:
         # token budget for prompt chunks per mixed dispatch (it also sizes
         # a window layer's ring of pages; every other spec ignores it)
         self._ragged_chunk = max(int(prefill_chunk or 0) or min(256, max_len), 1)
-        self.pools = self._cache_spec.make_pools(
-            self.num_pages, page_size, dtype, kv_cache_dtype,
-            max_seqs=max_seqs, prefill_chunk=self._ragged_chunk)
+        with _trace.setup_phase("engine.init.pools"):
+            self.pools = self._cache_spec.make_pools(
+                self.num_pages, page_size, dtype, kv_cache_dtype,
+                max_seqs=max_seqs, prefill_chunk=self._ragged_chunk)
         self.free_pages = list(range(1, self.num_pages))  # page 0 = scratch
         self.free_slots = list(range(max_seqs))
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
@@ -1389,20 +1406,26 @@ class ContinuousBatchingEngine:
             configs = [tuple(sampling)]
         else:
             configs = [tuple(s) for s in sampling]
-        t_warm0 = time.monotonic()
+        # the set-up log's `engine.warmup` (observability/tracing.py), each
+        # compile a child of the serve that made it. Synced by what it
+        # does: every dummy serve reads its tokens back
+        phase = _trace.setup_phase("engine.warmup", synced=True)
+        compiled0 = _compilemem.ledger.counts()["events"]
         try:
             # ledger trigger scope (ISSUE 8): compiles inside warmup are
             # deliberate AOT work, not cold-path stalls — /compilez and
             # the bench contract separate them by this label
-            with _compilemem.ledger.trigger("warmup"):
+            with phase, _compilemem.ledger.trigger("warmup"):
                 for cfg in configs:
                     self._warmup_serve(*cfg)
                 for rank in lora_ranks:
                     for cfg in configs:
                         self._warmup_serve(*cfg, lora_rank=int(rank))
-            self._publish_scopes()
+                self._publish_scopes()
+                phase.counts["programs"] = (
+                    _compilemem.ledger.counts()["events"] - compiled0)
         finally:
-            _M_WARMUP.observe(time.monotonic() - t_warm0)
+            _M_WARMUP.observe((phase.t1_ns - phase.t0_ns) / 1e9)
 
     def _publish_scopes(self):
         """For a model that names `serving_scopes` (its `jax.named_scope`s
@@ -1413,13 +1436,18 @@ class ContinuousBatchingEngine:
         scopes = getattr(self.model, "serving_scopes", None)
         if not scopes:
             return
-        for key in list(_compilemem.memory.programs()):
-            if key.startswith(("serve.ragged[", "serve.decode_block[")):
-                try:
-                    text = _compilemem.memory.compiled(key).as_text()
-                except KeyError:
-                    continue  # an earlier engine's program, since collected
-                _trace.note_program_scopes(key, text, scopes)
+        with _trace.setup_phase("engine.warmup.scopes", programs=0,
+                                scopes=0) as phase:
+            for key in list(_compilemem.memory.programs()):
+                if key.startswith(("serve.ragged[", "serve.decode_block[")):
+                    try:
+                        text = _compilemem.memory.compiled(key).as_text()
+                    except KeyError:
+                        # an earlier engine's program, since collected
+                        continue
+                    table = _trace.note_program_scopes(key, text, scopes)
+                    phase.counts["programs"] += 1
+                    phase.counts["scopes"] += len(table)
 
     def _warmup_serve(self, do_sample, temperature, top_k, top_p,
                       lora_rank=None):
@@ -1448,7 +1476,8 @@ class ContinuousBatchingEngine:
             fit = min(self.max_len - 1,
                       self._available_pages() * self.page_size - 1)
             n = max(min(self.decode_block + 1, fit), 1)
-            self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
+            with _trace.setup_phase("engine.warmup.serve"):
+                self.serve([np.ones(1, np.int32)], max_new_tokens=n, **kw)
         finally:
             self.enable_prefix_cache = pfx  # lint: shared-mutation-without-lock-ok (engine fields are dispatcher-owned — single-threaded by contract)
             self.stats = stats_before  # lint: shared-mutation-without-lock-ok (same dispatcher-owned contract)

@@ -177,6 +177,9 @@ class TrainStep:
 
     def __init__(self, model, loss_fn, optimizer, n_labels=1, scaler=None, mesh_shardings=None,
                  metrics_bus=None, accumulate_steps=1, nonfinite_guard=None):
+        # the set-up log's `train.step.build` starts here and ends with the
+        # first call (_build_phase)
+        self._t_build0_ns = _time.monotonic_ns()
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -194,7 +197,13 @@ class TrainStep:
             k: p for k, p in dict(model.named_parameters()).items() if p.stop_gradient
         }
         self._buffers = dict(model.named_buffers())
+        t_opt0_ns = _time.monotonic_ns()
         self.opt_state = optimizer.init_state(self._trainable)
+        # not synced: the device fills the slots while the host traces
+        _tracing.setup_record(
+            "train.opt_state", t_opt0_ns, _time.monotonic_ns(),
+            parent="train.step.build", synced=False,
+            opt_state_bytes=_compilemem.tree_nbytes(self.opt_state))
         self._scaler_state = scaler.init_state() if scaler is not None else None
         # non-finite sentinel (ISSUE 9 satellite): an in-program guard
         # skips the optimizer update when loss/grads go NaN/Inf — weights
@@ -392,6 +401,17 @@ class TrainStep:
 
     def _hbm_optimizer_bytes(self):
         return _compilemem.tree_nbytes([self.opt_state, self._scaler_state])
+
+    def _build_phase(self):
+        """The set-up log's `train.step.build` round the first call that
+        compiles: backdated to this step's construction the first time (the
+        optimizer state, `train.opt_state`, and a sharded step's placement
+        lie in between), the call's own start after that. Not synced: the
+        call returns with the first step still running, and whoever reads
+        the loss pays the wait."""
+        t0_ns, self._t_build0_ns = self._t_build0_ns, None
+        return _tracing.setup_phase("train.step.build", t0_ns=t0_ns,
+                                    synced=False)
 
     def _compile(self, step_fn):
         # ONE logical program: recompiles mean the input signature
@@ -605,7 +625,8 @@ class TrainStep:
 
     def __call__(self, *batch):
         first = not self._dispatched
-        with _tracing.span("train.step"), \
+        with (self._build_phase() if first else _tracing._NULL), \
+                _tracing.span("train.step"), \
                 _goodput.account("init" if first else "step"):
             with _tracing.span("train.step.host_prep"):
                 params = {k: p._data for k, p in self._trainable.items()}

@@ -3,7 +3,9 @@
 Each initializer is a callable (shape, dtype) -> jax array drawing from the
 global RNG discipline in framework.random.
 """
+import functools
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +13,7 @@ import numpy as np
 
 from ...framework import random as prandom
 from ...framework.core import Tensor, to_tensor
+from ...observability import tracing as _tracing
 
 
 def _fans(shape):
@@ -24,7 +27,29 @@ def _fans(shape):
     return shape[1] * receptive, shape[0] * receptive
 
 
+def _logged(call):
+    """An initialiser's call as one part of the set-up log's `setup.build`
+    burst: `init_calls` and the time inside it (`init_self_s`; the device
+    may still be filling the array when it returns)."""
+
+    @functools.wraps(call)
+    def logged(self, shape, dtype):
+        with _tracing.setup_phase("setup.build", burst=True) as part:
+            out = call(self, shape, dtype)
+            part.counts["init_calls"] = 1
+            part.counts["init_self_s"] = (
+                time.monotonic_ns() - part.t0_ns) / 1e9
+        return out
+
+    return logged
+
+
 class Initializer:
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "__call__" in cls.__dict__:
+            cls.__call__ = _logged(cls.__dict__["__call__"])
+
     def __call__(self, shape, dtype):
         raise NotImplementedError
 

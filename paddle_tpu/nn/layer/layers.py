@@ -16,6 +16,7 @@ import numpy as np
 
 from ...framework import dtype as dtypes
 from ...framework.core import Parameter, Tensor, to_tensor
+from ...observability import tracing as _tracing
 
 
 class HookRemoveHelper:
@@ -130,8 +131,15 @@ class Layer:
                 learning_rate = getattr(attr, "learning_rate", 1.0)
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
-        data = init(tuple(int(s) for s in shape), dtype)
-        p = Parameter(data, name=name)
+        # the set-up log's `setup.build`: every parameter of a burst of
+        # creation in ONE record (an initialiser called again on a weight
+        # that exists adds to its `init_calls`, not to `params`)
+        with _tracing.setup_phase("setup.build", burst=True,
+                                  synced=False) as part:
+            data = init(tuple(int(s) for s in shape), dtype)
+            p = Parameter(data, name=name)
+            part.counts["params"] = 1
+            part.counts["bytes"] = data.size * data.dtype.itemsize
         p.optimize_attr["learning_rate"] = learning_rate
         return p
 
@@ -315,14 +323,23 @@ class Layer:
         return self
 
     def _to_dtype(self, dt):
-        for layer in self.sublayers(include_self=True):
-            layer._dtype = dt
-            for k, p in layer._parameters.items():
-                if p is not None and dtypes.is_floating_point_dtype(p.dtype):
-                    p._data = p._data.astype(dt)
-            for k, b in layer._buffers.items():
-                if b is not None and dtypes.is_floating_point_dtype(b.dtype):
-                    b._data = b._data.astype(dt)
+        # the set-up log's `setup.cast`. Not synced: each astype returns
+        # before the device has run it, and the host goes on to trace
+        # meanwhile; the wait is paid by the first program that runs
+        with _tracing.setup_phase("setup.cast", synced=False) as phase:
+            n = b_in = b_out = 0
+            for layer in self.sublayers(include_self=True):
+                layer._dtype = dt
+                for held in (layer._parameters, layer._buffers):
+                    for t in held.values():
+                        if t is None or not dtypes.is_floating_point_dtype(
+                                t.dtype):
+                            continue
+                        b_in += t._data.size * t._data.dtype.itemsize
+                        t._data = t._data.astype(dt)
+                        b_out += t._data.size * t._data.dtype.itemsize
+                        n += 1
+            phase.counts.update(arrays=n, bytes_in=b_in, bytes_out=b_out)
 
     def float(self):
         return self.astype(np.float32)

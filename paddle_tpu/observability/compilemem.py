@@ -56,6 +56,7 @@ from collections import OrderedDict, deque
 from contextlib import contextmanager, nullcontext
 
 from ..utils.envs import env_bool, env_int, env_str
+from . import tracing as _tracing
 from .metrics import registry as _registry
 
 __all__ = [
@@ -101,6 +102,113 @@ def compiling_path(directory, rank):
     return os.path.join(directory, f"compiling.{rank}.json")
 
 
+# ---- what jax says of each compile (jax.monitoring) ------------------------
+# jax times a compile's parts itself and publishes them on the compiling
+# thread; the ledger banks them between begin() and end() so that an event
+# says where its wall time went. docs/OBSERVABILITY.md ("set-up log") has
+# which of them fire on a persistent-cache hit and on a miss.
+_TIMED = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_CACHE_TIMED = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+}
+_COUNTED = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+}
+_mon = threading.local()   # .bank: the open bank; .depth: timed parts open
+_listening = False
+
+
+def _new_bank():
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "retrieval_s": 0.0, "cache_requests": 0, "cache_hits": 0}
+
+
+def _bank(field, amount):
+    bank = getattr(_mon, "bank", None)
+    if bank is not None:
+        bank[field] += amount
+    else:
+        # compiled outside a ledger event (an eager op's own program):
+        # counted on whatever set-up phase is open on this thread
+        _tracing.setup_count(**{"jit_" + field: amount})
+
+
+def _on_enter(event, value, **kw):
+    """jax says a timed part STARTED (a scalar of the same name). Parts
+    nest — an inner jit traced inside an outer trace, an eager op compiled
+    while tracing, a helper traced while lowering — and the inner one is
+    timed inside the outer's time, so only the outermost is banked: the
+    three durations of an event are disjoint and never exceed its wall."""
+    if event in _TIMED:
+        _mon.depth = getattr(_mon, "depth", 0) + 1
+
+
+def _on_duration(event, duration_secs, **kw):
+    field = _TIMED.get(event)
+    if field is not None:
+        _mon.depth = depth = max(0, getattr(_mon, "depth", 1) - 1)
+        if depth:
+            return
+    else:
+        field = _CACHE_TIMED.get(event)
+        if field is None or _nested_compile():
+            return
+    _bank(field, float(duration_secs))
+
+
+def _nested_compile():
+    """The persistent cache's events fire INSIDE a backend compile's timed
+    part (depth 1 for a program's own compile). Deeper, they are those of a
+    little program compiled while the event's own was traced or lowered,
+    which jax never caches: not the event's answer."""
+    return getattr(_mon, "depth", 0) > 1
+
+
+def _on_event(event, **kw):
+    field = _COUNTED.get(event)
+    if field is not None and not _nested_compile():
+        _bank(field, 1)
+
+
+def _listen():
+    """Register the listeners, once: called where the ledger first meets
+    jax (ledgered_jit, record_compile), never at import."""
+    global _listening
+    if _listening or "jax" not in sys.modules:
+        return
+    _listening = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_enter)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def _banked_fields(bank, wall_s):
+    """A closed bank as an event's fields. `cache` is the persistent
+    cache's answer: "off" when no compile request went to one (jax counts a
+    request whenever the cache is enabled, directory or not, so the
+    directory is asked for too); `other_s` is what jax did not time: the
+    first execution and the dispatch."""
+    req, hits = bank["cache_requests"], bank["cache_hits"]
+    parts = bank["trace_s"] + bank["lower_s"] + bank["backend_s"]
+    if not req or not sys.modules["jax"].config.jax_compilation_cache_dir:
+        cache = "off"
+    else:
+        cache = "hit" if hits >= req else "miss"
+    return {"trace_s": round(bank["trace_s"], 4),
+            "lower_s": round(bank["lower_s"], 4),
+            "backend_s": round(bank["backend_s"], 4),
+            "cache": cache,
+            "retrieval_s": round(bank["retrieval_s"], 4),
+            "other_s": round(max(0.0, wall_s - parts), 4)}
+
+
 class CompileLedger:
     """Process-wide compile event log + recompile-churn detector.
 
@@ -142,15 +250,26 @@ class CompileLedger:
             self._local.trigger = prev
 
     @contextmanager
-    def suppressed(self):
+    def suppressed(self, key=None):
         """Don't record compiles inside the scope — the memory ledger's
-        re-lowering for analysis must not show up as real recompiles."""
+        re-lowering for analysis must not show up as real recompiles. What
+        jax traced, lowered and compiled inside it is still time spent:
+        it goes to the set-up log as a record named ``lower`` (no ledger
+        event, no count)."""
         prev = getattr(self._local, "suppress", False)
+        prev_bank = getattr(_mon, "bank", None)
         self._local.suppress = True
+        _mon.bank = bank = _new_bank()
+        t0_ns = time.monotonic_ns()
         try:
             yield
         finally:
             self._local.suppress = prev
+            _mon.bank = prev_bank
+            t1_ns = time.monotonic_ns()
+            _tracing.setup_record(
+                "lower", t0_ns, t1_ns, key=None if key is None else str(key),
+                **_banked_fields(bank, (t1_ns - t0_ns) / 1e9))
 
     # ---- the begin/end protocol ------------------------------------------
     def begin(self, key):
@@ -162,6 +281,7 @@ class CompileLedger:
         if depth or getattr(self._local, "suppress", False):
             return None
         tok = next(self._counter)
+        _mon.bank = _new_bank()
         with self._lock:
             self._active[tok] = {"key": str(key), "started_at": time.time(),
                                  "tid": threading.get_ident()}
@@ -180,11 +300,16 @@ class CompileLedger:
         ``None`` token (nested/suppressed begin) is a no-op."""
         if token is None:
             return None
+        t1_ns = time.monotonic_ns()
+        bank, _mon.bank = getattr(_mon, "bank", None) or _new_bank(), None
         with self._lock:
             self._active.pop(token, None)
             _M_ACTIVE.set(len(self._active))
         self._write_compiling()
-        return _ledger_record(self, key, wall_s, signature, trigger, error)
+        timed = {"t0_ns": t1_ns - int(wall_s * 1e9), "t1_ns": t1_ns,
+                 **_banked_fields(bank, wall_s)}
+        return _ledger_record(self, key, wall_s, signature, trigger, error,
+                              timed)
 
     # ---- cache-size accounting -------------------------------------------
     def note_cache_size(self, name, size):
@@ -300,9 +425,12 @@ class CompileLedger:
             pass
 
 
-def _ledger_record(led, key, wall_s, signature, trigger, error):
+def _ledger_record(led, key, wall_s, signature, trigger, error, timed):
     """The shared event-append + churn/trigger classification (module
-    function so both ledgered_jit and record_compile use one copy)."""
+    function so both ledgered_jit and record_compile use one copy).
+    ``timed`` is the event's stamps and what jax said of its parts
+    (``_banked_fields``); the finished event also goes to the set-up log as
+    a record named ``compile``, child of the phase open on this thread."""
     key = str(key)
     sig = "?" if signature is None else str(signature)
     err = None if error is None else f"{type(error).__name__}: {error}"
@@ -331,10 +459,13 @@ def _ledger_record(led, key, wall_s, signature, trigger, error):
         if churned:
             entry["churn_alerts"] += 1
         rec = {"key": key, "signature": sig, "wall_s": round(float(wall_s), 4),
-               "trigger": resolved, "time": time.time()}
+               "trigger": resolved, "time": time.time(), **timed}
         if err:
             rec["error"] = err
         led._events.append(rec)
+    _tracing.setup_record(
+        "compile", key=key, trigger=resolved, wall_s=rec["wall_s"],
+        **timed, **({"error": err} if err else {}))
     _M_EVENTS.inc()
     _M_WALL.observe(wall_s)
     if not first and err is None:
@@ -416,6 +547,7 @@ def ledgered_jit(fn, key=None, static_argnums=None, track_memory=True,
     """
     import jax
 
+    _listen()
     if key is None:
         key = getattr(fn, "__qualname__", None) or getattr(
             fn, "__name__", "anonymous")
@@ -464,7 +596,7 @@ def ledgered_jit(fn, key=None, static_argnums=None, track_memory=True,
         return out
 
     def lower(*args, **kwargs):
-        with led.suppressed():
+        with led.suppressed(key):
             return jitted.lower(*args, **kwargs)
 
     wrapper._jitted = jitted
@@ -486,6 +618,7 @@ def record_compile(key, trigger=None, signature=None):
     ``.lower(...).compile()``) where :func:`ledgered_jit` can't wrap the
     callable. Times the body, records one ledger event, and routes
     exceptions through OOM forensics before re-raising."""
+    _listen()
     tok = ledger.begin(key)
     t0 = time.perf_counter()
     try:
@@ -679,9 +812,9 @@ class MemoryLedger:
                 self._programs.popitem(last=False)
 
     @staticmethod
-    def _compile_captured(jitted, abstract):
+    def _compile_captured(jitted, abstract, key=None):
         a, kw = abstract
-        with _compile_lock(), ledger.suppressed():
+        with _compile_lock(), ledger.suppressed(key):
             return jitted.lower(*a, **kw).compile()  # compile-ledger-ok (the ledger's own suppressed analysis)
 
     def compiled(self, key):
@@ -694,7 +827,7 @@ class MemoryLedger:
         jitted = v["jitted"]()
         if jitted is None:
             raise KeyError(f"{key}: program garbage-collected")
-        return self._compile_captured(jitted, v["abstract"])
+        return self._compile_captured(jitted, v["abstract"], key)
 
     def analyze(self, keys=None, force=False):
         """Harvest ``memory_analysis()`` for captured programs (all, or
@@ -716,7 +849,7 @@ class MemoryLedger:
                 out[k] = {"error": err}
                 continue
             try:
-                compiled = self._compile_captured(jitted, v["abstract"])
+                compiled = self._compile_captured(jitted, v["abstract"], k)
                 analysis = _analysis_dict(compiled.memory_analysis())
                 cost = _cost_dict(compiled)
                 with self._lock:

@@ -30,6 +30,17 @@ dispatcher thread beside the device lines, on the profiler's own clock.
 With tracing enabled the completed record also fans out as ordinary span
 records, a parent and its phases.
 
+The **set-up log** (:func:`setup_phase`, :func:`setup_record`,
+:func:`setup_records`) is its sibling for the time before the first step:
+one record per phase of a process's start — import, parameter creation, the
+cast, the engine's pools, warm-up, the train step's build, and every compile
+the ledger saw (observability/compilemem.py) — with two stamps on the same
+``time.monotonic_ns()``, the phase that was open on the thread when it
+started, and the phase's counts. A phase is seconds long and a process makes
+a few dozen, so it is always on like the step log; an open phase sits in
+:func:`annotation`, and with tracing enabled a finished record fans out as a
+span of the same name. docs/OBSERVABILITY.md has the table of phases.
+
 Cost contract (asserted in tests/test_telemetry.py like chaos.site's):
 **disabled, an attr-less span is one module-global load + a None/False
 check** returning a shared no-op context manager — no allocation, no clock
@@ -57,7 +68,8 @@ from ..utils.envs import env_bool, env_str
 __all__ = ["span", "enable", "disable", "enabled", "last_spans",
            "add_jsonl_sink", "clear_sinks", "JsonlSpanSink", "emit_record",
            "annotation", "new_step", "commit_step", "step_records",
-           "span_record", "program_scopes", "note_program_scopes"]
+           "span_record", "program_scopes", "note_program_scopes",
+           "setup_phase", "setup_record", "setup_count", "setup_records"]
 
 _ENABLED = None           # tri-state: None = resolve from env on first use
 _RING_DEFAULT = 512
@@ -209,9 +221,14 @@ def last_spans(n=64):
 
 
 def clear():
-    """Test hook: drop captured spans and step records (sinks untouched)."""
+    """Test hook: drop captured spans, step records and set-up records
+    (sinks untouched)."""
+    global _burst
     _ring.clear()
     steps.clear()
+    setups.clear()
+    with _setup_lock:
+        _burst = None
 
 
 class _NullSpan:
@@ -377,6 +394,152 @@ def commit_step(rec, phases=()):
 def step_records(n=None):
     """The step log, oldest first (the last ``n`` records, or all)."""
     buf = list(steps)
+    return buf if n is None else buf[-n:]
+
+
+# ---- the set-up log ---------------------------------------------------------
+
+#: the set-up log: a process makes a few dozen records before its first step
+setups = collections.deque(maxlen=512)
+_setup_lock = threading.Lock()
+#: the open burst (a record still growing), or None: parameter creation is
+#: thousands of calls that read as ONE `setup.build`
+_burst = None
+
+
+def _open_phases():
+    stack = getattr(_local, "setup_stack", None)
+    if stack is None:
+        stack = _local.setup_stack = []
+    return stack
+
+
+class _SetupPhase:
+    """An open set-up phase: ``counts`` is the caller's to fill before it
+    closes, ``t0_ns`` / ``t1_ns`` are its stamps afterwards."""
+
+    __slots__ = ("name", "counts", "t0_ns", "t1_ns", "parent", "_burst",
+                 "_ann")
+
+    def __init__(self, name, t0_ns, burst, counts):
+        self.name, self.t0_ns, self._burst = name, t0_ns, burst
+        self.counts = counts
+        self.t1_ns = None
+
+    def __enter__(self):
+        stack = _open_phases()
+        # a part of a burst is no parent of its own burst
+        self.parent = next((p.name for p in reversed(stack)
+                            if p.name != self.name), None)
+        if not self._burst:
+            _close_burst()
+        stack.append(self)
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
+        if self.t0_ns is None:
+            self.t0_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1_ns = time.monotonic_ns()
+        self._ann.__exit__(*exc)
+        stack = _open_phases()
+        if stack and stack[-1] is self:
+            stack.pop()
+        if self._burst:
+            _grow_burst(self)
+        else:
+            setup_record(self.name, self.t0_ns, self.t1_ns, self.parent,
+                         **self.counts)
+        return False
+
+
+def setup_phase(name, t0_ns=None, burst=False, **counts):
+    """``with setup_phase("engine.warmup") as ph:`` opens a set-up phase on
+    this thread; ``ph.counts`` takes what the phase counted, and the record
+    is appended when the block ends. ``t0_ns`` backdates the start (a phase
+    that began in an earlier call: the train step's build starts at its
+    construction). ``burst=True`` makes the block one part of a burst: parts
+    of one name are merged into ONE record, first start to last end, their
+    counts summed (a flag keeps its last value), closed when another phase
+    opens, a record is appended or the log is read."""
+    return _SetupPhase(name, t0_ns, burst, counts)
+
+
+def setup_count(**adds):
+    """Add numbers to the counts of the innermost phase open on this thread
+    (nothing is open: nothing is counted). The compile ledger's listeners
+    bank what jax compiles outside a ledger event here."""
+    stack = _open_phases()
+    if stack:
+        counts = stack[-1].counts
+        for k, v in adds.items():
+            counts[k] = counts.get(k, 0) + v
+
+
+def _grow_burst(part):
+    global _burst
+    done = None
+    with _setup_lock:
+        b = _burst
+        if b is not None and b["name"] != part.name:
+            done, b = b, None
+        if b is None:
+            b = _burst = {"name": part.name, "t0_ns": part.t0_ns,
+                          "t1_ns": part.t1_ns, "parent": part.parent,
+                          "tid": _small_tid()}
+        b["t1_ns"] = max(b["t1_ns"], part.t1_ns)
+        for k, v in part.counts.items():
+            b[k] = v if isinstance(v, bool) else b.get(k, 0) + v
+    if done is not None:
+        _commit(done)
+
+
+def _close_burst():
+    global _burst
+    if _burst is None:
+        return
+    with _setup_lock:
+        b, _burst = _burst, None
+    if b is not None:
+        _commit(b)
+
+
+_SETUP_FIELDS = ("name", "t0_ns", "t1_ns", "parent", "tid")
+
+
+def _commit(rec):
+    """Append a finished record; with tracing enabled it also goes out as an
+    ordinary span, its counts the span's attrs."""
+    setups.append(rec)
+    if enabled():
+        span_rec = span_record(
+            rec["name"], rec["t0_ns"], rec["t1_ns"], rec["parent"],
+            **{k: v for k, v in rec.items() if k not in _SETUP_FIELDS})
+        span_rec["tid"] = rec["tid"]
+        _emit(span_rec, span_rec["dur_us"])
+
+
+_OPEN = object()
+
+
+def setup_record(name, t0_ns, t1_ns, parent=_OPEN, **counts):
+    """Append a finished set-up record from two ``time.monotonic_ns()``
+    stamps. ``parent`` defaults to the phase open on this thread now."""
+    if parent is _OPEN:
+        stack = _open_phases()
+        parent = stack[-1].name if stack else None
+    rec = {"name": name, "t0_ns": t0_ns, "t1_ns": t1_ns, "parent": parent,
+           "tid": _small_tid(), **counts}
+    _close_burst()
+    _commit(rec)
+    return rec
+
+
+def setup_records(n=None):
+    """The set-up log, oldest first (the last ``n`` records, or all)."""
+    _close_burst()
+    buf = list(setups)
     return buf if n is None else buf[-n:]
 
 

@@ -467,6 +467,10 @@ class ServingFrontend:
         self._stop = threading.Event()
         self._threads = []
         self._started = False
+        # replicas start() launched that do not serve yet, and its stamp:
+        # the set-up log's `frontend.start` (_note_serving)
+        self._starting = set()
+        self._start_stamp = (0, 0)   # (start()'s stamp, replicas launched)
         self._class_hists = {}
         # AOT precompile vocabulary: kwargs forwarded to each engine's
         # warmup() by ITS dispatcher thread before it serves (replicas
@@ -529,6 +533,8 @@ class ServingFrontend:
         if self._started:
             return self
         self._started = True
+        self._start_stamp = (time.monotonic_ns(), len(self.replicas))
+        self._starting = {rep.name for rep in self.replicas}
         # scope the (process-global) serving goodput split to this
         # frontend's lifetime: without the reset, an hour of training
         # before serving dilutes every serving fraction toward zero
@@ -1121,6 +1127,21 @@ class ServingFrontend:
         # already terminal or unknown: cancel() is idempotent
 
     # ---- dispatcher -------------------------------------------------------
+    def _note_serving(self, rep):
+        """The set-up log's `frontend.start` (observability/tracing.py):
+        from start() until the last replica it launched has warmed up and
+        enters its serve loop. The stamps are taken on two threads, so it
+        is appended finished."""
+        with self._lock:
+            if rep.name not in self._starting:
+                return
+            self._starting.remove(rep.name)
+            if self._starting:
+                return
+        t0_ns, replicas = self._start_stamp
+        _tracing.setup_record("frontend.start", t0_ns, time.monotonic_ns(),
+                              parent=None, replicas=replicas)
+
     def _run_replica(self, rep):
         eng = rep.engine
         wake = self._wakes[rep.name]
@@ -1165,6 +1186,7 @@ class ServingFrontend:
             finally:
                 warm_done.set()
                 beater.join(timeout=5.0)
+        self._note_serving(rep)
         while not self._stop.is_set():
             rep.beat()
             rep.publish_gauges()

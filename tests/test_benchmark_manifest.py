@@ -1,9 +1,10 @@
 """The benchmark's manifest and its newest cell, guarded by tier-1:
 `benchmarks/run.py --check` (BENCHMARK.json against the contract's limits
-and against every file it names) and a CPU rehearsal of each cell of a
-family other than Llama's (latent attention and routed experts; sparse and
-linear attention; state-space, window and cross-attention layers over one
-shared K/V pool) through the harness's own entry point (tiny widths, 3 s
+and against every file it names) and a CPU rehearsal of the training cell
+and of each cell of a family other than Llama's (latent attention and routed
+experts; sparse and linear attention; state-space, window and
+cross-attention layers over one shared K/V pool) through the harness's own
+entry point (tiny widths, 3 s
 window; it prints no result line and measures nothing). The harness's
 own unit tests stay in benchmarks/tests (run by hand). The cell's runner
 pins one arrival schedule for every seed: that is guarded here too."""
@@ -30,21 +31,30 @@ def test_manifest_checks_clean():
     assert "0 fault(s), 5 cell(s)" in done.stdout
 
 
-# cell -> what has to reach its metrics line: the family's counters and the
-# numbers of the comparison that decides `correct`
+#: the set-up log's metrics (ISSUE 37): on the metrics line of every run
+SETUP = ("setup.import_s", "setup.build_s", "setup.trace_lower_s",
+         "setup.backend_compile_s", "setup.cache_hit_pct",
+         "setup.engine_warm_s", "setup.unplaced_pct")
+
+# cell -> what has to reach its metrics line: the family's counters, the
+# numbers of the comparison that decides `correct`, and the set-up log's seven
 REHEARSED = {
+    # the training cell has no engine: six of the seven (10 s in the sandbox)
+    "mistral7b-pretrain-4k": (
+        "train.mfu_pct", "reference_loss",
+        *(name for name in SETUP if name != "setup.engine_warm_s")),
     "kimi-k2.7-code-agent-steady": (
         "moe.experts_hit_pct", "moe.max_load_ratio", "latent_pool.used_pct",
-        "serve.mfu_pct", "full_forward_rel_rms", "far_share"),
+        "serve.mfu_pct", "full_forward_rel_rms", "far_share", *SETUP),
     "minicpm-sala-longdoc-steady": (
         "sparse.kept_pct", "state_slots.used_pct", "sala.serve_mfu_pct",
         "full_forward_rel_rms", "far_share", "blocks_selected_alike",
-        "lightning-xla", "sparse-prefill-xla", "sparse-decode-xla"),
+        "lightning-xla", "sparse-prefill-xla", "sparse-decode-xla", *SETUP),
     "phi4flash-reasoning-steady": (
         "swa.keys_visited_pct", "yoco.tail_tok_pct",
         "phi4flash.serve_mfu_pct", "full_forward_rel_rms", "far_share",
         "mismatch_share", "first_state_rel_rms", "ragged-kernel-interpret",
-        "ssm-xla"),
+        "ssm-xla", *SETUP),
 }
 
 
@@ -57,6 +67,8 @@ def test_cell_rehearses(cell, tmp_path):
     assert "rehearsal passed" in done.stdout
     for name in REHEARSED[cell]:
         assert name in done.stdout, name
+    for name in set(SETUP) - set(REHEARSED[cell]):
+        assert name not in done.stdout, name
 
 
 def test_pinned_schedule_is_one_order_for_every_seed():
