@@ -573,7 +573,10 @@ class ContinuousBatchingEngine:
                       # grid steps the mixed steps' attention calls walked a
                       # layer, and the steps of the dense walk (step log's
                       # `ragged_walk`, summed)
-                      "ragged_walk": (0, 0)}
+                      "ragged_walk": (0, 0),
+                      # the same of the scan steps' decode calls, a layer
+                      # and forward (step log's `paged_walk`, summed)
+                      "paged_walk": (0, 0)}
         # per-serve map rid -> exception for requests that failed in
         # isolation (their results entry is None); the EngineRequest carries
         # the same exception + its rendered string for the online path.
@@ -2120,11 +2123,11 @@ class ContinuousBatchingEngine:
         cu[1:] = np.cumsum(q_lens)
         walk = getattr(self._cache_spec, "ragged_walk", None)
         if walk is not None:  # K/V pages: the ragged kernel's grid, a layer
-            step["ragged_walk"] = walk(self.pools[0], cu, lengths_op + q_lens,
-                                       T, self.pages_per_seq)
-            self.stats["ragged_walk"] = tuple(
-                a + b for a, b in zip(self.stats["ragged_walk"],
-                                      step["ragged_walk"]))
+            self._log_walk(step, "ragged_walk", walk(
+                self.pools[0], cu, lengths_op + q_lens, T,
+                self.pages_per_seq))
+        if k > 1:  # the scan's rows, as its first step finds them
+            self._log_paged_walk(step, lengths_op + q_lens, caps)
         # non-participant rows (empty slots + still-mid-prefill prompts)
         # route their scan-step writes to the scratch page
         scan_pt = np.where((caps > 0)[:, None], self.page_table, 0)
@@ -2292,6 +2295,7 @@ class ContinuousBatchingEngine:
             if sampling[0]:
                 bases[slot] = r.key_base
                 idxs[slot] = r.n_dispatched
+        self._log_paged_walk(step, self.lengths, caps)
         if chain is None:
             feed = jnp.asarray(toks)
         elif fresh.any():
@@ -2424,6 +2428,24 @@ class ContinuousBatchingEngine:
             host = np.asarray(blk)  # serve-readback-ok
         step["t_ready"] = time.monotonic_ns()
         return host
+
+    def _log_walk(self, step, name, walk):
+        """`walk` = (walked, dense) grid steps onto the record and the
+        engine's running sums."""
+        step[name] = walk
+        self.stats[name] = tuple(a + b for a, b in zip(self.stats[name], walk))
+
+    def _log_paged_walk(self, step, lengths, caps):
+        """The step log's `paged_walk`: the grid steps a layer and forward
+        of this dispatch's first scan step walks in the paged decode kernel
+        beside the live rows x longest row's blocks walk, where the cache
+        spec counts them (float K/V pages)."""
+        walk = getattr(self._cache_spec, "paged_walk", None)
+        got = walk and walk(
+            self.pools, np.where(caps > 0, np.minimum(lengths, caps) + 1, 0),
+            self.pages_per_seq)
+        if got:
+            self._log_walk(step, "paged_walk", got)
 
     def _dispatched(self, step, cold, rows):
         """The dispatch went out: its rows ``(rid, role, q_len, kv_len)``

@@ -34,7 +34,10 @@ The engine reads every spec through the same four members:
 
 and, where a mixed step's attention walks a grid sized from the spans
 (KVCacheSpec alone), `spec.ragged_walk(pool, cu, kv_lens, n_tokens, npages)`:
-the step log's `ragged_walk`; a spec without it logs none.
+the step log's `ragged_walk`; a spec without it logs none. Likewise
+`spec.paged_walk(pools, lengths, npages)` where a scan step's decode call
+walks a list made from the rows' lengths (KVCacheSpec, and `LayerCacheSpecs`
+for its layers of that kind): the step log's `paged_walk`.
 
 Pages stay the allocator's one unit: every layer whose pages the allocator
 hands out shares the row's page table; a state slot and a window ring are
@@ -129,6 +132,16 @@ class LayerCacheSpecs:
                    max_seqs=None, prefill_chunk=None):
         return [s.make_pool(num_pages, page_size, dtype, kv_cache_dtype,
                             max_seqs, prefill_chunk) for s in self.layers]
+
+    def paged_walk(self, pools, lengths, npages):
+        """The step log's `paged_walk`, summed over the layers whose pages
+        are the allocator's and whose decode call walks a row's whole table
+        (KVCacheSpec); None where no layer does."""
+        walks = [s.paged_walk([pool], lengths, npages)
+                 for s, pool in zip(self.layers, pools)
+                 if s.allocator_pages and hasattr(s, "paged_walk")]
+        walks = [w for w in walks if w is not None]
+        return tuple(map(sum, zip(*walks))) if walks else None
 
     def refuses(self, plane):
         for s in self.layers:

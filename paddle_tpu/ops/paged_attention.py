@@ -26,21 +26,26 @@ and fails if a pool-shaped copy comes back.
 
 Decode tiers, chosen at trace time like ops/flash_attention.py (`LAST_IMPL`;
 a tier that cannot run raises, it never becomes another):
-- `paged-kernel`, float pool: `_paged_pallas`, this module's kernel, on the
-  pattern of ops/mla_decode_attention.py. Grid (live row, block of pages)
-  with both bounds as OPERANDS (the rows that have anything to attend, the
-  blocks of the longest of them); the row list, lengths and page table are
-  scalar prefetch; every page of a block is one page-indirect operand
-  holding ALL KV heads ([Hkv, bs, D], one strided DMA a page), so a step
-  folds a block into every head's online softmax with two batched dots
-  (q grouped [Hkv, G, D]: MHA and GQA take the one path). Scores, softmax
-  and accumulator are f32. Work is in proportion to the live rows' extents:
-  a dead row (length 0) is never visited and returns zeros; past a row's
-  own pages an operand stays on the page it held, which is not fetched
-  again. On the v5e at 16 rows x 32/32 heads it reads 76-92% of the HBM
-  roofline (28 us a call at 3 live rows of 350 tokens), where jax's kernel
-  took 200-404 us (PERF.md PR 32: a 512-step grid, a 4 KB DMA a page and
-  head, f32 products, and 13 dead rows handed a length of 1).
+- `paged-kernel`, float pool: `_paged_pallas`, this module's kernel. ONE
+  grid axis over the call's live (row, block of pages) pairs, on the
+  pattern of `_ragged_pallas`: a work list made from the rows' lengths
+  before the call (`paged_work`: the pair's row, its block, a first / last
+  flag, the row's last held page) is scalar prefetch beside the lengths and
+  the page table, and its length is the grid's bound, an OPERAND. Every
+  page of a block is one page-indirect operand holding ALL KV heads
+  ([Hkv, bs, D], one strided DMA a page), so a step folds a block into
+  every head's online softmax with two batched dots (q grouped
+  [Hkv, G, D]: MHA and GQA take the one path). Scores, softmax and
+  accumulator are f32. Work is in proportion to the blocks the live rows
+  hold: a dead row (length 0) is never visited and returns zeros, a row
+  steps through its own blocks and not the longest row's (a step that
+  folds nothing still cost 1.2 us: 13 rows of 1.7k-12.6k tokens walked 429
+  steps for 178 live ones, PERF.md PR 38); past a row's own pages an
+  operand stays on the page it held, which is not fetched again. On the
+  v5e at 16 rows x 32/32 heads it reads 76-92% of the HBM roofline (28 us
+  a call at 3 live rows of 350 tokens), where jax's kernel took 200-404 us
+  (PERF.md PR 32: a 512-step grid, a 4 KB DMA a page and head, f32
+  products, and 13 dead rows handed a length of 1).
 - `paged-kernel`, int8 pool (`is_quantized`): jax's
   `jax.experimental.pallas.ops.tpu.paged_attention`, whose DMAs dequantise
   (this module's kernel has no scales operand). It skips rows of length 0
@@ -60,6 +65,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 LAST_IMPL = None  # "paged-kernel[-interpret]" | "paged-math" — at trace time
 
@@ -171,6 +177,16 @@ class KVCacheSpec:
 
         return ragged_walk(cu, kv_lens, n_tokens, self.num_heads, pool[0],
                            npages)
+
+    @staticmethod
+    def paged_walk(pools, lengths, npages):
+        """(walked, dense) grid steps a layer and forward of a scan step's
+        decode call at these lengths (the step's own token included, 0 for
+        a row the scan leaves out): the step log's `paged_walk`. None for
+        the int8 pool, whose kernel is jax's."""
+        k_pages = pools[0][0]
+        return (None if is_quantized(k_pages)
+                else paged_walk(k_pages, lengths, npages))
 
 
 class WindowRingSpec:
@@ -377,35 +393,99 @@ _LANES = 128  # m/l scratch keep a lane-aligned last dim
 _SUBLANES = 8  # q's group rows are padded to whole f32 sublane tiles
 
 
-def _first_block(length, window, kb):
-    """The first block of `kb` keys that holds one of a row's last `window`
-    keys: a windowed walk starts there, whatever the row's length."""
-    return jax.lax.div(jnp.maximum(length - window, 0), kb)
+def _row_blocks(lengths, bs, ppb, npages, window, xp):
+    """(first, count) [R] int32: the first block of `ppb` pages a row's walk
+    folds and how many it folds. Without a `window` a row walks from block 0
+    to the block of its last key (a length past the table reads no page);
+    with one, from the block that holds the first of its last `window` keys.
+    A dead row (length 0) walks none."""
+    kb = ppb * bs
+    lens = lengths.astype(xp.int32)
+    last = xp.minimum(-(-lens // kb), -(-npages // ppb))
+    first = (xp.zeros_like(lens) if window is None
+             else xp.maximum(lens - window, 0) // kb)
+    return first, xp.maximum(last - first, 0)
 
 
-def _decode_kernel(ppb, window, row_ref, len_ref, pt_ref, q_ref, *refs):
-    """Grid (i-th live row, block j of ppb pages): fold the block's K and V
-    rows, every KV head at once, into row `row_ref[i]`'s online softmax.
+def paged_work(lengths, bs, ppb, npages, window=None, xp=jnp):
+    """The decode kernel's work list from the rows' lengths, for `jnp`
+    (before the call: no sort, no gather, no scan) and for numpy.
+
+    Returns (work, n_steps): `work` four int32 arrays of R x ceil(npages /
+    ppb) entries, one a pair p, rows in order and a row's blocks in order:
+    work[0][p] the pair's row, work[1][p] its block of `ppb` pages (of the
+    row's table: a windowed row's first pair stands at the block of its
+    first visible key), work[2][p] bit 0 set on a row's first pair and bit 1
+    on its last, work[3][p] the index of the row's last held page (the page
+    maps keep an operand past it on the page it held a block before).
+    `n_steps` is the sum of the rows' blocks; entries past it are zeros and
+    are never visited. The list follows from `lengths` alone: the calls of
+    one forward that share them share it.
+
+    Every op between the lengths and the kernel is a launch of its own, and
+    the shortest call is a few microseconds long: so the pairs come from
+    masked sums over the rows (two rounds of them, the indices constants),
+    four arrays and not one stacked, and nothing is computed after them."""
+    lens = lengths.astype(xp.int32)
+    R = lens.shape[0]
+    first, count = _row_blocks(lens, bs, ppb, npages, window, xp)
+    last_page = xp.maximum(-(-lens // bs) - 1, 0)
+    r = np.arange(R, dtype=np.int32)
+    below, own = r[None, :] < r[:, None], r[None, :] == r[:, None]
+    before = xp.where(below, count[None, :], 0)
+    starts = xp.sum(before, axis=1)                  # pairs before the row's
+    ends = xp.sum(before + xp.where(own, count[None, :], 0), axis=1)
+    # a row's block less its place among the pairs, and its last page, as
+    # sums too: they then come out of the one fusion that makes `starts`
+    lead = xp.sum(xp.where(own, first[None, :], 0) - before, axis=1)
+    held = xp.sum(xp.where(own, last_page[None, :], 0), axis=1)
+    p = np.arange(R * -(-npages // ppb), dtype=np.int32)[:, None]
+    mine = (starts[None, :] <= p) & (p < ends[None, :])    # one row a pair
+
+    def of(x):
+        return xp.sum(xp.where(mine, x, 0), axis=1).astype(xp.int32)
+
+    edge = (p == starts[None, :]) + 2 * (p + 1 == ends[None, :])
+    return ((of(r[None, :]), of(lead[None, :] + p), of(edge),
+             of(held[None, :])), ends[-1])
+
+
+def paged_walk(k_pages, lengths, npages, window=None):
+    """(walked, dense): the grid steps `_paged_pallas` takes for these
+    lengths (the just-written token included, 0 for a dead row), and the
+    steps of the rectangular walk it replaced (every live row through the
+    blocks of the longest). Host arithmetic; `k_pages` lends its shape and
+    dtype."""
+    ppb = _pages_per_block(k_pages, npages)
+    _, count = _row_blocks(np.asarray(lengths), k_pages.shape[2], ppb,
+                           npages, window, np)
+    return (int(count.sum()),
+            int(np.count_nonzero(count)) * int(count.max(initial=0)))
+
+
+def _decode_kernel(ppb, window, row_ref, blk_ref, edge_ref, held_ref, len_ref,
+                   pt_ref, q_ref, *refs):
+    """Grid (pair p of the work list): fold block `blk_ref[p]` of ppb pages,
+    every KV head at once, into row `row_ref[p]`'s online softmax.
     q_ref [Hkv, Gp, D] (the group's rows padded to Gp); refs: ppb K pages
     and ppb V pages [Hkv, bs, D], then o_ref [Hkv, G, D] and the scratch acc
-    [Hkv, Gp, D], m and l [Hkv, Gp, 128]. With a `window` the row's walk
-    starts at the block of its first visible key (`_first_block`)."""
+    [Hkv, Gp, D], m and l [Hkv, Gp, 128]. A row's pairs follow one another,
+    so the scratch is the row's from its first pair to its last."""
     import jax.experimental.pallas as pl
 
     k_pg, v_pg = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, acc, m, l = refs[2 * ppb:]
-    step = j = pl.program_id(1)
+    p = pl.program_id(0)
+    j, edge = blk_ref[p], edge_ref[p]
     kb = ppb * k_pg[0].shape[1]
-    length = len_ref[row_ref[pl.program_id(0)]]
-    if window is not None:
-        j = step + _first_block(length, window, kb)
+    length = len_ref[row_ref[p]]
 
     def seen(pos):
         if window is None:
             return pos < length
         return (pos < length) & (pos >= length - window)
 
-    @pl.when(step == 0)
+    @pl.when((edge & 1) == 1)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, -1e30)
@@ -434,7 +514,7 @@ def _decode_kernel(ppb, window, row_ref, len_ref, pt_ref, q_ref, *refs):
             preferred_element_type=jnp.float32)                # [Hkv, Gp, D]
         acc[...] = acc[...] * corr + pv
 
-    @pl.when(step == pl.num_programs(1) - 1)
+    @pl.when(edge >= 2)
     def _finish():
         out = acc[...] / jnp.maximum(l[:, :, :1], 1e-30)
         o_ref[...] = out[:, :o_ref.shape[1]]
@@ -443,20 +523,20 @@ def _decode_kernel(ppb, window, row_ref, len_ref, pt_ref, q_ref, *refs):
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb",
                                              "window"))
 def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
-                  interpret, ppb=None, window=None):
+                  interpret, ppb=None, window=None, work=None):
     """The float pool's kernel (module docstring). `ppb`, pages a block, is
-    `_pages_per_block`'s unless a test or a sweep says otherwise. The
-    `pallas_call` is named `paged_attention`: once a layer and scan step,
-    under the name the benchmark's reader looks for.
+    `_pages_per_block`'s and `work` (the list and its length) `paged_work`'s
+    unless a test or a sweep says otherwise. The `pallas_call` is named
+    `paged_attention`: once a layer and scan step, under the name the
+    benchmark's reader looks for.
 
     A table a row AND K/V head (`page_indices [B, Hkv, n]`, `lengths
     [B, Hkv]` keys in table order: ops/sparse_paged_attention.py, whose
-    kept blocks differ by K/V head) walks the same grid with one (row, head)
+    kept blocks differ by K/V head) walks the same list with one (row, head)
     pair where a row stood: a page operand then holds its own head alone.
 
     `window` (a row sees its last `window` keys only): the row's walk starts
-    at the block of its first visible key and the grid's second bound is the
-    most blocks any live row's window spans, so the cost stops growing with
+    at the block of its first visible key, so the cost stops growing with
     the row. The table is read at the row's LOGICAL pages: a ring of pages
     (`WindowRingSpec`) is a table whose entries repeat."""
     import jax.experimental.pallas as pl
@@ -474,44 +554,33 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
     npages = page_indices.shape[1]
     ppb = ppb or _pages_per_block(jax.ShapeDtypeStruct(
         (hb,) + k_pages.shape[1:], k_pages.dtype), npages)
+    lengths = lengths.astype(jnp.int32)
+    if work is None:
+        work = paged_work(lengths, bs, ppb, npages, window)
+    work, n_steps = work
 
     def page_map(pg):
-        def index(i, j, rows, lens, pt):
-            # past the row's pages the operand stays on the last page it
-            # held in this row (the scratch page if it held none): a block
+        def index(p, row, blk, edge, held, lens, pt):
+            # past the row's pages the operand stays on the page it held a
+            # block before (the scratch page if it held none): a block
             # index that repeats between steps is not fetched again
-            b, held = rows[i], (lens[rows[i]] + bs - 1) // bs
-            if window is not None:
-                j = j + _first_block(lens[rows[i]], window, ppb * bs)
-            last = pg + jnp.maximum(held - 1 - pg, 0) // ppb * ppb
-            page = pt[b, jnp.minimum(j * ppb + pg, last)]
-            return (b % Hkv if by_head else 0,
-                    jnp.where(pg < held, page, 0), 0, 0)
+            b, at = row[p], blk[p] * ppb + pg
+            at = jnp.where(at > held[p], at - ppb, at)
+            return (jax.lax.rem(b, Hkv) if by_head else 0,
+                    jnp.where(at < 0, 0, pt[b, jnp.maximum(at, 0)]), 0, 0)
         return index
 
-    def row_map(i, j, rows, lens, pt):
-        return (rows[i], 0, 0, 0)
+    def row_map(p, row, *_):
+        return (row[p], 0, 0, 0)
 
-    lengths = lengths.astype(jnp.int32)
-    live = lengths > 0
-    # the live rows' numbers, in order, then zeros: no sort (one fusion)
-    idx = jnp.arange(B, dtype=jnp.int32)
-    place = jnp.sum(live[None, :] & (idx[None, :] <= idx[:, None]), axis=1) - 1
-    rows = jnp.sum(jnp.where(live[None, :] & (place[None, :] == idx[:, None]),
-                             idx[None, :], 0), axis=1).astype(jnp.int32)
-    n_blocks = jnp.minimum((jnp.max(lengths) + ppb * bs - 1) // (ppb * bs),
-                           -(-npages // ppb))
-    if window is not None:
-        n_blocks = jnp.max((lengths + ppb * bs - 1) // (ppb * bs)
-                           - _first_block(lengths, window, ppb * bs))
     qs = (q * scale).astype(k_pages.dtype).reshape(B, hb, group, D)
     qs = jnp.pad(qs, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     page_bytes = hb * bs * D * k_pages.dtype.itemsize
     fn = pl.pallas_call(
         functools.partial(_decode_kernel, ppb, window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(live.sum().astype(jnp.int32), n_blocks),
+            num_scalar_prefetch=6,
+            grid=(n_steps,),
             in_specs=[pl.BlockSpec((None, hb, gp, D), row_map)]
             + [pl.BlockSpec((hb, None, bs, D), page_map(pg))
                for pg in range(ppb)] * 2,
@@ -521,17 +590,18 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, scale,
                             pltpu.VMEM((hb, gp, _LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, hb, group, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             # every page operand twice (the pipeline's two buffers), the
             # block's K and V gathered and widened once more, and room
             vmem_limit_bytes=int(12 * ppb * page_bytes) + (16 << 20)),
         interpret=interpret,
         name="paged_attention",
     )
-    out = fn(rows, lengths, page_indices.astype(jnp.int32), qs,
+    out = fn(*work, lengths, page_indices.astype(jnp.int32), qs,
              *([k_pages] * ppb), *([v_pages] * ppb))
-    # the grid never visits a row of length 0: its block is whatever was there
-    out = jnp.where(live[:, None, None], out.reshape(B, hb * group, D), 0.0)
+    # the list never visits a row of length 0: its block is whatever was there
+    out = jnp.where((lengths > 0)[:, None, None],
+                    out.reshape(B, hb * group, D), 0.0)
     return out.reshape(q.shape).astype(q.dtype)
 
 

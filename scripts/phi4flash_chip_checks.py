@@ -5,6 +5,7 @@ PR 35). One process, one model at the published widths, the checks named on
 the command line in order:
 
     python3 scripts/phi4flash_chip_checks.py [--seed N] [--rehearse] CHECK...
+    python3 scripts/phi4flash_chip_checks.py [--tree DIR] [--calls N] sweep
 
 Every check serves rows that the cell's traffic produces for this seed, ONE
 AFTER THE OTHER in an engine of two slots at the cell's sizes (each lands on
@@ -25,11 +26,23 @@ cell's own limits.
               weights rounded to float8_e4m3 (and back to bf16) while the
               reference keeps the configuration's bf16 weights. Has to FAIL
               `check_served`. Rounds the model in place: name it last.
+`sweep`:      (named alone; no model, no engine) device time of the paged
+              decode kernel ALONE, off a profiler trace's module lines, at
+              the cell's shape (32 slots, 40 / 10 heads of 128, pages of 64,
+              a table 256 wide, bf16): 13 live rows all of 4,992 tokens
+              (`u`), 13 / 4 / 32 live rows of the lengths the cell's traffic
+              gives (`r`, `r4`, `r32`: `SWEEP_LENGTHS`), the window layers'
+              call over rings (`w`); and at the dense cell's (16 slots,
+              32 / 32 heads, pages of 16, a table 128 wide): 1 x 64, rows of
+              300 and 900, 16 x 2,047. `--tree .parent` imports `paddle_tpu`
+              from another checkout, so parent and change go in one chip
+              call. PERF.md section 6, PR 38.
 The planted faults (a stale slot, a ring too short, a window off by one, the
 tail gathered a token early, ...) are tests/test_phi4flash.py's, on the CPU.
 
 Prints one JSON line a check; exits 0 if every check came out as it has to."""
 import argparse
+import hashlib
 import json
 import os
 import sys
@@ -122,9 +135,143 @@ def tiers():
             "ssm_impl": ssm.LAST_IMPL}
 
 
+#: 32 live extents as the cell's traffic gives them (a Monte Carlo over the
+#: traffic file's two lognormals, rows in proportion to their residence, the
+#: position uniform in the output; fixed here, PR 38): the first 4 average
+#: 4,692, the first 13 5,078 with a longest of 12,603, all of them 4,960
+SWEEP_LENGTHS = (
+    2380, 11085, 2717, 2588, 2082, 3965, 4851, 3638, 12603, 12166, 3997,
+    1752, 2189, 1738, 5203, 7450, 3692, 7491, 5403, 840, 4094, 2676, 4390,
+    2095, 2908, 1747, 13602, 2686, 11047, 3838, 4459, 7364)
+
+
+def sweep(args):
+    """The `sweep` check: one JSON line a case, then the table; also left in
+    chiprun_out/pr38/."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from benchmarks.profiler import WindowTracer
+    from benchmarks.readers.trace import Trace
+    from paddle_tpu.ops.paged_attention import (
+        WindowRingSpec, paged_decode_attention,
+    )
+
+    cfg, knobs, _ = _load(args.rehearse)
+    dev = jax.devices()[0]
+    if not args.rehearse and dev.platform != "tpu":
+        raise SystemExit(f"no TPU: {dev}")
+    cut = 64 if args.rehearse else 1       # a rehearsal's rows are shorter
+    window = cfg["sliding_window"]
+    bf = jnp.bfloat16
+
+    def shape(name, slots, hq, hkv, d, bs, npages, ring=None):
+        """Pools, a query and a table at one cell's shape; with `ring` the
+        table is the window layers' ring of that many pages a row."""
+        key = jax.random.split(jax.random.PRNGKey(args.seed % 2 ** 31), 3)
+        pool = (hkv, 1 + slots * (ring or npages), bs, d)
+        k_pages = jax.random.normal(key[0], pool, bf)
+        v_pages = jax.random.normal(key[1], pool, bf)
+        q = jax.random.normal(key[2], (slots, hq, d), bf)
+        own = 1 + jnp.arange(slots * npages, dtype=jnp.int32).reshape(
+            slots, npages)
+        table = WindowRingSpec.table((k_pages,), own) if ring else own
+
+        def call(q, k_pages, v_pages, lengths, table):
+            return paged_decode_attention(
+                q, k_pages, v_pages, lengths, table,
+                impl="pallas" if args.rehearse else None,
+                window=window if ring else None)
+
+        call.__name__ = f"sweep_{name}"
+        return dict(fn=jax.jit(call), q=q, k=k_pages, v=v_pages, table=table,
+                    slots=slots, key_bytes=2 * hkv * d * 2,
+                    window=window if ring else None)
+
+    S, bs = knobs["max_seqs"], knobs["page_size"]
+    # a stored K/V head is a pair of the configuration's (differential
+    # attention: `Phi4FlashModel.cache_spec`); the table as the engine's
+    hq, hkv, d = (cfg["num_attention_heads"],
+                  cfg["num_key_value_heads"] // 2, 2 * cfg["head_dim"])
+    npages = -(-knobs["max_len"] // bs)
+    chunk = knobs["prefill_chunk"]
+    shapes = {
+        "cross": shape("cross", S, hq, hkv, d, bs, npages),
+        "window": shape("window", S, hq, hkv, d, bs, npages,
+                        ring=-(-(window + chunk) // bs) + 1),
+        "dense": shape("dense", 16, 32, 32, 128, 16, 128),
+    }
+    ragged = [max(n // cut, 1) for n in SWEEP_LENGTHS]
+    cases = [("u", "cross", [4992 // cut] * 13), ("r", "cross", ragged[:13]),
+             ("r4", "cross", ragged[:4]), ("r32", "cross", ragged[:S]),
+             ("w", "window", ragged[:13]),
+             ("d1x64", "dense", [64]), ("d300+900", "dense", [300, 900]),
+             ("d16x2047", "dense", [2047] * 16)]
+
+    def lengths_of(sh, lens):   # scattered over the slots, as an engine's are
+        out = np.zeros(sh["slots"], np.int32)
+        n = len(lens)
+        out[(3 + np.arange(n) * sh["slots"] // n) % sh["slots"]] = lens
+        return jnp.asarray(out)
+
+    def run(sh, lens):
+        lens = lengths_of(sh, lens)
+        table = jnp.where((lens > 0)[:, None], sh["table"], 0)
+        return sh["fn"](sh["q"], sh["k"], sh["v"], lens, table)
+
+    # compile every shape; what each case returns, to set beside the other
+    # tree's: a row's blocks fold in one order in both walks
+    sha = {name: hashlib.sha1(np.asarray(
+        run(shapes[which], lens).astype(jnp.float32)).tobytes()
+    ).hexdigest()[:12] for name, which, lens in cases}
+    out_dir = os.path.join(ROOT, "chiprun_out", "pr38")
+    say(check="sweep", tree=args.name, device=dev.device_kind,
+        paddle_tpu=os.path.dirname(paddle_tpu.__file__), cases=len(cases))
+    # the benchmark's own hold on the profiler: on at once, the traced
+    # interval under the annotation its reader clips to
+    tracer = WindowTracer(True, os.path.join(out_dir, "sweep_trace."
+                                             + args.name), 0.0, 0.0)
+    tracer.tick(0.0)
+    for name, which, lens in cases:
+        for _ in range(args.calls):
+            got = run(shapes[which], lens)
+        jax.block_until_ready(got)
+    tracer.stop()
+    trace = Trace(tracer.xplane_path())
+    if not trace.devices:   # a rehearsal: the CPU's trace has no device line
+        return say(check="sweep", tree=args.name, cases=len(cases),
+                   device_time="not measured", out_sha1=sha)
+    runs = {which: trace.module_runs(f"sweep_{which}") for which in shapes}
+    lines = []
+    for name, which, lens in cases:
+        mine, runs[which] = (runs[which][:args.calls],
+                             runs[which][args.calls:])
+        if len(mine) != args.calls:
+            raise SystemExit(f"the trace holds {len(mine)} runs of {name}, "
+                             f"not {args.calls}")
+        sh = shapes[which]
+        seen = [min(n, sh["window"] or n) for n in lens]
+        lines.append({"check": "sweep", "tree": args.name, "case": name,
+                      "live": len(lens), "keys": sum(lens),
+                      "longest": max(lens), "out_sha1": sha[name],
+                      "floor_us": 1e6 * sum(seen) * sh["key_bytes"] / 819e9,
+                      "us_median": 1e6 * float(np.median(mine)),
+                      "us_min": 1e6 * min(mine), "us_max": 1e6 * max(mine)})
+        say(**lines[-1])
+    with open(os.path.join(out_dir, f"sweep.{args.name}.json"), "w") as f:
+        json.dump({"device": dev.device_kind, "calls": args.calls,
+                   "lines": lines}, f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("checks", nargs="+", choices=sorted(HAS_TO_PASS))
+    ap.add_argument("checks", nargs="+",
+                    choices=sorted(HAS_TO_PASS) + ["sweep"])
+    ap.add_argument("--tree", help="sweep: import paddle_tpu from this "
+                    "checkout (the parent's) instead of the script's own")
+    ap.add_argument("--calls", type=int, default=20,
+                    help="sweep: runs of each case")
     ap.add_argument("--seed", type=int, default=3500000011)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--rows", default="shortest,over_a_chunk,longest",
@@ -135,6 +282,14 @@ def main():
                     help="dump every thread's stack when nothing is said "
                          "for this long")
     args = ap.parse_args()
+    if "sweep" in args.checks:
+        if args.checks != ["sweep"]:
+            ap.error("sweep runs alone")
+        args.name = "change"
+        if args.tree:     # ".parent" -> "parent"
+            sys.path.insert(0, os.path.abspath(args.tree))
+            args.name = os.path.basename(sys.path[0]).lstrip(".")
+        return sweep(args)
     import faulthandler
 
     faulthandler.dump_traceback_later(args.stall_s, repeat=True)

@@ -232,3 +232,164 @@ def test_a_ring_of_pages_is_a_table_whose_entries_repeat():
         spec.make_pool(9, BS, jnp.float32, max_seqs=3)
     with pytest.raises(ValueError, match="float pools"):
         spec.make_pool(9, BS, jnp.float32, "int8", 3, 8)
+
+
+# ---- the flat walk: one grid step a live (row, block) pair -----------------
+
+def _blocks(n, kb, nb, window=None):
+    """The blocks of `kb` keys a row of `n` keys folds, in order."""
+    last = min(-(-n // kb), nb)
+    first = max(n - window, 0) // kb if window is not None else 0
+    return list(range(first, last))
+
+
+WORK = {
+    # name: (lengths, page size, pages a block, table width, window)
+    "page-and-block-edges": ([1, 16, 17, 63, 64, 65, 96, 0], 16, 4, 6, None),
+    "dead-rows-between": ([0, 70, 0, 0, 33, 0, 96, 0], 16, 2, 6, None),
+    "one-live-row": ([0, 0, 45, 0], 16, 1, 6, None),
+    "no-live-row": ([0, 0, 0], 16, 4, 6, None),
+    "past-the-table": ([200, 5], 16, 4, 6, None),
+    "window": ([7, 21, 0, 96, 50, 64, 65], 16, 2, 6, 20),
+    "window-wider-than-a-row": ([7, 40, 96], 16, 1, 6, 64),
+    "by-head": (np.array([[70, 33], [0, 0], [16, 96]]).reshape(-1), 16, 4, 6,
+                None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORK))
+def test_the_work_list_holds_each_rows_own_blocks(case):
+    """`paged_work` from numpy and from `jnp` agree; the list holds the sum
+    of the rows' blocks, rows in order and a row's blocks in order, the first
+    / last flags on a row's ends, the row's last held page beside each pair,
+    and zeros after them. `paged_walk` counts the same steps
+    beside the live rows x longest walk."""
+    lens, bs, ppb, npages, window = WORK[case]
+    lens = np.asarray(lens, np.int32)
+    nb = -(-npages // ppb)
+    work, n = pa.paged_work(lens, bs, ppb, npages, window, xp=np)
+    jwork, jn = pa.paged_work(jnp.asarray(lens), bs, ppb, npages, window)
+    assert all(a.dtype == jnp.int32 for a in jwork) and int(jn) == n
+    work = np.stack(work)
+    np.testing.assert_array_equal(work, np.stack(jwork))
+    assert work.shape == (4, len(lens) * nb) and work.dtype == np.int32
+    want = [(r, j, (j == blks[0]) + 2 * (j == blks[-1]),
+             max(-(-int(ln) // bs) - 1, 0))
+            for r, ln in enumerate(lens)
+            for blks in [_blocks(int(ln), ppb * bs, nb, window)] for j in blks]
+    assert n == len(want)
+    assert [tuple(col) for col in work[:, :n].T] == want
+    assert not work[:, n:].any()
+    counts = [len(_blocks(int(ln), ppb * bs, nb, window)) for ln in lens]
+    assert (n, sum(c > 0 for c in counts) * max(counts)) == _walk(
+        lens, bs, ppb, npages, window)
+
+
+def _walk(lens, bs, ppb, npages, window):
+    """`paged_walk` with the block rule pinned to `ppb` pages."""
+    first, count = pa._row_blocks(lens, bs, ppb, npages, window, np)
+    return int(count.sum()), int((count > 0).sum()) * int(count.max())
+
+
+def _ragged_case(hq, hkv, rows, npages, lens, seed=0, ring=None):
+    """q, pools and a table for `lens`; with `ring` the table is a ring of
+    that many pages a row (`WindowRingSpec.table`), else the rows' own pages
+    with the scratch page past each row's length."""
+    rng = np.random.RandomState(seed)
+    P = 1 + rows * (ring or npages)
+    kp = rng.randn(hkv, P, BS, D).astype(np.float32)
+    vp = rng.randn(hkv, P, BS, D).astype(np.float32)
+    kp[:, 0], vp[:, 0] = 1e3, 1e3
+    if ring:
+        table = np.asarray(pa.WindowRingSpec.table(
+            (jnp.zeros((hkv, P, BS, D)),), jnp.zeros((rows, npages))))
+        table = np.where((np.asarray(lens) > 0)[:, None], table, 0)
+    else:
+        table = np.arange(1, P, dtype=np.int32).reshape(rows, npages)
+        held = np.arange(npages)[None] * BS < np.asarray(lens)[:, None]
+        table = np.where(held, table, 0)
+    q = rng.randn(rows, hq, D).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32))
+
+
+RAGGED = {
+    # name: (query heads, KV heads, table width, pages a block, window, ring)
+    "gqa-40-10": (40, 10, 12, 2, None, None),
+    "mha-32-32": (32, 32, 12, 4, None, None),
+    "window-512-ring": (8, 2, 96, 8, 512, 35),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_flat_walk_matches_math_on_ragged_lengths(case):
+    """Rows of 1 key to the table's width, dead rows between them: every live
+    row is its own softmax over its own keys whatever the others' lengths,
+    and a dead row is exactly zero."""
+    hq, hkv, npages, ppb, window, ring = RAGGED[case]
+    full = npages * BS
+    lens = [1, 0, full, BS * ppb + 1, 0, full // 3, BS * ppb, full - 1]
+    q, kp, vp, table, lens = _ragged_case(hq, hkv, len(lens), npages, lens,
+                                          ring=ring)
+    out = pa._paged_pallas(q, kp, vp, lens, table, 0.2, interpret=True,
+                           ppb=ppb, window=window)
+    ref = pa._paged_math(q, kp, vp, lens, table, 0.2, window)
+    dead = np.asarray(lens) == 0
+    assert not np.asarray(out)[dead].any()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL["f32"])
+    walked, dense = _walk(np.asarray(lens), BS, ppb, npages, window)
+    assert walked < dense
+
+
+def test_flat_walk_by_head_matches_math():
+    """A table and a length a (row, K/V head): each pair walks its own
+    blocks, a head with no key is zero beside a head of the same row that
+    has some."""
+    hq, hkv, npages, rows = 8, 2, 6, 3
+    lens = np.array([[70, 33], [0, 0], [0, FULL]], np.int32)
+    rng = np.random.RandomState(5)
+    P = 1 + rows * hkv * npages
+    kp = jnp.asarray(rng.randn(hkv, P, BS, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(hkv, P, BS, D), jnp.float32)
+    table = jnp.asarray(rng.permutation(P - 1)[:rows * hkv * npages].reshape(
+        rows, hkv, npages) + 1, jnp.int32)
+    q = jnp.asarray(rng.randn(rows, hq, D), jnp.float32)
+    out = np.asarray(pa._paged_pallas(q, kp, vp, jnp.asarray(lens), table,
+                                      0.25, interpret=True, ppb=2))
+    g = hq // hkv
+    for h in range(hkv):  # head h alone, under its own table
+        ref = pa._paged_math(q[:, h * g:(h + 1) * g], kp[h:h + 1],
+                             vp[h:h + 1], jnp.asarray(lens[:, h]),
+                             table[:, h], 0.25)
+        np.testing.assert_allclose(out[:, h * g:(h + 1) * g],
+                                   np.asarray(ref), **TOL["f32"])
+    assert not out[1].any() and not out[2, :g].any()
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "window-40"])
+def test_flat_walk_is_the_rectangular_walk_bit_for_bit(window):
+    """The walk this kernel had (every live row through the blocks of the
+    longest, a step past a row's length folding nothing), rebuilt here by
+    padding the list: the same bits, in fewer steps."""
+    npages, ppb = 12, 2
+    lens = [5, 0, npages * BS, 70, 0, 33, 150]
+    q, kp, vp, table, lens = _ragged_case(8, 2, len(lens), npages, lens,
+                                          seed=1)
+    ln = np.asarray(lens)
+    first, count = pa._row_blocks(ln, BS, ppb, npages, window, np)
+    n_blocks = int(count.max())
+    rect = [(r, first[r] + j, (j == 0) + 2 * (j == n_blocks - 1),
+             -(-ln[r] // BS) - 1)
+            for r in np.flatnonzero(ln) for j in range(n_blocks)]
+    work, n_flat = pa.paged_work(ln, BS, ppb, npages, window, xp=np)
+    assert n_flat == count.sum() < len(rect) <= len(work[0])
+    padded = np.array(rect + [(0,) * 4] * (len(work[0]) - len(rect)),
+                      np.int32).T
+    flat = pa._paged_pallas(q, kp, vp, lens, table, 0.3, interpret=True,
+                            ppb=ppb, window=window)
+    old = pa._paged_pallas(q, kp, vp, lens, table, 0.3, interpret=True,
+                           ppb=ppb, window=window,
+                           work=(tuple(jnp.asarray(padded)),
+                                 jnp.int32(len(rect))))
+    np.testing.assert_array_equal(np.asarray(flat), np.asarray(old))
+    assert np.asarray(flat)[ln > 0].any()

@@ -173,6 +173,55 @@ def test_mixed_dispatches_log_the_ragged_walk(served):
         sum(r["ragged_walk"][i] for r in mixed) for i in (0, 1))
 
 
+def test_scanning_dispatches_log_the_paged_walk(served):
+    """Every dispatch that scans (decode, and mixed at `decode_block` > 1)
+    carries `paged_walk = (walked, dense)`: the decode kernel's grid steps a
+    layer and forward at the first scan step's lengths, beside the live rows
+    x longest row's blocks walk it replaced. `walked <= dense`, and they are
+    equal when the scan's rows walk as many blocks each (at this size a
+    table is one block: a step a row either way)."""
+    recs = served["records"]
+    assert all("paged_walk" in r for r in recs)
+    for r in recs:
+        walked, dense = r["paged_walk"]
+        scanning = [row for row in r["rows"] if row[1] in "dg"]
+        assert 0 < walked <= dense, r
+        assert walked == dense == len(scanning), r
+    assert served["stats"]["paged_walk"] == tuple(
+        sum(r["paged_walk"][i] for r in recs) for i in (0, 1))
+
+
+@pytest.mark.parametrize("lens, want", [
+    ((300, 300, 300), (9, 9)),        # one length: the rectangle is the list
+    ((300, 20, 129), (3 + 1 + 2, 9)),  # each row to its own last block
+    ((0, 257, 0), (3, 3)),             # dead rows between: no step
+    ((0, 0, 0), (0, 0)),
+])
+def test_paged_walk_counts_each_rows_own_blocks(lens, want):
+    """`KVCacheSpec.paged_walk` on the host: 8 pages of 16 a block here, so
+    a row of n keys walks ceil(n / 128) steps; `LayerCacheSpecs` sums the
+    layers whose pages are the allocator's and leaves rings and slots out."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.cache_specs import LayerCacheSpecs, NoPoolSpec
+    from paddle_tpu.ops.paged_attention import (
+        KVCacheSpec, WindowRingSpec, _pages_per_block,
+    )
+
+    spec = KVCacheSpec(2, 32, 128)
+    pool = (jax.ShapeDtypeStruct((32, 99, 16, 128), jnp.bfloat16),) * 2
+    assert _pages_per_block(pool[0], 32) == 8
+    assert spec.paged_walk([pool, pool], np.asarray(lens), 32) == want
+    layered = LayerCacheSpecs([KVCacheSpec(1, 32, 128), NoPoolSpec(0),
+                               WindowRingSpec(32, 128, 64),
+                               KVCacheSpec(1, 32, 128)])
+    assert layered.paged_walk([pool, (), pool, pool], np.asarray(lens),
+                              32) == tuple(2 * w for w in want)
+    assert LayerCacheSpecs([NoPoolSpec()]).paged_walk(
+        [()], np.asarray(lens), 32) is None
+
+
 def test_ttft_parts_sum_to_the_request_stamps(served):
     recs = served["records"]
     admits = {a[0]: a for r in recs for a in r["admits"]}
@@ -249,7 +298,8 @@ def test_tracing_on_fans_the_record_out_as_spans(model):
     assert one["kind"] == "mixed" and one["rows"] and one["emits"]
     # the parent carries the record's counts and none of its stamps
     assert sorted(one) == ["admits", "chained", "cold", "emits", "engine",
-                           "k", "kind", "ragged_walk", "rows", "step"]
+                           "k", "kind", "paged_walk", "ragged_walk", "rows",
+                           "step"]
     # a phase lies inside its step's life
     by_step = {s["attrs"]["step"]: s for s in steps}
     for s in spans:
